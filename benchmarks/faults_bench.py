@@ -40,6 +40,7 @@ import numpy as np                                            # noqa: E402
 
 from repro import apc                                         # noqa: E402
 from repro.apc.metrics import get_registry                    # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache    # noqa: E402
 from repro.serve.batcher import AdmissionCfg, BatchServer     # noqa: E402
 
 from serve_bench import build_engine                          # noqa: E402
@@ -143,6 +144,7 @@ def main() -> None:
     p.add_argument("--json", default=os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "apc_bench.json"))
     args = p.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parent.parent)
     if args.smoke:
         points = [(0.0, ()), (1e-3, (1,))]
         kw = dict(n_requests=3, n_new=2)

@@ -37,6 +37,7 @@ from __future__ import annotations
 import json
 import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +50,7 @@ from repro.kernels.tap_pass.ops import hbm_traffic_model
 from repro.kernels.tap_pass.ref import apply_schedule, ripple_add_schedule
 from repro.kernels.ternary_matmul.ops import quantize_and_pack
 from repro.kernels.ternary_matmul.ref import ternary_matmul_ref
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _time(fn, *args, n=5) -> float:
@@ -611,6 +613,7 @@ def main():
     p.add_argument("--json", default=os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "apc_bench.json"))
     args = p.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parent.parent)
     bench_tap()
     bench_ternary()
     rows = (1024, 65536, 1048576) if args.full else (1024, 65536)

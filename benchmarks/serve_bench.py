@@ -41,7 +41,9 @@ import jax                                                    # noqa: E402
 import numpy as np                                            # noqa: E402
 
 from repro import apc                                         # noqa: E402
-from repro.configs import get_smoke_config                    # noqa: E402
+from repro.configs import (ModelConfig, TernaryCfg,           # noqa: E402
+                           get_smoke_config)
+from repro.launch.compile_cache import enable_compile_cache    # noqa: E402
 from repro.launch.mesh import make_smoke_mesh                 # noqa: E402
 from repro.models import model as M                           # noqa: E402
 from repro.models.quant import quantize_model_params          # noqa: E402
@@ -49,21 +51,30 @@ from repro.serve.batcher import AdmissionCfg, BatchServer     # noqa: E402
 from repro.serve.engine import Engine, ServeCfg               # noqa: E402
 
 
-def build_engine(*, n_arrays: int = 4, rows: int = 64,
-                 faults=None) -> Engine:
-    """Smallest Engine whose MLPs really run on the AP runtime.
-
-    ``faults`` (a :class:`repro.apc.FaultConfig`) installs the seeded
-    device fault model on the bank — the faults_bench sweep and the
-    degraded-bank smoke gate use it."""
+def toy_config() -> ModelConfig:
+    """The bench's model: the qwen3-0.6b smoke config cut to 1 layer at
+    ``d_model=16``, ``d_ff=24``, vocab 32, with ternary MLPs."""
     base = get_smoke_config("qwen3-0.6b")
-    cfg = base.with_(n_layers=1, d_model=16, d_ff=24, n_heads=2,
-                     n_kv_heads=2, head_dim=8, vocab=32,
-                     ternary=base.ternary.__class__(enabled=True))
+    return base.with_(n_layers=1, d_model=16, d_ff=24, n_heads=2,
+                      n_kv_heads=2, head_dim=8, vocab=32,
+                      ternary=TernaryCfg(enabled=True))
+
+
+def build_engine(cfg: ModelConfig | None = None, *, n_arrays: int = 4,
+                 rows: int = 64, cols: int = 64, faults=None) -> Engine:
+    """An Engine whose packed-ternary MLPs run on an AP bank of
+    ``n_arrays`` x ``rows`` x ``cols`` arrays.
+
+    ``cfg`` (default :func:`toy_config`) must enable ternary packing;
+    weights are random from a fixed seed.  ``faults`` (a
+    :class:`repro.apc.FaultConfig`) installs the seeded device fault model
+    on the bank — the faults_bench sweep and the degraded-bank smoke gate
+    use it."""
+    cfg = toy_config() if cfg is None else cfg
     mesh = make_smoke_mesh()
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     qparams = quantize_model_params(params)
-    pool = apc.ArrayPool(n_arrays=n_arrays, rows=rows, cols=64,
+    pool = apc.ArrayPool(n_arrays=n_arrays, rows=rows, cols=cols,
                          faults=faults)
     ctx = apc.APServeContext(apc.Runtime(pool), x_levels=7)
     return Engine(cfg, qparams, mesh, ServeCfg(max_len=8), ap_ctx=ctx)
@@ -149,6 +160,7 @@ def main() -> None:
     p.add_argument("--json", default=os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "apc_bench.json"))
     args = p.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parent.parent)
     if args.smoke:
         points = [(4.0, 4), (16.0, 6)]
     else:

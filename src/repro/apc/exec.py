@@ -36,7 +36,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.ap import APStats
 from ..kernels.tap_pass.kernel import tap_run_program
 from ..kernels.tap_pass.ops import _pad_rows
@@ -117,8 +116,8 @@ def sharded_program_run(padded: jax.Array, sched: tuple, mesh, axes,
         return out, jnp.zeros((), jnp.int32)
 
     spec_in = P(axes if len(axes) > 1 else axes[0])
-    f = shard_map(per_shard, mesh=mesh, in_specs=(spec_in,),
-                  out_specs=(spec_in, P()))
+    f = jax.shard_map(per_shard, mesh=mesh, in_specs=(spec_in,),
+                      out_specs=(spec_in, P()), check_vma=False)
     return f(padded)
 
 

@@ -333,7 +333,8 @@ def resolve_schedule(compiled: CompiledProgram,
     :func:`default_kernel_variant`.  Programs whose steps carry duplicate
     write or compare columns fall back to the gather body (the only
     bit-exact one for serial same-column writes / per-position mismatch
-    counting); ``onehot_packed`` additionally falls back to the flat
+    counting) off TPU, and raise on TPU, where that body has no compiled
+    lowering; ``onehot_packed`` additionally falls back to the flat
     one-hot schedule when list scheduling found nothing to pack, or when
     group-width padding would inflate the slot grid faster than the trip
     count shrinks (carry-ripple chains pin most slots to 1-wide groups).
@@ -344,6 +345,12 @@ def resolve_schedule(compiled: CompiledProgram,
             f"kernel_variant must be one of {KERNEL_VARIANTS}, got {kv!r}")
     if kv != "gather" and not (compiled.writes_distinct
                                and compiled.compares_distinct):
+        import jax                      # local: keep lowering importable
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                "program has duplicate write or compare columns in one "
+                "step; only the gather body replays those, and it does not "
+                "compile under Mosaic")
         kv = "gather"
     if kv == "onehot_packed":
         p = compiled.packed(max_pack)

@@ -9,36 +9,44 @@ with exactly ONE HBM read and ONE HBM write per block.
 Layout: digits [rows, cols] int8, rows is the parallel axis (grid dim 0),
 cols the operand digit columns (2p+1 for a p-digit add).
 
-Two step-body formulations for the whole-program executor:
+Two step-body formulations for the whole-program executor, both reading the
+schedule one scalar at a time (:class:`SlotLayout`):
 
-- ``variant="gather"`` — the original body: per-step dynamic column gathers
-  (``jnp.take``) for the compare and a serial ``dynamic_update_index_in_dim``
-  chain for the writes.  Runs everywhere in interpret mode; lane-hostile on
-  real vector hardware (dynamic cross-lane indexing in the loop body).
-- ``variant="onehot"`` — the AP-native formulation: the compare becomes a
-  one-hot matmul (``block @ onehot(cmp_cols)``, an int8 MXU contraction on
-  TPU) and each write a ``jnp.where(col_mask & tag[:, None], vals, block)``
-  blend over the full row.  No dynamic indexing anywhere, so the body
-  compiles (``interpret=False``): Mosaic on TPU, plain XLA elsewhere.  With
-  ``pack > 1`` each fori_loop iteration replays a whole VLIW group of
-  mutually independent slots (see :class:`repro.apc.lower.PackedProgram`)
-  against the pre-group block and lands all writes in one blend.
+- ``variant="gather"`` — the original body: per-step dynamic column reads
+  for the compare and a serial ``dynamic_update_index_in_dim`` chain for
+  the writes.  Runs in the pallas interpreter and the jitted-XLA harness;
+  it has no Mosaic lowering (dynamic cross-lane indexing), so a compiled
+  run on TPU refuses it.
+- ``variant="onehot"`` — the AP-native formulation: the compare columns
+  and key expand into a one-hot mask/value plane over the full row, the
+  compare is a masked equality reduction, and each write a
+  ``jnp.where(col_mask & tag, vals, block)`` blend.  No dynamic indexing
+  and only rank-2 temporaries, so the body compiles under Mosaic on TPU
+  (and plain XLA elsewhere).  With ``pack > 1`` each fori_loop iteration
+  replays a whole VLIW group of mutually independent slots (see
+  :class:`repro.apc.lower.PackedProgram`) against the pre-group block and
+  lands all writes in one blend.
 
 Both formulations are bit-identical — digits AND traced counters, including
 the mismatch histogram's saturating top bin (pinned by tests/test_pack.py).
 
-Block shape: (BLOCK_ROWS, cols) with BLOCK_ROWS a multiple of the 8x128 VREG
-tile (default 1024 rows => 1024 x cols int8 in VMEM, ~48 KB for 20-trit adds,
-well inside the ~16 MB VMEM budget, leaving room for double buffering).
+The pallas kernel's grid is (row block, schedule chunk): the digit block
+(``block_rows`` x cols int8, 1 MiB for the default 4096 x 256 bank array)
+stays in VMEM across the chunks while each chunk's schedule words stream
+through SMEM.
 """
 from __future__ import annotations
 
 import functools
+import math
+import operator
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import DONT_CARE, Step
 
@@ -113,179 +121,356 @@ def _tap_kernel(arr_ref, out_ref, *, schedule: tuple[Step, ...]):
 # Whole-program step body (shared by the pallas kernel and the XLA path)
 # ---------------------------------------------------------------------------
 
-def _program_block_body(block, row_ok, sched, *, collect_stats: bool,
-                        hist_bins: int, unroll: int, variant: str,
-                        pack: int):
-    """Replay the packed schedule tensors on one resident row-block.
+# The pallas kernel's per-block counter tile: (8, 128) int32, the traced
+# counters [sets, resets, hist[0..hist_bins)] in the lanes of its row 0.
+COUNT_LANES = 128
+# Schedule slots per grid step along the pallas kernel's schedule axis.  The
+# schedule is read as scalars from SMEM (1 MiB on v5e), one chunk per grid
+# step, so program length is not bounded by it: a 9k-step MAC tile at 16
+# words per slot would need 570 KiB whole, a chunk needs 64 KiB.
+SCHED_CHUNK = 1024
+# Rows per inner sub-block of the compiled (Mosaic) kernel: each sub-block
+# replays the whole schedule chunk with its digits as the loop carry, and
+# sub-blocks holding only padding rows are skipped.  On a v5e, a 9129-step
+# MAC tile over 4096 x 250 digits with counters took 10.6 / 9.3 / 9.0 /
+# 9.1 us per step at 256 / 512 / 1024 / 4096 rows.
+TPU_SUB_ROWS = 1024
 
-    ``block`` [rows, cols] int8, ``row_ok`` [rows] bool (padding rows masked
-    out of writes and counters), ``sched`` the 6 dense schedule tensors.
-    Returns ``(out_block, sets, resets, hist)`` — the counters are zeros
-    when ``collect_stats`` is off (no extra compute on that path).
+
+class SlotLayout(NamedTuple):
+    """Field offsets of one schedule slot in the flat int32 word stream:
+    ``[cmp_cols(C) | keys(K*C) | key_valid(K) | hist_flag | wr_cols(W) |
+    wr_vals(W)]``."""
+    n_c: int
+    n_k: int
+    n_w: int
+
+    @property
+    def key(self) -> int:
+        return self.n_c
+
+    @property
+    def kv(self) -> int:
+        return self.n_c * (1 + self.n_k)
+
+    @property
+    def hist(self) -> int:
+        return self.kv + self.n_k
+
+    @property
+    def wc(self) -> int:
+        return self.hist + 1
+
+    @property
+    def wv(self) -> int:
+        return self.wc + self.n_w
+
+    @property
+    def width(self) -> int:
+        return self.wv + self.n_w
+
+
+def _schedule_words(cmp_cols, keys, key_valid, hist_flag, wr_cols, wr_vals
+                   ) -> tuple[jax.Array, SlotLayout]:
+    """The 6 dense schedule tensors as one ``[S, F]`` int32 word matrix."""
+    n = cmp_cols.shape[0]
+    words = jnp.concatenate(
+        [jnp.asarray(t).astype(jnp.int32).reshape(n, -1)
+         for t in (cmp_cols, keys, key_valid, hist_flag, wr_cols, wr_vals)],
+        axis=1)
+    return words, SlotLayout(cmp_cols.shape[1], keys.shape[1],
+                             wr_cols.shape[1])
+
+
+def _noop_slots(n: int, lay: SlotLayout) -> jax.Array:
+    """``n`` slots that compare nothing and write nothing (no valid key,
+    every write column -1): the schedule padding every body skips."""
+    row = ([-1] * lay.n_c + [0] * (lay.wc - lay.n_c) + [-1] * lay.n_w
+           + [0] * lay.n_w)
+    return jnp.tile(jnp.asarray(row, jnp.int32)[None, :], (n, 1))
+
+
+def _count(mask):
+    """Set entries of a rank-2 mask, as a (1, 1) int32."""
+    return jnp.sum(jnp.where(mask, 1, 0), axis=(0, 1), keepdims=True)
+
+
+def _program_block_body(block, row_ok, slot, lay: SlotLayout, n_slots: int, *,
+                        collect_stats: bool, hist_bins: int, unroll: int,
+                        variant: str, pack: int):
+    """Replay ``n_slots`` schedule slots on one resident row-block.
+
+    ``block`` [rows, cols] digits, ``row_ok`` [rows, 1] bool (padding rows
+    masked out of writes and counters), ``slot(s)`` a reader of slot ``s``:
+    ``slot(s)(off)`` is the int32 scalar at field ``off`` (see
+    :class:`SlotLayout`).  Every temporary is rank 2 and the schedule is
+    read one scalar at a time, so the same body runs under Mosaic, the
+    pallas interpreter and plain XLA.  Returns ``(out_block, counts)`` with
+    ``counts`` the (1, 2 + hist_bins) int32 counter row [sets, resets,
+    hist...] (zeros when ``collect_stats`` is off).
     """
-    cmp_cols, keys, key_valid, hist_flag, wr_cols, wr_vals = sched
     rows, n_cols = block.shape
-    n_steps, n_w = wr_cols.shape
-    n_c = cmp_cols.shape[1]
-    n_k = keys.shape[1]
-    if n_steps % pack:
-        raise ValueError(f"{n_steps} schedule slots not a multiple of "
+    dt = block.dtype
+    if n_slots % pack:
+        raise ValueError(f"{n_slots} schedule slots not a multiple of "
                          f"pack={pack}")
     col_iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2 + hist_bins), 1)
 
-    def slot_compare(blk, s, hist):
-        """Tag vector (+ histogram update) for slot ``s`` vs ``blk``."""
-        cc = cmp_cols[s]                              # (C,) int32, -1 pad
-        kv = key_valid[s]                             # (K,) bool
+    def bump(counts, idx, n):
+        return counts + jnp.where(lane == idx, n, 0)
+
+    def slot_compare(blk, rd, counts):
+        """Tag column (+ histogram update) for the slot ``rd`` reads."""
+        cols = [rd(c) for c in range(lay.n_c)]
         if variant == "gather":
-            sub = jnp.take(blk, jnp.maximum(cc, 0), axis=1)     # (rows, C)
-            key_s = keys[s]                           # (K, C)
-            c_ok = cc >= 0
-            miss = (sub[:, None, :] != key_s[None, :, :]) & \
-                   (sub[:, None, :] != DONT_CARE) & \
-                   c_ok[None, None, :]                # (rows, K, C)
+            sub = [jax.lax.dynamic_index_in_dim(blk, jnp.maximum(c, 0),
+                                                axis=1) for c in cols]
         else:
             # one-hot formulation: expand the compare columns + key into a
-            # full-row mask/value plane (tiny C x n_cols one-hot ops, pad
-            # cc=-1 rows all-zero; compare columns are distinct, enforced
-            # by resolve_schedule) — the compare itself is then a masked
-            # equality reduction over whole rows, the AP's "broadcast key
-            # to every cell" with zero dynamic indexing
-            oh = (cc[:, None] == col_iota).astype(jnp.int8)     # (C, n_cols)
-            cmp_mask = oh.any(axis=0)                           # (n_cols,)
-            key_vals = jax.lax.dot_general(                     # (K, n_cols)
-                keys[s], oh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int8)
-            if n_k == 1:                  # non-blocked schedules: keep the
-                miss = (blk != key_vals) & \
-                       (blk != DONT_CARE) & \
-                       cmp_mask[None, :]  # temporaries 2-D ((rows, n_cols))
+            # full-row mask/value plane (pad columns -1 never hit the iota;
+            # compare columns are distinct, enforced by resolve_schedule)
+            # — the compare itself is then a masked equality reduction over
+            # whole rows, the AP's "broadcast key to every cell" with zero
+            # dynamic indexing
+            hits = [col_iota == c for c in cols]
+            cmp_mask = functools.reduce(operator.or_, hits)
+        hist_on = rd(lay.hist) != 0
+        kvs = []
+        tag = None
+        for k in range(lay.n_k):
+            kv = rd(lay.kv + k) != 0
+            kvs.append(kv)
+            key = [rd(lay.key + k * lay.n_c + c) for c in range(lay.n_c)]
+            if variant == "gather":
+                mm = sum(jnp.where((sub[c] != key[c].astype(dt))
+                                   & (sub[c] != DONT_CARE) & (cols[c] >= 0),
+                                   1, 0) for c in range(lay.n_c))
             else:
-                miss = (blk[:, None, :] != key_vals[None, :, :]) & \
-                       (blk[:, None, :] != DONT_CARE) & \
-                       cmp_mask[None, None, :]        # (rows, K, n_cols)
-            if miss.ndim == 2:
-                miss = miss[:, None, :]
-        if collect_stats:
+                key_row = sum(jnp.where(hits[c], key[c], 0)
+                              for c in range(lay.n_c)).astype(dt)
+                miss = (blk != key_row) & (blk != DONT_CARE) & cmp_mask
+                mm = jnp.sum(jnp.where(miss, 1, 0), axis=1, keepdims=True)
             # mismatch count doubles as the matcher: full match <=> mm == 0
-            mm = jnp.sum(miss, axis=2, dtype=jnp.int32)         # (rows, K)
-            tag = ((mm == 0) & kv[None, :]).any(axis=1)
-            counted = kv[None, :] & hist_flag[s] & row_ok[:, None]
-            # mm <= #compare columns (n_c), so higher bins are statically
-            # zero; when mm can exceed the bin range the top bin saturates
-            # (>= hist_bins-1 mismatches) instead of dropping mass
-            for b in range(min(hist_bins, n_c + 1)):
-                in_bin = ((mm >= b) if b == hist_bins - 1 < n_c
-                          else (mm == b))
-                hist = hist.at[b].add(
-                    jnp.sum(in_bin & counted, dtype=jnp.int32))
-        else:
-            tag = (~miss.any(axis=2) & kv[None, :]).any(axis=1)
-        tag = jnp.where(kv.any(), tag, True) & row_ok
-        return tag, hist
+            hit = (mm == 0) & kv
+            tag = hit if tag is None else tag | hit
+            if collect_stats:
+                # one lane per histogram bin: row r lands in lane
+                # 2 + min(mm, hist_bins - 1) — the top bin saturates
+                # (>= hist_bins-1 mismatches) instead of dropping mass
+                counted = row_ok & (kv & hist_on)
+                in_bin = (2 + jnp.minimum(mm, hist_bins - 1) == lane) \
+                    & counted
+                counts = counts + jnp.sum(jnp.where(in_bin, 1, 0), axis=0,
+                                          keepdims=True)
+        any_kv = functools.reduce(operator.or_, kvs)
+        return (tag | ~any_kv) & row_ok, counts
 
     def step_gather(s, carry):
-        block, sets, resets, hist = carry
-        tag, hist = slot_compare(block, s, hist)
-        for w in range(n_w):
-            col = jnp.maximum(wr_cols[s, w], 0)
-            w_ok = wr_cols[s, w] >= 0
-            v = wr_vals[s, w]
-            old = jax.lax.dynamic_index_in_dim(block, col, axis=1,
-                                               keepdims=False)
-            changed = tag & (old != v) & w_ok
+        block, counts = carry
+        rd = slot(s)
+        tag, counts = slot_compare(block, rd, counts)
+        for w in range(lay.n_w):
+            wc = rd(lay.wc + w)
+            col = jnp.maximum(wc, 0)
+            v = rd(lay.wv + w).astype(dt)
+            old = jax.lax.dynamic_index_in_dim(block, col, axis=1)
+            changed = tag & (old != v) & (wc >= 0)
             if collect_stats:
-                sets = sets + jnp.sum(changed, dtype=jnp.int32)
-                resets = resets + jnp.sum(changed & (old != DONT_CARE),
-                                          dtype=jnp.int32)
+                counts = bump(counts, 0, _count(changed))
+                counts = bump(counts, 1,
+                              _count(changed & (old != DONT_CARE)))
             block = jax.lax.dynamic_update_index_in_dim(
                 block, jnp.where(changed, v, old), col, axis=1)
-        return block, sets, resets, hist
+        return block, counts
 
     def step_onehot(g, carry):
-        block, sets, resets, hist = carry
+        block, counts = carry
         # all slots of the group compare against (and count set/reset deltas
         # vs) the pre-group block; the pack pass guarantees slots are
         # mutually independent, so the single combined blend below equals
         # serial application slot by slot — bit-exactly, counters included
-        apply = jnp.zeros((rows, n_cols), jnp.bool_)
-        gval = jnp.zeros((n_cols,), jnp.int8)
+        apply = None
+        gval = None
         for p in range(pack):
-            s = g * pack + p
-            tag, hist = slot_compare(block, s, hist)
-            w_oh = wr_cols[s][:, None] == col_iota    # (W, n_cols); -1 pads
-            wmask = w_oh.any(axis=0)                  # never match the iota
-            wval = jnp.sum(w_oh * wr_vals[s][:, None], axis=0,
-                           dtype=jnp.int32).astype(jnp.int8)
-            slot_apply = tag[:, None] & wmask[None, :]
+            rd = slot(g * pack + p)
+            tag, counts = slot_compare(block, rd, counts)
+            w_hits = [col_iota == rd(lay.wc + w) for w in range(lay.n_w)]
+            wmask = functools.reduce(operator.or_, w_hits)
+            wval = sum(jnp.where(w_hits[w], rd(lay.wv + w), 0)
+                       for w in range(lay.n_w))
+            slot_apply = tag & wmask
             if collect_stats:
-                changed = slot_apply & (block != wval[None, :])
-                sets = sets + jnp.sum(changed, dtype=jnp.int32)
-                resets = resets + jnp.sum(changed & (block != DONT_CARE),
-                                          dtype=jnp.int32)
-            apply = apply | slot_apply
-            gval = gval + wval                        # disjoint write columns
-        block = jnp.where(apply, gval[None, :], block)
-        return block, sets, resets, hist
+                changed = slot_apply & (block != wval.astype(dt))
+                counts = bump(counts, 0, _count(changed))
+                counts = bump(counts, 1,
+                              _count(changed & (block != DONT_CARE)))
+            apply = slot_apply if apply is None else apply | slot_apply
+            gval = wval if gval is None else gval + wval   # disjoint cols
+        block = jnp.where(apply, gval.astype(dt), block)
+        return block, counts
 
-    zero = jnp.zeros((), jnp.int32)
-    init = (block, zero, zero, jnp.zeros((hist_bins,), jnp.int32))
     step = step_gather if variant == "gather" else step_onehot
-    return jax.lax.fori_loop(0, n_steps // pack, step, init, unroll=unroll)
+    n_iter = n_slots // pack
+
+    def unrolled(t, carry):
+        # Mosaic's loop lowering unrolls only fully or not at all, so
+        # ``unroll`` steps are traced into one trip by hand
+        for u in range(unroll):
+            carry = step(t * unroll + u, carry)
+        return carry
+
+    carry = jax.lax.fori_loop(0, n_iter // unroll, unrolled,
+                              (block, jnp.zeros_like(lane)))
+    for t in range(n_iter - n_iter % unroll, n_iter):
+        carry = step(t, carry)
+    return carry
 
 
-def _tap_program_kernel(n_valid_ref, cmp_cols_ref, keys_ref, key_valid_ref,
-                        hist_flag_ref, wr_cols_ref, wr_vals_ref, arr_ref,
-                        out_ref, *stats_refs, block_rows: int,
+def _tap_program_kernel(n_valid_ref, words_ref, arr_ref, out_ref,
+                        *stats_refs, lay: SlotLayout, chunk: int,
+                        block_rows: int, sub_rows: int, interpret: bool,
                         collect_stats: bool, hist_bins: int, unroll: int,
                         variant: str, pack: int):
-    """Pallas wrapper: lax.fori_loop over a baked schedule tensor.
+    """Pallas body: grid (row block ``i``, schedule chunk ``j``).
 
-    Unlike :func:`_tap_kernel` (schedule unrolled into the trace — fine for
-    one LUT sweep, hopeless for a 5k-step multiply program), this body traces
-    ONE generic step and loops over the dense schedule tensors, so trace time
-    is O(1) in program length.  Stats are carried through the loop and
-    written once per row-block; rows past ``n_valid_rows`` (block padding)
-    are masked out of both writes and counters.
+    The output block stays resident across ``j`` (its index ignores ``j``):
+    chunk 0 copies the input digits in, every chunk replays its ``chunk``
+    slots — read as scalars from the SMEM chunk — on each ``sub_rows`` row
+    sub-block in turn, and the counters accumulate into row 0 of the block's
+    (8, COUNT_LANES) stats tile.  Unlike :func:`_tap_kernel` (schedule
+    unrolled into the trace — fine for one LUT sweep, hopeless for a 5k-step
+    multiply program), trace time is O(1) in program length.  Rows past
+    ``n_valid`` (block padding) are masked out of writes and counters, and
+    sub-blocks holding none of the valid rows are skipped.
     """
-    i = pl.program_id(0)
-    block = arr_ref[...]                              # [block_rows, cols] int8
-    rows = block.shape[0]
-    row_ok = (i * block_rows
-              + jax.lax.broadcasted_iota(jnp.int32, (rows,), 0)
-              ) < n_valid_ref[0]
-    sched = tuple(r[...] for r in (cmp_cols_ref, keys_ref, key_valid_ref,
-                                   hist_flag_ref, wr_cols_ref, wr_vals_ref))
-    block, sets, resets, hist = _program_block_body(
-        block, row_ok, sched, collect_stats=collect_stats,
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        out_ref[...] = arr_ref[...]
+        if collect_stats:
+            stats_refs[0][...] = jnp.zeros(stats_refs[0].shape, jnp.int32)
+
+    n_local = n_valid_ref[0] - i * block_rows
+
+    def slot(s):
+        if not interpret:                       # Mosaic: SMEM scalar loads
+            return lambda off: words_ref[s * lay.width + off]
+        # interpreter: one slice per slot, then static indexing
+        row = words_ref[pl.ds(s * lay.width, lay.width)]
+        return lambda off: row[off]
+
+    # Mosaic has no int8 vector compares on this path: digits are widened
+    # to int32 for the replay and narrowed again on the store
+    compute_dtype = jnp.int8 if interpret else jnp.int32
+
+    def sub_block(r, carry):
+        r0 = pl.multiple_of(r * sub_rows, sub_rows)
+
+        @pl.when(r0 < n_local)
+        def _run():
+            blk = out_ref[pl.ds(r0, sub_rows), :].astype(compute_dtype)
+            row_ok = (r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (sub_rows, 1), 0)) < n_local
+            blk, counts = _program_block_body(
+                blk, row_ok, slot, lay, chunk, collect_stats=collect_stats,
+                hist_bins=hist_bins, unroll=unroll, variant=variant,
+                pack=pack)
+            out_ref[pl.ds(r0, sub_rows), :] = blk.astype(out_ref.dtype)
+            if collect_stats:
+                stats_refs[0][0:1, :2 + hist_bins] += counts
+
+        return carry
+
+    jax.lax.fori_loop(0, block_rows // sub_rows, sub_block, 0)
+
+
+def _tap_program_pallas(arr, sched, n_valid, *, block_rows: int,
+                        collect_stats: bool, hist_bins: int, interpret: bool,
+                        unroll: int, variant: str, pack: int):
+    """The pallas launch of a whole program (Mosaic on TPU, or the pallas
+    interpreter).  ``sched`` is the 6 dense schedule tensors, ``n_valid``
+    the int32 count of real rows; returns ``(out, counts)`` with ``counts``
+    the per-grid-block (grid, 2 + hist_bins) counters, or None.
+
+    Kept free of backend probing so a test can lower it for a described
+    TPU that is not attached."""
+    rows, cols = arr.shape
+    if rows % block_rows:
+        raise ValueError(f"rows={rows} not a multiple of {block_rows}")
+    if 2 + hist_bins > COUNT_LANES:
+        raise ValueError(f"hist_bins={hist_bins} exceeds the counter row")
+    words, lay = _schedule_words(*sched)
+    n_slots = words.shape[0]
+    n_chunks = -(-n_slots // SCHED_CHUNK)
+    chunk = n_slots
+    if n_chunks > 1:
+        # a chunk holds whole VLIW groups, and a partial SMEM block must
+        # span a multiple of the 1024-word SMEM tiling
+        m = math.lcm(pack, 1024 // math.gcd(lay.width, 1024))
+        chunk = m * -(-n_slots // (n_chunks * m))
+    if n_chunks * chunk > n_slots:
+        words = jnp.concatenate(
+            [words, _noop_slots(n_chunks * chunk - n_slots, lay)])
+    sub_rows = (TPU_SUB_ROWS if not interpret and block_rows > TPU_SUB_ROWS
+                and block_rows % TPU_SUB_ROWS == 0 else block_rows)
+    grid = (rows // block_rows, n_chunks)
+    kernel = functools.partial(
+        _tap_program_kernel, lay=lay, chunk=chunk, block_rows=block_rows,
+        sub_rows=sub_rows, interpret=interpret, collect_stats=collect_stats,
         hist_bins=hist_bins, unroll=unroll, variant=variant, pack=pack)
-    out_ref[...] = block
+    digits = pl.BlockSpec((block_rows, cols), lambda i, j: (i, 0))
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((chunk * lay.width,), lambda i, j: (j,),
+                             memory_space=pltpu.SMEM),
+                digits]
+    out_specs = [digits]
+    out_shape = [jax.ShapeDtypeStruct((rows, cols), jnp.int8)]
     if collect_stats:
-        stats_refs[0][...] = jnp.concatenate(
-            [sets[None], resets[None], hist])[None, :]
+        out_specs.append(pl.BlockSpec((8, COUNT_LANES), lambda i, j: (i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((grid[0] * 8, COUNT_LANES),
+                                              jnp.int32))
+    res = pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(jnp.reshape(n_valid, (1,)).astype(jnp.int32), words.reshape(-1),
+      jnp.asarray(arr, jnp.int8))
+    if not collect_stats:
+        return res[0], None
+    counts = res[1].reshape(grid[0], 8, COUNT_LANES)[:, 0, :2 + hist_bins]
+    return res[0], counts
 
 
 def _tap_program_xla(padded, sched, n_valid, *, block_rows: int,
                      collect_stats: bool, hist_bins: int, unroll: int,
                      variant: str, pack: int):
-    """Compiled-XLA path: the same step body vmapped over row-blocks.
+    """Compiled-XLA path: the same step body mapped over row-blocks.
 
     Used when ``interpret=False`` on a non-TPU backend, where pallas has no
-    compiled lowering — the one-hot body is ordinary static vector algebra,
-    so plain jit gives the compiled semantics (and per-block counter layout)
+    compiled lowering — the body is ordinary static vector algebra, so
+    plain jit gives the compiled semantics (and per-block counter layout)
     the TPU kernel has, bit-identically.
     """
     rows, cols = padded.shape
     grid = rows // block_rows
+    words, lay = _schedule_words(*sched)
+
+    def slot(s):
+        row = jax.lax.dynamic_index_in_dim(words, s, keepdims=False)
+        return lambda off: row[off]
 
     def per_block(args):
         i, blk = args
-        row_ok = (i * block_rows
-                  + jnp.arange(block_rows, dtype=jnp.int32)) < n_valid[0]
-        out, sets, resets, hist = _program_block_body(
-            blk, row_ok, sched, collect_stats=collect_stats,
-            hist_bins=hist_bins, unroll=unroll, variant=variant, pack=pack)
-        return out, jnp.concatenate([sets[None], resets[None], hist])
+        row_ok = (i * block_rows + jax.lax.broadcasted_iota(
+            jnp.int32, (block_rows, 1), 0)) < n_valid
+        out, counts = _program_block_body(
+            blk, row_ok, slot, lay, words.shape[0],
+            collect_stats=collect_stats, hist_bins=hist_bins, unroll=unroll,
+            variant=variant, pack=pack)
+        return out, counts[0]
 
     # sequential lax.map over row-blocks, mirroring the pallas grid — vmap
     # batches the gather body's dynamic updates into scatter HLO that XLA
@@ -319,6 +504,7 @@ def tap_run_program(arr: jax.Array, cmp_cols: jax.Array, keys: jax.Array,
     produces them).  ``interpret=None`` resolves per backend (see
     :func:`resolve_interpret`); ``interpret=False`` off-TPU runs the jitted
     XLA harness — same body, same per-block counter layout, bit-identical.
+    The gather body has no Mosaic lowering: compiled on TPU it raises.
     """
     if variant not in VARIANTS:
         raise ValueError(f"variant must be one of {VARIANTS}, got {variant!r}")
@@ -330,10 +516,15 @@ def tap_run_program(arr: jax.Array, cmp_cols: jax.Array, keys: jax.Array,
     # env-default resolution happens OUT here, before the jit boundary —
     # inside it the resolved value would be baked into the cache entry
     # keyed on the None static and never re-read on cache hits
+    interpret = resolve_interpret(interpret)
+    if variant == "gather" and not interpret \
+            and jax.default_backend() == "tpu":
+        raise ValueError("the gather body (dynamic column indexing) does "
+                         "not compile under Mosaic; run variant='onehot'")
     return _tap_run_program_jit(
         arr, cmp_cols, keys, key_valid, hist_flag, wr_cols, wr_vals,
         n_valid_rows, block_rows=block_rows, collect_stats=collect_stats,
-        hist_bins=hist_bins, interpret=resolve_interpret(interpret),
+        hist_bins=hist_bins, interpret=interpret,
         unroll=resolve_unroll(unroll, variant, pack), variant=variant,
         pack=pack)
 
@@ -346,45 +537,27 @@ def _tap_run_program_jit(arr, cmp_cols, keys, key_valid, hist_flag,
                          collect_stats: bool, hist_bins: int,
                          interpret: bool, unroll: int, variant: str,
                          pack: int):
-    rows, cols = arr.shape
+    rows = arr.shape[0]
     if rows % block_rows:
         raise ValueError(f"rows={rows} not a multiple of {block_rows}")
-    grid = (rows // block_rows,)
-    n_valid = jnp.asarray(n_valid_rows, jnp.int32).reshape((1,))
+    sched = (cmp_cols, keys, key_valid, hist_flag, wr_cols, wr_vals)
+    n_valid = jnp.asarray(n_valid_rows, jnp.int32)
     body_kw = dict(block_rows=block_rows, collect_stats=collect_stats,
                    hist_bins=hist_bins, unroll=unroll, variant=variant,
                    pack=pack)
     if not interpret and jax.default_backend() != "tpu":
-        sched = (cmp_cols, keys, key_valid, hist_flag, wr_cols, wr_vals)
-        out, counts = _tap_program_xla(jnp.asarray(arr, jnp.int8), sched,
-                                       n_valid, **body_kw)
-        return out, (counts if collect_stats else None)
-    full = lambda t: pl.BlockSpec(t.shape, lambda i: (0,) * t.ndim)
-    kernel = functools.partial(_tap_program_kernel, **body_kw)
-    in_specs = [full(n_valid), full(cmp_cols), full(keys), full(key_valid),
-                full(hist_flag), full(wr_cols), full(wr_vals),
-                pl.BlockSpec((block_rows, cols), lambda i: (i, 0))]
-    out_specs = [pl.BlockSpec((block_rows, cols), lambda i: (i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((rows, cols), jnp.int8)]
-    if collect_stats:
-        out_specs.append(pl.BlockSpec((1, 2 + hist_bins), lambda i: (i, 0)))
-        out_shape.append(
-            jax.ShapeDtypeStruct((grid[0], 2 + hist_bins), jnp.int32))
-    res = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret,
-    )(n_valid, cmp_cols, keys, key_valid, hist_flag, wr_cols, wr_vals, arr)
-    if collect_stats:
-        return res[0], res[1]
-    return res[0], None
+        return _tap_program_xla(jnp.asarray(arr, jnp.int8), sched, n_valid,
+                                **body_kw)
+    return _tap_program_pallas(arr, sched, n_valid, interpret=interpret,
+                               **body_kw)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("schedule", "block_rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("schedule", "block_rows"))
 def tap_apply_schedule(arr: jax.Array, schedule: tuple[Step, ...],
-                       block_rows: int = BLOCK_ROWS,
-                       interpret: bool = True) -> jax.Array:
-    """Apply a fused LUT schedule to the digit array via pallas_call.
+                       block_rows: int = BLOCK_ROWS) -> jax.Array:
+    """Apply a fused LUT schedule to the digit array via pallas_call, on
+    the pallas interpreter (the unrolled body has no Mosaic lowering; the
+    compiled route is :func:`tap_run_program`).
 
     ``arr``: [rows, cols] int8, rows % block_rows == 0 (pad with don't-care
     rows if needed — they never match and are returned unchanged).
@@ -399,5 +572,5 @@ def tap_apply_schedule(arr: jax.Array, schedule: tuple[Step, ...],
         in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.int8),
-        interpret=interpret,
+        interpret=True,
     )(arr)

@@ -13,7 +13,8 @@ from .ref import ripple_add_schedule, schedule_from_lut
 # (tap_run_program) instead of unrolling every pass into the trace: a 20-trit
 # non-blocked add is 421 steps, and the unrolled trace costs minutes to
 # build/compile per (schedule, shape) while the packed kernel traces one
-# generic step.  Short schedules keep the unrolled path (no gather overhead).
+# generic step.  Short schedules keep the unrolled path (no gather overhead)
+# when interpreted.
 UNROLL_STEP_LIMIT = 64
 
 
@@ -32,20 +33,11 @@ def _run_schedule(arr: jax.Array, sched, block_rows: int,
                   kernel_variant: str | None = None) -> jax.Array:
     """Dispatch a flat schedule to the unrolled or fori_loop kernel."""
     padded, rows = _pad_rows(arr, block_rows)
-    off_tpu = jax.default_backend() != "tpu"
-    # The unrolled pallas kernel has no compiled lowering off-TPU.  An
-    # env/backend-RESOLVED interpret=False (the REPRO_AP_INTERPRET=0
-    # lever) quietly stays on the interpreter there — the unrolled body is
-    # static ops either way — but an EXPLICIT interpret=False is honored
-    # by routing the short schedule through the program kernel, whose
-    # jitted-XLA harness is the compiled path on hosts.
-    if len(sched) <= UNROLL_STEP_LIMIT and not (interpret is False
-                                                and off_tpu):
-        interp = resolve_interpret(interpret)
-        if off_tpu:
-            interp = True
-        out = tap_apply_schedule(padded, sched, block_rows=block_rows,
-                                 interpret=interp)
+    # The unrolled kernel runs on the pallas interpreter only; a compiled
+    # run (Mosaic on TPU, the jitted-XLA harness elsewhere) takes the
+    # program kernel, which compiles on both.
+    if len(sched) <= UNROLL_STEP_LIMIT and resolve_interpret(interpret):
+        out = tap_apply_schedule(padded, sched, block_rows=block_rows)
         return out[:rows]
     from ...apc.lower import (Step, _compile_steps,     # lazy: import cycle
                               resolve_schedule)

@@ -57,12 +57,12 @@ def _ternary_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k: int):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def ternary_matmul(x: jax.Array, packed: jax.Array, scale: jax.Array,
-                   bm: int = BM, bn: int = BN, bk: int = BK,
-                   interpret: bool = True) -> jax.Array:
+                   bm: int = BM, bn: int = BN, bk: int = BK, *,
+                   interpret: bool) -> jax.Array:
     """y[M, N] = (x[M, K] @ unpack(packed)) * scale, tiled on TPU.
 
     Shapes must tile exactly: M % bm == 0, N % bn == 0, K % bk == 0,
-    bk % 16 == 0.  (The ops.py wrapper pads.)
+    bk % 16 == 0.  (The ops.py wrapper pads and resolves ``interpret``.)
     """
     m, kdim = x.shape
     k16, n = packed.shape

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..tap_pass.kernel import resolve_interpret
 from .kernel import BK, BM, BN
 from .kernel import ternary_matmul as _ternary_matmul_kernel
 from .ref import PACK, pack_ternary, quantize_ternary, ternary_matmul_ref
@@ -20,10 +21,12 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 
 
 def ternary_matmul_op(x: jax.Array, packed: jax.Array, scale: jax.Array,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """y = (x @ unpack(packed)) * scale with automatic tile padding.
 
     x [M, K] float; packed [K/16, N] int32; scale [N] fp32 -> y [M, N].
+    ``interpret=None`` resolves per backend like the program kernel
+    (compiled Mosaic on TPU, the pallas interpreter elsewhere).
     """
     m, k = x.shape
     n = packed.shape[1]
@@ -42,7 +45,8 @@ def ternary_matmul_op(x: jax.Array, packed: jax.Array, scale: jax.Array,
         # 0b01 repeated = ternary 0 everywhere: zero padding weights
     pn = _pad_to(packed, 1, BN)
     sn = _pad_to(scale.reshape(-1), 0, BN)
-    y = _ternary_matmul_kernel(xk, pn, sn, bm=bm, interpret=interpret)
+    y = _ternary_matmul_kernel(xk, pn, sn, bm=bm,
+                               interpret=resolve_interpret(interpret))
     return y[:m, :n]
 
 

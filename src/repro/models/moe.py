@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import MoECfg
 from .common import MODEL_AXIS, act_fn, dense_init, mesh_data_axes
 
@@ -214,6 +213,6 @@ def moe_ffn(p: dict, x: jax.Array, cfg: MoECfg, act: str,
                     P(None, "data", MODEL_AXIS),
                     P(None, "data", MODEL_AXIS),
                     P(None, MODEL_AXIS, "data"))
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(da, None, None))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(da, None, None), check_vma=False)
     return fn(x, p["router"], p["w1"], p["w3"], p["w2"])

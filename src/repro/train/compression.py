@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import ModelConfig
 from ..models import model as M
 from .optimizer import AdamWCfg, adamw_update
@@ -46,7 +45,6 @@ def ternarize(g: jax.Array, scale: jax.Array, key: jax.Array
 
 def ternary_allreduce(grads, key: jax.Array, axis_names=DP_AXES):
     """Inside shard_map: all-reduce a gradient pytree in ternary wire format."""
-    # axis size, portably: jax.lax.axis_size only exists on jax >= 0.6
     n = jax.lax.psum(1, axis_names)
     leaves, treedef = jax.tree.flatten(grads)
     keys = jax.random.split(key, len(leaves))
@@ -99,8 +97,8 @@ def make_compressed_dp_step(cfg: ModelConfig, mesh, opt_cfg: AdamWCfg):
     batch_spec = P(dp_axes)
 
     def step(state, batch):
-        fn = shard_map(
-            body, mesh=mesh,
+        fn = jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(jax.tree.map(lambda _: P(), state),
                       jax.tree.map(lambda _: batch_spec, batch)),
             out_specs=(jax.tree.map(lambda _: P(), state),
