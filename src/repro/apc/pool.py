@@ -311,6 +311,17 @@ class ArrayPool:
             empty = jnp.zeros((1, 2 + HIST_BINS), jnp.int32)
             return (jnp.asarray(arr, jnp.int8),
                     TracedStats(empty) if collect_stats else None)
+        with trace.annotate("ap.pool.run"):
+            return self._run_blocks(arr, compiled, n_rows, block_valid,
+                                    collect_stats=collect_stats,
+                                    interpret=interpret,
+                                    kernel_variant=kernel_variant,
+                                    unroll=unroll)
+
+    def _run_blocks(self, arr, compiled, n_rows, block_valid, *,
+                    collect_stats, interpret, kernel_variant, unroll):
+        """The launch loop of :meth:`run`, from schedule upload to the
+        concatenated outputs and counters."""
         sched, variant, pack = self._device_schedule(compiled,
                                                      kernel_variant)
         arr = jnp.asarray(arr, jnp.int8)
@@ -344,41 +355,46 @@ class ArrayPool:
                 predicted_ns=wall["waves"] * program_ns).__enter__()
         try:
             for b in range(n_blocks):
-                lo = b * self.rows
-                block = arr[lo:min(lo + self.rows, n_rows)]
-                valid = block.shape[0] if block_valid is None \
-                    else block_valid[b]
-                padded, _ = _pad_rows(block, self.rows)
-                if tr is not None:
-                    w, a = divmod(b, self.n_arrays)
-                    if a == 0:
-                        if wave_span is not None:
-                            wave_span.__exit__(None, None, None)
-                        wave_span = tr.span(
-                            f"wave{w}", cat="pool",
-                            blocks=min(self.n_arrays, n_blocks - b),
-                            predicted_compare_cycles=(
-                                compiled.n_compare_cycles),
-                            predicted_write_cycles=compiled.n_write_cycles,
-                            predicted_ns=program_ns).__enter__()
-                    tr.instant("launch", cat="pool", block=b, array=a,
-                               rows=valid)
-                    tr.model_span(f"block{b}", track=f"arr{a}",
-                                  start_ns=run_span.ts_ns + w * program_ns,
-                                  dur_ns=program_ns, block=b, rows=valid)
-                # async dispatch: this launch targets array b % n_arrays
-                # while the next iteration encodes the following block
-                # (double buffering); bound in-flight launches to 2 per
-                # array
-                out, raw = tap_run_program(
-                    padded, *sched, jnp.int32(valid), block_rows=self.rows,
-                    collect_stats=collect_stats, hist_bins=HIST_BINS,
-                    interpret=interpret, unroll=unroll, variant=variant,
-                    pack=pack)
+                with trace.annotate("ap.pool.launch"):
+                    lo = b * self.rows
+                    block = arr[lo:min(lo + self.rows, n_rows)]
+                    valid = block.shape[0] if block_valid is None \
+                        else block_valid[b]
+                    padded, _ = _pad_rows(block, self.rows)
+                    if tr is not None:
+                        w, a = divmod(b, self.n_arrays)
+                        if a == 0:
+                            if wave_span is not None:
+                                wave_span.__exit__(None, None, None)
+                            wave_span = tr.span(
+                                f"wave{w}", cat="pool",
+                                blocks=min(self.n_arrays, n_blocks - b),
+                                predicted_compare_cycles=(
+                                    compiled.n_compare_cycles),
+                                predicted_write_cycles=(
+                                    compiled.n_write_cycles),
+                                predicted_ns=program_ns).__enter__()
+                        tr.instant("launch", cat="pool", block=b, array=a,
+                                   rows=valid)
+                        tr.model_span(f"block{b}", track=f"arr{a}",
+                                      start_ns=(run_span.ts_ns
+                                                + w * program_ns),
+                                      dur_ns=program_ns, block=b,
+                                      rows=valid)
+                    # async dispatch: this launch targets array
+                    # b % n_arrays while the next iteration encodes the
+                    # following block (double buffering)
+                    out, raw = tap_run_program(
+                        padded, *sched, jnp.int32(valid),
+                        block_rows=self.rows, collect_stats=collect_stats,
+                        hist_bins=HIST_BINS, interpret=interpret,
+                        unroll=unroll, variant=variant, pack=pack)
                 in_flight.append((out, raw, valid))
+                # bound in-flight launches to 2 per array
                 if len(in_flight) >= 2 * self.n_arrays:
                     oldest = in_flight.pop(0)
-                    jax.block_until_ready(oldest[0])
+                    with trace.annotate("ap.pool.drain"):
+                        jax.block_until_ready(oldest[0])
                     drain(oldest)
             if wave_span is not None:
                 wave_span.__exit__(None, None, None)
@@ -443,20 +459,22 @@ class ArrayPool:
         reg = get_registry()
         n_blocks = self.n_blocks(n_rows)
         outs, counts = [], []
-        with trace.span("pool.run_faulty", cat="pool", rows=n_rows,
-                        blocks=n_blocks, variant=variant):
+        with trace.span("pool.run_faulty", cat="pool", prof="ap.pool.run",
+                        rows=n_rows, blocks=n_blocks, variant=variant):
             for b in range(n_blocks):
-                lo = b * self.rows
-                block = arr[lo:min(lo + self.rows, n_rows)]
-                valid = block.shape[0] if block_valid is None \
-                    else block_valid[b]
-                padded, _ = _pad_rows(block, self.rows)
-                out, raw = tap_run_program(
-                    padded, *sched, jnp.int32(valid), block_rows=self.rows,
-                    collect_stats=collect_stats, hist_bins=HIST_BINS,
-                    interpret=interpret, unroll=unroll, variant=variant,
-                    pack=pack)
-                true_np = np.asarray(out)       # write driver's intent
+                with trace.annotate("ap.pool.launch"):
+                    lo = b * self.rows
+                    block = arr[lo:min(lo + self.rows, n_rows)]
+                    valid = block.shape[0] if block_valid is None \
+                        else block_valid[b]
+                    padded, _ = _pad_rows(block, self.rows)
+                    out, raw = tap_run_program(
+                        padded, *sched, jnp.int32(valid),
+                        block_rows=self.rows, collect_stats=collect_stats,
+                        hist_bins=HIST_BINS, interpret=interpret,
+                        unroll=unroll, variant=variant, pack=pack)
+                with trace.annotate("ap.pool.drain"):
+                    true_np = np.asarray(out)   # write driver's intent
                 healthy = self.healthy_arrays()
                 base = b % len(healthy)
                 stored = a = None
@@ -662,12 +680,14 @@ def run_mac_tiled(x: jax.Array, w_ter: jax.Array, tiled: TiledMac, *,
                                    unroll=unroll, radix=radix)
             drain_fault_charges(pool, stats)
         else:
-            out, traced = execute(arr, compiled,
-                                  collect_stats=stats is not None,
-                                  block_rows=block_rows,
-                                  interpret=interpret,
-                                  kernel_variant=kernel_variant,
-                                  unroll=unroll)
+            with trace.annotate("ap.pool.run"), \
+                    trace.annotate("ap.pool.launch"):
+                out, traced = execute(arr, compiled,
+                                      collect_stats=stats is not None,
+                                      block_rows=block_rows,
+                                      interpret=interpret,
+                                      kernel_variant=kernel_variant,
+                                      unroll=unroll)
         if stats is not None:
             accumulate(stats, traced, compiled, n_rows=R, label=label)
         return out

@@ -132,15 +132,18 @@ class DevicePool(ArrayPool):
         # per shard and the counters psummed by the shared scaffolding
         rows_per_dev = -(-n_rows // d)
         shard_rows = self.rows * max(1, -(-rows_per_dev // self.rows))
-        padded, _ = _pad_rows(jnp.asarray(arr, jnp.int8), d * shard_rows)
-        with trace.span("devicepool.run", cat="pool", rows=n_rows,
-                        n_devices=d, n_arrays=self.n_arrays,
-                        steps=compiled.n_steps, variant=variant):
-            out, raw = sharded_program_run(
-                padded, sched, self.mesh, self.axes, n_rows, self.rows,
-                collect_stats=collect_stats, interpret=interpret,
-                variant=variant, pack=pack, unroll=unroll)
-        out = out[:n_rows]
+        with trace.annotate("ap.pool.run"):
+            with trace.span("devicepool.run", cat="pool",
+                            prof="ap.pool.launch", rows=n_rows, n_devices=d,
+                            n_arrays=self.n_arrays, steps=compiled.n_steps,
+                            variant=variant):
+                padded, _ = _pad_rows(jnp.asarray(arr, jnp.int8),
+                                      d * shard_rows)
+                out, raw = sharded_program_run(
+                    padded, sched, self.mesh, self.axes, n_rows, self.rows,
+                    collect_stats=collect_stats, interpret=interpret,
+                    variant=variant, pack=pack, unroll=unroll)
+            out = out[:n_rows]
         if collect_stats:
             return out, TracedStats(raw)
         return out, None
@@ -266,7 +269,8 @@ class Runtime:
         collect = stats is not None or collect_stats
         tracer = trace.current_tracer()
         wave_of = {nid: w for w, ws in enumerate(waves) for nid in ws}
-        with trace.span("run_graph", cat="runtime", n_nodes=len(nodes),
+        with trace.span("run_graph", cat="runtime",
+                        prof="ap.runtime.run_graph", n_nodes=len(nodes),
                         n_waves=len(waves)) as gspan:
             # per-wavefront spans: a new one opens whenever the dispatch
             # order crosses a wavefront boundary, so a custom (non-wave-
@@ -296,7 +300,9 @@ class Runtime:
                                         node.compiled.n_compare_cycles),
                                     write_cycles=node.compiled.n_write_cycles,
                                     deps=list(node.deps)):
-                        arr = node.build(*(results[d] for d in node.deps))
+                        with trace.annotate("ap.model.graph_build"):
+                            arr = node.build(*(results[d]
+                                               for d in node.deps))
                         if arr.ndim != 2 or arr.shape[0] != node.rows:
                             raise ValueError(
                                 f"node {nid} ({node.label or 'unlabeled'}) "

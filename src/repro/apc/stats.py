@@ -76,7 +76,8 @@ def accumulate(stats: APStats, traced: TracedStats,
     the integers merged here, which is what makes the tracer's per-phase
     totals sum bit-identically to the aggregated APStats.
     """
-    counts = np.asarray(traced.block_counts, np.int64)  # the one host sync
+    with trace.annotate("ap.stats.sync"):               # the one host sync
+        counts = np.asarray(traced.block_counts, np.int64)
     sets = int(counts[:, 0].sum())
     resets = int(counts[:, 1].sum())
     stats.sets += sets

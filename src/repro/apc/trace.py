@@ -1,13 +1,37 @@
 """Structured tracing for the AP stack: nested spans, instants, Perfetto.
 
-Zero required dependencies (stdlib only) and strictly pay-for-what-you-use:
-every instrumentation site goes through the module-level front doors
-(:func:`span` / :func:`instant` / :func:`attribute`), which cost one
-contextvar read plus one env check when no tracer is active and return a
-shared no-op object — ``REPRO_AP_TRACE`` unset/0 leaves the executor
-trajectory untouched (the ``trace_overhead`` row in
-``benchmarks/apc_bench.json`` keeps that honest, and
-``tests/test_trace.py`` pins bit-identical digits/APStats either way).
+Strictly pay-for-what-you-use: every instrumentation site goes through the
+module-level front doors (:func:`span` / :func:`annotate` /
+:func:`instant` / :func:`attribute`), which cost one contextvar read plus
+one env check when no tracer is active and return a shared no-op object —
+``REPRO_AP_TRACE`` unset/0 leaves the executor trajectory untouched
+(``tests/test_trace.py`` pins bit-identical digits/APStats/tokens either
+way).
+
+Two sinks, one front door.  Besides the :class:`Tracer`, a span given a
+profiler name (``span(..., prof="ap.pool.run")``, or :func:`annotate` for
+a span the Tracer does not keep) opens a ``jax.profiler.TraceAnnotation``
+under that name, whether or not a Tracer is active.  While a profile is
+being taken (``jax.profiler.start_trace``) these land on the profiler's
+host plane, one line per thread, on the same clock as the device's
+``XLA Ops``; with the profiler off each costs well under a microsecond.
+Profiler names are static (``ap.<layer>.<what>``): indices, sizes and node
+labels stay Tracer-only names and args.  The names, where each is opened,
+and whether the host works or waits inside it:
+
+- ``ap.serve.wave`` — one batcher wave, joining the request threads (wait);
+- ``ap.serve.rendezvous`` — a request thread at the wave's barrier (wait);
+- ``ap.serve.merge`` — ``coalesce_graphs`` and the split of results per
+  request;
+- ``ap.serve.account`` — per-request makespan, sink defers, sink flush;
+- ``ap.model.step`` — one model step of a request, its token sync included;
+- ``ap.model.graph_build`` — quantize and projection graph build, and
+  each graph node's input build (the encode) where the runtime runs it;
+- ``ap.runtime.run_graph`` — one program graph through the runtime;
+- ``ap.pool.run`` — one program over the bank, output concatenate included;
+- ``ap.pool.launch`` — one launch: slice, pad, kernel dispatch;
+- ``ap.pool.drain`` — waiting on a launch's digits (wait);
+- ``ap.stats.sync`` — the device->host copy of traced counters (wait).
 
 Two clocks, one timeline:
 
@@ -53,11 +77,13 @@ from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Any, Iterator
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "TRACE_ENV", "Tracer", "SpanRecord", "InstantRecord",
     "AttributionRecord", "CounterRecord", "tracing", "disabled",
     "current_tracer", "global_tracer", "reset_global_tracer", "env_enabled",
-    "span", "instant", "attribute", "traced_compile",
+    "span", "annotate", "instant", "attribute", "traced_compile",
     "validate_chrome_trace",
 ]
 
@@ -182,6 +208,32 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+class _AnnotatedSpan:
+    """A Tracer span (or the no-op) inside a profiler annotation; ``with``
+    binds what the Tracer span binds."""
+
+    __slots__ = ("ann", "sp")
+
+    def __init__(self, prof: str, sp):
+        self.ann = TraceAnnotation(prof)
+        self.sp = sp
+
+    def set(self, **kw) -> "_AnnotatedSpan":
+        self.sp.set(**kw)
+        return self
+
+    def __enter__(self):
+        self.ann.__enter__()
+        return self.sp.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        try:
+            self.sp.__exit__(*exc)
+        finally:
+            self.ann.__exit__(*exc)
+        return False
 
 
 # ---------------------------------------------------------------------------
@@ -523,12 +575,20 @@ def disabled() -> Iterator[None]:
 # Module-level front doors (the zero-overhead-when-off entry points)
 # ---------------------------------------------------------------------------
 
-def span(name: str, cat: str = "host", track: str = "host", **args):
-    """Open a span on the active tracer, or a shared no-op when off."""
+def span(name: str, cat: str = "host", track: str = "host", *,
+         prof: str | None = None, **args):
+    """Open a span on the active tracer, or a shared no-op when off; with
+    ``prof``, also a profiler annotation of that static name (always)."""
     tr = current_tracer()
-    if tr is None:
-        return _NULL_SPAN
-    return tr.span(name, cat=cat, track=track, **args)
+    sp = _NULL_SPAN if tr is None else tr.span(name, cat=cat, track=track,
+                                                **args)
+    return sp if prof is None else _AnnotatedSpan(prof, sp)
+
+
+def annotate(prof: str) -> TraceAnnotation:
+    """A span on the profiler's clock only, under the static name ``prof``
+    (one of the names in the module docstring)."""
+    return TraceAnnotation(prof)
 
 
 def instant(name: str, cat: str | None = None, **args) -> None:

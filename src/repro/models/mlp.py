@@ -50,21 +50,30 @@ def mlp_ap(p: dict, x: jax.Array, act: str, ctx) -> jax.Array:
     to ``ctx.x_levels`` integers per projection — the AP arithmetic on the
     quantized grid is exact, and every compare/write cycle lands in
     ``ctx.stats`` for the per-request Table XI report."""
+    from ..apc import trace
     from ..apc.graph import ProgramGraph
     lead, d = x.shape[:-1], x.shape[-1]
     x2d = x.reshape(-1, d)
-    lin1 = ctx.linear("w1", p["w1_packed"], p["w1_scale"], label="mlp.w1")
-    lin3 = ctx.linear("w3", p["w3_packed"], p["w3_scale"], label="mlp.w3")
-    lin2 = ctx.linear("w2", p["w2_packed"], p["w2_scale"], label="mlp.w2")
-    x_int, s_x = ctx.quantize(x2d)
-    g1 = ProgramGraph()
-    c1 = lin1.add_call(g1, x_int, max_cols=ctx.max_cols, max_q=ctx.x_levels)
-    c3 = lin3.add_call(g1, x_int, max_cols=ctx.max_cols, max_q=ctx.x_levels)
+    with trace.annotate("ap.model.graph_build"):
+        lin1 = ctx.linear("w1", p["w1_packed"], p["w1_scale"],
+                          label="mlp.w1")
+        lin3 = ctx.linear("w3", p["w3_packed"], p["w3_scale"],
+                          label="mlp.w3")
+        lin2 = ctx.linear("w2", p["w2_packed"], p["w2_scale"],
+                          label="mlp.w2")
+        x_int, s_x = ctx.quantize(x2d)
+        g1 = ProgramGraph()
+        c1 = lin1.add_call(g1, x_int, max_cols=ctx.max_cols,
+                           max_q=ctx.x_levels)
+        c3 = lin3.add_call(g1, x_int, max_cols=ctx.max_cols,
+                           max_q=ctx.x_levels)
     res1 = ctx.run_graph(g1)
     h = act_fn(act)(c1.decode(res1, s_x)) * c3.decode(res1, s_x)
-    h_int, s_h = ctx.quantize(h)
-    g2 = ProgramGraph()
-    c2 = lin2.add_call(g2, h_int, max_cols=ctx.max_cols, max_q=ctx.x_levels)
+    with trace.annotate("ap.model.graph_build"):
+        h_int, s_h = ctx.quantize(h)
+        g2 = ProgramGraph()
+        c2 = lin2.add_call(g2, h_int, max_cols=ctx.max_cols,
+                           max_q=ctx.x_levels)
     res2 = ctx.run_graph(g2)
     y = c2.decode(res2, s_h)
     return y.reshape(*lead, y.shape[-1]).astype(x.dtype)

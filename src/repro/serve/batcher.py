@@ -138,22 +138,27 @@ class WaveMerger:
             [(n.compiled, n.rows, n.deps, n.upload_cycles)
              for n in graph.nodes])
         try:
-            if self._barrier.wait() == 0:        # all deposited; 0 leads
+            with trace.annotate("ap.serve.rendezvous"):
+                lead = self._barrier.wait() == 0    # all deposited; 0 leads
+            if lead:
                 try:
-                    self._merge_and_run(ctx)
+                    with trace.annotate("ap.serve.merge"):
+                        self._merge_and_run(ctx)
                 except BaseException as e:       # peers must not hang
                     self._run_error = e
-            self._barrier.wait()                 # results ready
+            with trace.annotate("ap.serve.rendezvous"):
+                self._barrier.wait()             # results ready
         except threading.BrokenBarrierError as e:
             raise WaveAborted("wave rendezvous broke") from e
         if self._run_error is not None:
             raise WaveAborted("merged wave run failed") from self._run_error
         view = self._views[slot]
-        sink.add_report(self._reports[slot])
-        for acc in self._accums[slot]:
-            sink.defer(*acc)
-        if self._power_defers[slot] is not None:
-            sink.defer_power(*self._power_defers[slot])
+        with trace.annotate("ap.serve.account"):
+            sink.add_report(self._reports[slot])
+            for acc in self._accums[slot]:
+                sink.defer(*acc)
+            if self._power_defers[slot] is not None:
+                sink.defer_power(*self._power_defers[slot])
         self._graphs[slot] = None
         return view
 
@@ -174,7 +179,8 @@ class WaveMerger:
             # exact numbers sequential serving would have recorded (and,
             # via ``rec``, the schedule its power timeline is placed on)
             rec: list = []
-            self._reports[slot] = self.runtime.makespan(g, record=rec)
+            with trace.annotate("ap.serve.account"):
+                self._reports[slot] = self.runtime.makespan(g, record=rec)
             self._views[slot] = MergedGraphView(res, m, self._reports[slot])
             accums = []
             traced_map: dict[int, TracedStats] = {}
@@ -508,8 +514,6 @@ class BatchServer:
                 h._was_queued = True
                 self.n_queued += 1
         self.max_queue_depth = max(self.max_queue_depth, len(self._pending))
-        reg.gauge("serve.inflight").set(len(self._active))
-        reg.gauge("serve.queued").set(len(self._pending))
 
     def _run_wave(self, reg) -> None:
         stepping = [a for a in self._active if not a.request.done]
@@ -518,8 +522,8 @@ class BatchServer:
         t0 = time.perf_counter()
         ctx = self.engine.ap_ctx
         merger = None
-        with trace.span("serve.wave", cat="serve", wave=self.n_waves,
-                        width=len(stepping)):
+        with trace.span("serve.wave", cat="serve", prof="ap.serve.wave",
+                        wave=self.n_waves, width=len(stepping)):
             if ctx is None:
                 for act in stepping:
                     self._step_float(act)
@@ -556,10 +560,6 @@ class BatchServer:
             wave_ms, inflight=len(stepping), queued=len(self._pending),
             bank_peak_w=merger.last_wave_peak_w if merger is not None
             else None)
-        for act in stepping:
-            if act.error is None and \
-                    act.request.pos > act.request.s_prompt:
-                reg.histogram("serve.decode_step_ms").observe(wave_ms)
         self.n_waves += 1
 
     def _step_float(self, act: _Active) -> None:
@@ -631,8 +631,9 @@ class BatchServer:
             elif act.request.done:
                 rep = None
                 if act.sink is not None and act.sink.n_graphs > 0:
-                    act.sink.flush()        # settle deferred counters
-                    rep = act.sink.report()
+                    with trace.annotate("ap.serve.account"):
+                        act.sink.flush()    # settle deferred counters
+                        rep = act.sink.report()
                     pool = self.engine.ap_ctx.runtime.pool
                     rep["n_arrays_total"] = getattr(
                         pool, "total_arrays", pool.n_arrays)
@@ -648,4 +649,3 @@ class BatchServer:
             else:
                 still.append(act)
         self._active = still
-        reg.gauge("serve.inflight").set(len(self._active))

@@ -139,9 +139,10 @@ class Request:
         if i >= self.s_prompt:
             raise RuntimeError("prefill already complete")
         eng = self.engine
-        self.logits, self.cache = eng._step(
-            eng.params, self.cache,
-            jnp.asarray(self.prompts[:, i], jnp.int32), jnp.int32(i))
+        with trace.annotate("ap.model.step"):
+            self.logits, self.cache = eng._step(
+                eng.params, self.cache,
+                jnp.asarray(self.prompts[:, i], jnp.int32), jnp.int32(i))
         self.pos += 1
         self.n_model_steps += 1
 
@@ -149,15 +150,17 @@ class Request:
         if self.out or self.pos != self.s_prompt:
             raise RuntimeError("sample_first() wants exactly-finished "
                                "prefill and no sampled tokens yet")
-        self.tok = self.engine._sample(self.logits, self.key)
-        self.out.append(np.asarray(self.tok))
+        with trace.annotate("ap.model.step"):
+            self.tok = self.engine._sample(self.logits, self.key)
+            self.out.append(np.asarray(self.tok))
 
     def decode_step(self) -> None:
         j = self.pos - self.s_prompt   # decode index, 0-based
         if j < 0 or self.tok is None:
             raise RuntimeError("decode_step() before prefill + first sample")
         eng = self.engine
-        with trace.span(f"decode{j}", cat="serve", step=j):
+        with trace.span(f"decode{j}", cat="serve", prof="ap.model.step",
+                        step=j):
             self.logits, self.cache = eng._step(eng.params, self.cache,
                                                 self.tok, jnp.int32(self.pos))
             self.key = jax.random.fold_in(self.key, j)

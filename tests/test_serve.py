@@ -326,6 +326,55 @@ def test_batched_serving_bit_identical_to_sequential():
             assert br[key] == sr[key], key
 
 
+def test_merged_wave_spans_on_the_profiler_clock(profiled):
+    """A wave that merges two requests shows on the profiler's clock: the
+    dispatcher's wave around both request threads' model steps (graph
+    builds inside) and rendezvous, and the leader's merge around the
+    graph run and its pool launches."""
+    from repro.serve.batcher import AdmissionCfg, BatchServer
+
+    def nest(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    eng = _tiny_engine()
+    with BatchServer(eng, admission=AdmissionCfg(max_inflight=2)) as srv:
+        def two():
+            hs = [srv.submit(np.array([[1 + i, 2 + i]], np.int32), 1)
+                  for i in range(2)]
+            return [h.result(timeout=300) for h in hs]
+
+        two()                                   # compiles every shape
+        _, spans = profiled(two)
+    evs = [(n, s, e, t) for t, line in spans.items() for n, s, e in line]
+
+    def named(name):
+        return [e for e in evs if e[0] == name]
+
+    merged = [w for w in named("ap.serve.wave")
+              if len({r[3] for r in named("ap.serve.rendezvous")
+                      if nest(r, w)}) == 2]
+    assert merged
+    wave = merged[0]
+    inside = [e for e in evs if nest(e, wave) and e is not wave]
+    steps = [e for e in inside if e[0] == "ap.model.step"]
+    assert len({e[3] for e in steps}) == 2
+    for name in ("ap.model.graph_build", "ap.serve.rendezvous"):
+        assert all(any(nest(e, st) for st in steps if st[3] == e[3])
+                   for e in inside if e[0] == name)
+    merge = [e for e in inside if e[0] == "ap.serve.merge"]
+    runs = [e for e in inside if e[0] == "ap.runtime.run_graph"]
+    assert merge and all(any(nest(r, m) and r[3] == m[3] for m in merge)
+                         for r in runs)
+    launches = [e for e in inside if e[0] == "ap.pool.launch"]
+    assert launches and all(any(nest(e, r) for r in runs) for e in launches)
+    # the spans of each thread nest: two are disjoint or one holds the other
+    for line in spans.values():
+        for i, a in enumerate(line):
+            for b in line[i + 1:]:
+                assert (a[2] <= b[1] or b[2] <= a[1] or nest(a, b)
+                        or nest(b, a)), (a, b)
+
+
 @pytest.mark.slow
 def test_batched_serving_unequal_lengths_and_late_join():
     """Continuous batching: requests of different prompt/decode lengths

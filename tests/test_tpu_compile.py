@@ -13,7 +13,10 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and each pytest worker imports
 every test file.
 """
+import importlib.util
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -138,6 +141,33 @@ def test_four_chip_route_compiles(topo, monkeypatch):
     compiled = jax.jit(run).lower(*args).compile()
     _assert_compiled(compiled)
     assert "all-reduce" in compiled.as_text()
+
+
+def test_kernel_keeps_the_name_the_benchmark_reads(one_chip, monkeypatch):
+    """The launch ``tap_run_program`` dispatches (``_tap_run_program_jit``)
+    lowers on the chip to a custom call whose instruction text is what
+    the benchmark's kernel readers match in the device trace
+    (``bench/work.py``'s ``KERNEL_PATTERN``): renaming the jit would
+    otherwise silently zero ``kernel_roofline.*``."""
+    from repro.kernels.tap_pass import kernel as K
+    spec = importlib.util.spec_from_file_location(
+        "bench_work", Path(__file__).resolve().parents[1] / "bench/work.py")
+    work = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(work)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog = apc.compile_named("add", 3, 20)        # the vec cells' program
+    tensors, variant, pack, _ = resolve_schedule(prog, "onehot_packed")
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = K._tap_run_program_jit.lower(
+        s((4096, prog.min_cols), jnp.int8),
+        *[s(t.shape, t.dtype) for t in tensors], s((), jnp.int32),
+        block_rows=4096, collect_stats=True, hist_bins=8, interpret=False,
+        unroll=resolve_unroll(None, variant, pack), variant=variant,
+        pack=pack).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "custom-call(" in ln]
+    assert calls
+    assert any(re.search(work.KERNEL_PATTERN, ln) for ln in calls), calls
 
 
 def test_ternary_matmul_kernel_compiles(one_chip):

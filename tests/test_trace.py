@@ -335,24 +335,68 @@ def test_validate_chrome_trace_rejects_malformed_counter_events():
 # instrumented paths: parity off, bit-exact attribution on
 # ---------------------------------------------------------------------------
 
-def test_tracing_off_is_bit_identical_across_variants():
-    """REPRO_AP_TRACE=0 parity: digits + APStats unchanged by the
-    instrumentation, for every kernel variant, traced or not."""
+def _smoke_engine(kernel_variant=None):
+    """The 1-layer d_model=16 qwen3 smoke cut, its MLPs on a 4x64x64 bank."""
+    import jax
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_smoke_mesh
+    from repro.models import model as M
+    from repro.models.quant import quantize_model_params
+    from repro.serve.engine import Engine, ServeCfg
+    base = get_smoke_config("qwen3-0.6b")
+    cfg = base.with_(n_layers=1, d_model=16, d_ff=24, n_heads=2,
+                     n_kv_heads=2, head_dim=8, vocab=32,
+                     ternary=base.ternary.__class__(enabled=True))
+    qparams = quantize_model_params(M.init_params(cfg, jax.random.PRNGKey(0)))
+    pool = apc.ArrayPool(n_arrays=4, rows=64, cols=64,
+                         kernel_variant=kernel_variant)
+    ctx = apc.APServeContext(apc.Runtime(pool), x_levels=7)
+    return Engine(cfg, qparams, make_smoke_mesh(), ServeCfg(max_len=8),
+                  ap_ctx=ctx)
+
+
+@pytest.mark.parametrize("case", ["mac", "serve"])
+def test_tracing_off_is_bit_identical_across_variants(case, profiled):
+    """REPRO_AP_TRACE=0 parity: digits, APStats and served tokens are the
+    same with no tracer and no profiler, under a Tracer, and under the
+    profiler (which records the program's spans), for every kernel
+    variant of the MAC, whose digits decode to the integer dot
+    products."""
     x, w = _mac_inputs()
     radix, width, K = 3, 8, x.shape[1]
+    # serving, on the backend's default variant (the MAC case covers all)
+    variants = apc.KERNEL_VARIANTS if case == "mac" else (None,)
+    engines = ({kv: _smoke_engine(kv) for kv in variants}
+               if case == "serve" else {})
     outs, stats = [], []
-    for traced in (False, True):
-        for kv in apc.KERNEL_VARIANTS:
-            st = APStats(radix=radix)
-            pool = apc.ArrayPool(n_arrays=2, rows=16, cols=96)
-            tiled = apc.compile_mac_tiled(radix, K, width, 4,
-                                          max_cols=pool.cols)
-            guard = (trace.tracing(trace.Tracer()) if traced
+    for mode in ("off", "tracer", "profiler"):
+        for kv in variants:
+            if case == "mac":
+                def go():
+                    st = APStats(radix=radix)
+                    pool = apc.ArrayPool(n_arrays=2, rows=16, cols=96)
+                    tiled = apc.compile_mac_tiled(radix, K, width, 4,
+                                                  max_cols=pool.cols)
+                    out = apc.run_mac_tiled(x, w, tiled, pool=pool,
+                                            stats=st, kernel_variant=kv)
+                    return np.asarray(out), st
+            else:
+                def go():
+                    eng = engines[kv]
+                    toks = eng.generate(np.array([[3]], np.int32), 2)
+                    return toks, eng.ap_ctx.stats
+            guard = (trace.tracing(trace.Tracer()) if mode == "tracer"
                      else trace.disabled())
             with guard:
-                outs.append(np.asarray(apc.run_mac_tiled(
-                    x, w, tiled, pool=pool, stats=st, kernel_variant=kv)))
+                if mode == "profiler":
+                    (out, st), spans = profiled(go)
+                    assert spans
+                else:
+                    out, st = go()
+            outs.append(out)
             stats.append(st)
+    if case == "mac":
+        assert np.array_equal(outs[0], (x * w).sum(axis=1))
     for o in outs[1:]:
         assert np.array_equal(outs[0], o)
     for st in stats[1:]:
@@ -360,6 +404,37 @@ def test_tracing_off_is_bit_identical_across_variants():
         assert st.n_compare_cycles == stats[0].n_compare_cycles
         assert st.n_write_cycles == stats[0].n_write_cycles
         assert np.array_equal(st.mismatch_hist, stats[0].mismatch_hist)
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_pool_spans_on_the_profiler_clock(profiled):
+    """One pooled run over 3 blocks puts ap.pool.run around 3 launches
+    (and a drain at each launch past the one-array bank's in-flight cap
+    of 2), then the counter sync, properly nested on the calling
+    thread."""
+    import jax.numpy as jnp
+    prog = apc.compile_named("add", 3, 4)
+    pool = apc.ArrayPool(n_arrays=1, rows=16, cols=16)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(np.concatenate(
+        [rng.integers(0, 3, (40, 8)), np.zeros((40, 1))], 1), jnp.int8)
+    apc.run_pooled(x, prog, pool, stats=APStats(radix=3))      # compile
+    with trace.disabled():
+        _, spans = profiled(
+            lambda: apc.run_pooled(x, prog, pool, stats=APStats(radix=3)))
+    (thread, evs), = spans.items()
+    names = [e[0] for e in evs]
+    assert names == ["ap.pool.run", "ap.pool.launch", "ap.pool.launch",
+                     "ap.pool.drain", "ap.pool.launch", "ap.pool.drain",
+                     "ap.stats.sync"]
+    run = evs[0]
+    launches = [e for e in evs if e[0] == "ap.pool.launch"]
+    assert all(_inside(e, run) for e in evs[1:6])
+    assert all(a[2] <= b[1] for a, b in zip(launches, launches[1:]))
+    assert run[2] <= evs[-1][1]                 # the sync follows the run
 
 
 def test_attribution_sums_bit_exactly_to_ap_stats():
