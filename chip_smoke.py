@@ -123,8 +123,8 @@ def describe_kernels(cfg, pool, x_levels: int) -> None:
               f"write_cycles={tiled.n_write_cycles}", flush=True)
         print(f"    (variant, pack, interpret, fori trips per launch): "
               f"{sorted(set(kinds))}", flush=True)
-        check(all(v == "onehot" and not interp for v, _, interp, _ in kinds),
-              f"{name} programs resolve to the compiled one-hot body")
+        check(all(v == "lanes" and not interp for v, _, interp, _ in kinds),
+              f"{name} programs resolve to the compiled lanes body")
 
 
 def phase_serve(*, rows: int = 4096, cols: int = 256, seed: int = 0) -> None:
@@ -234,8 +234,8 @@ def phase_program(*, rows: int = 1000, width: int = 8, seed: int = 2
     print(f"program: add r3 w{width}, {compiled.n_steps} steps, {rows} rows; "
           f"kernel variant={variant} pack={pack} interpret={interp}, "
           f"{trips} fori trips", flush=True)
-    check(variant != "gather" and not interp,
-          "the program resolves to the compiled one-hot body")
+    check(variant == "lanes" and not interp,
+          "the program resolves to the compiled lanes body")
     rng = np.random.default_rng(seed)
     a = rng.integers(0, 3 ** width, rows)
     b = rng.integers(0, 3 ** width, rows)

@@ -16,6 +16,9 @@ Kernel variants (``kernel_variant=``, default the fastest bit-exact path):
 - ``"onehot_packed"`` — one-hot over the VLIW-packed schedule
   (:func:`~repro.apc.lower.pack_steps`): fewer fori_loop trips, same
   digits and APStats.
+- ``"lanes"`` — the TPU default: digits column-major with rows on the
+  lanes, each step loading and storing only the columns it names
+  (Mosaic on TPU, plain XLA elsewhere).
 
 Rows are the data-parallel axis. :func:`execute` runs on whatever device
 holds the array; :func:`execute_sharded` shard_maps row-blocks over the

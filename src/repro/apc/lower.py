@@ -199,26 +199,27 @@ class CompiledProgram:
 
 DEFAULT_MAX_PACK = 8         # slots per packed group (kernel unrolls them)
 
-KERNEL_VARIANTS = ("gather", "onehot", "onehot_packed")
+KERNEL_VARIANTS = ("gather", "onehot", "onehot_packed", "lanes")
 
 
 def default_kernel_variant() -> str:
     """What the executors run when no ``kernel_variant`` is requested.
 
-    On TPU the one-hot body over the VLIW-packed schedule — the lane-native
-    formulation (no dynamic cross-lane indexing, compiles under Mosaic).
-    On CPU/GPU hosts the gather body stays the measured-fastest simulator
-    path: its per-step work is O(rows x C) against the one-hot body's
-    O(rows x n_cols), and XLA lowers host-side gathers cheaply
-    (bench_ap_kernel records the matrix).  ``REPRO_AP_KERNEL_VARIANT``
-    overrides — CI uses it to run the kernel shard through the compiled
-    one-hot path.  All variants are bit-identical (tests/test_pack.py).
+    On TPU the ``lanes`` body: digits column-major with rows on sublanes x
+    lanes, so a step loads and stores only the columns it names, at a
+    dynamic leading-axis offset Mosaic compiles.  On CPU/GPU hosts the
+    gather body stays the measured-fastest simulator path: its per-step
+    work is O(rows x C) against the one-hot body's O(rows x n_cols), and
+    XLA lowers host-side gathers cheaply (bench_ap_kernel records the
+    matrix).  ``REPRO_AP_KERNEL_VARIANT`` overrides — CI uses it to run the
+    kernel shard through the compiled one-hot path.  All variants are
+    bit-identical (tests/test_pack.py).
     """
     import jax                          # local: keep lowering importable
     env = os.environ.get("REPRO_AP_KERNEL_VARIANT")
     if env:
         return env
-    return "onehot_packed" if jax.default_backend() == "tpu" else "gather"
+    return "lanes" if jax.default_backend() == "tpu" else "gather"
 
 
 def pack_steps(steps: tuple[Step, ...], max_pack: int = DEFAULT_MAX_PACK
@@ -330,28 +331,32 @@ def resolve_schedule(compiled: CompiledProgram,
     Returns ``(schedule_tensors, variant, pack, resolved_name)`` — the
     tensors to feed :func:`~repro.kernels.tap_pass.kernel.tap_run_program`
     plus its ``variant``/``pack`` statics.  ``None`` resolves to
-    :func:`default_kernel_variant`.  Programs whose steps carry duplicate
-    write or compare columns fall back to the gather body (the only
-    bit-exact one for serial same-column writes / per-position mismatch
-    counting) off TPU, and raise on TPU, where that body has no compiled
-    lowering; ``onehot_packed`` additionally falls back to the flat
-    one-hot schedule when list scheduling found nothing to pack, or when
-    group-width padding would inflate the slot grid faster than the trip
-    count shrinks (carry-ripple chains pin most slots to 1-wide groups).
+    :func:`default_kernel_variant`.  ``lanes`` runs the flat schedule:
+    it replays slots one by one, so packing would only add no-op slots.
+    The one-hot variants need distinct write and compare columns in a
+    step; a program without them falls back to the gather body (serial
+    same-column writes, per-position mismatch counting) off TPU, and
+    raises on TPU, where that body has no compiled lowering (``lanes``
+    replays such steps as the gather body does, and compiles).
+    ``onehot_packed`` additionally falls back to the flat one-hot schedule
+    when list scheduling found nothing to pack, or when group-width padding
+    would inflate the slot grid faster than the trip count shrinks
+    (carry-ripple chains pin most slots to 1-wide groups).
     """
     kv = default_kernel_variant() if kernel_variant is None else kernel_variant
     if kv not in KERNEL_VARIANTS:
         raise ValueError(
             f"kernel_variant must be one of {KERNEL_VARIANTS}, got {kv!r}")
-    if kv != "gather" and not (compiled.writes_distinct
-                               and compiled.compares_distinct):
+    if kv in ("gather", "lanes"):
+        return compiled.schedule_tensors, kv, 1, kv
+    if not (compiled.writes_distinct and compiled.compares_distinct):
         import jax                      # local: keep lowering importable
         if jax.default_backend() == "tpu":
             raise ValueError(
                 "program has duplicate write or compare columns in one "
-                "step; only the gather body replays those, and it does not "
-                "compile under Mosaic")
-        kv = "gather"
+                "step; the one-hot bodies cannot replay those, and the "
+                "gather body does not compile under Mosaic: run 'lanes'")
+        return compiled.schedule_tensors, "gather", 1, "gather"
     if kv == "onehot_packed":
         p = compiled.packed(max_pack)
         if p.pack > 1 and p.n_slots <= 1.25 * compiled.n_steps:

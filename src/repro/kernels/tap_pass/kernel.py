@@ -1,37 +1,50 @@
 """Fused TAP LUT-schedule kernel: pallas + compiled-XLA program executors.
 
 TPU adaptation of the paper's in-memory property: the MvCAM row-block is the
-VMEM-resident tile, the CAM rows map onto the TPU vector lanes, and the whole
-compare/write pass schedule (e.g. all 20 digits x 21 passes of a 20-trit add,
-441 HBM round-trips in a naive implementation) executes against that tile
-with exactly ONE HBM read and ONE HBM write per block.
+VMEM-resident tile and the whole compare/write pass schedule (e.g. all 20
+digits x 21 passes of a 20-trit add, 441 HBM round-trips in a naive
+implementation) executes against that tile with exactly ONE HBM read and ONE
+HBM write per block.
 
-Layout: digits [rows, cols] int8, rows is the parallel axis (grid dim 0),
-cols the operand digit columns (2p+1 for a p-digit add).
+Layout: digits [rows, cols] int8 in HBM, rows the parallel axis (grid dim
+0), cols the operand digit columns (2p+1 for a p-digit add).  On TPU the
+``lanes`` body holds a block column-major, ``[cols, rows / 128, 128]``: the
+CAM rows map onto sublanes x lanes, one column is ``rows / 1024`` int32
+vregs, and a schedule step addresses its columns by a dynamic offset on the
+leading axis.  The other bodies keep ``[rows, cols]`` with columns on the
+lanes.
 
-Two step-body formulations for the whole-program executor, both reading the
-schedule one scalar at a time (:class:`SlotLayout`):
+Three step-body formulations for the whole-program executor, all reading
+the schedule one scalar at a time (:class:`SlotLayout`):
 
+- ``variant="lanes"`` — the TPU body: per step, load the C compare
+  columns, count each row's mismatches per key, and for each of the W
+  write columns load, blend and store it back, in place and in order.  A
+  step touches O(C + W) columns whatever the block's width.  The kernel
+  widens and transposes the [rows, cols] block into int32 VMEM scratch on
+  its first schedule chunk, and back on its last.  On a v5e, 4096-row
+  blocks: the 250-column MAC tile's 9129 steps took 0.79 ms (0.087 us a
+  step) against 65.9 ms for the one-hot body, and the 20-trit add's 421
+  steps ~32 us of device time against ~2.1 ms.
 - ``variant="gather"`` — the original body: per-step dynamic column reads
   for the compare and a serial ``dynamic_update_index_in_dim`` chain for
-  the writes.  Runs in the pallas interpreter and the jitted-XLA harness;
-  it has no Mosaic lowering (dynamic cross-lane indexing), so a compiled
-  run on TPU refuses it.
-- ``variant="onehot"`` — the AP-native formulation: the compare columns
-  and key expand into a one-hot mask/value plane over the full row, the
-  compare is a masked equality reduction, and each write a
-  ``jnp.where(col_mask & tag, vals, block)`` blend.  No dynamic indexing
-  and only rank-2 temporaries, so the body compiles under Mosaic on TPU
-  (and plain XLA elsewhere).  With ``pack > 1`` each fori_loop iteration
-  replays a whole VLIW group of mutually independent slots (see
-  :class:`repro.apc.lower.PackedProgram`) against the pre-group block and
-  lands all writes in one blend.
+  the writes, the lanes body's semantics with columns on the lanes.  Runs
+  in the pallas interpreter and the jitted-XLA harness; it has no Mosaic
+  lowering (dynamic cross-lane indexing), so a compiled run on TPU refuses
+  it.
+- ``variant="onehot"`` — the compare columns and key expand into a one-hot
+  mask/value plane over the full row, the compare is a masked equality
+  reduction, and each write a ``jnp.where(col_mask & tag, vals, block)``
+  blend: O(rows x cols) per step.  No dynamic indexing and only rank-2
+  temporaries, so it compiles under Mosaic too; it stays as a cross-check.
+  With ``pack > 1`` each fori_loop iteration replays a whole VLIW group of
+  mutually independent slots (see :class:`repro.apc.lower.PackedProgram`)
+  against the pre-group block and lands all writes in one blend.
 
-Both formulations are bit-identical — digits AND traced counters, including
+All formulations are bit-identical — digits AND traced counters, including
 the mismatch histogram's saturating top bin (pinned by tests/test_pack.py).
 
 The pallas kernel's grid is (row block, schedule chunk): the digit block
-(``block_rows`` x cols int8, 1 MiB for the default 4096 x 256 bank array)
 stays in VMEM across the chunks while each chunk's schedule words stream
 through SMEM.
 """
@@ -52,22 +65,26 @@ from .ref import DONT_CARE, Step
 
 BLOCK_ROWS = 1024
 
-VARIANTS = ("gather", "onehot")
+VARIANTS = ("gather", "onehot", "lanes")
 
 # Measured defaults for the knobs the executors thread through (None = "use
 # the measured default"; REPRO_AP_INTERPRET / REPRO_AP_UNROLL override for
 # CI/bench sweeps).  interpret=None resolves per backend: on TPU the
-# compiled path (Mosaic — the whole point of the one-hot reformulation); on
-# CPU/GPU hosts the pallas interpreter, which under jit stages to the same
-# XLA ops and measured FASTER than the lax.map harness for the gather body
-# (bench_ap_kernel records the matrix).  interpret=False off-TPU runs the
-# jitted-XLA harness below — the compiled path CI keeps green.
+# compiled path (Mosaic); on CPU/GPU hosts the pallas interpreter, which
+# under jit stages to the same XLA ops and measured FASTER than the lax.map
+# harness for the gather body (bench_ap_kernel records the matrix).
+# interpret=False off-TPU runs the jitted-XLA harness below — the compiled
+# path CI keeps green.
 #
 # Unroll (bench_ap_kernel, CPU host, 65k rows): the gather body is cheap
 # per step and profits from unroll=4; the one-hot body is ~n_cols/C times
 # fatter (full-row compares and blends), so deeper unrolls only grow the
-# trace — unroll=2 flat / 1 packed measured fastest.
-DEFAULT_UNROLL = {"gather": 4, "onehot": 2, "onehot_packed": 1}
+# trace — unroll=2 flat / 1 packed measured fastest.  The lanes body, on a
+# v5e over the 250-column MAC tile at 4096 rows: 0.094 / 0.092 / 0.088 /
+# 0.085 us per step at unroll 1 / 2 / 4 / 8; 4, since 8 doubles the trace
+# for 3%.
+DEFAULT_UNROLL = {"gather": 4, "onehot": 2, "onehot_packed": 1,
+                  "lanes": 4}
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
@@ -129,11 +146,12 @@ COUNT_LANES = 128
 # step, so program length is not bounded by it: a 9k-step MAC tile at 16
 # words per slot would need 570 KiB whole, a chunk needs 64 KiB.
 SCHED_CHUNK = 1024
-# Rows per inner sub-block of the compiled (Mosaic) kernel: each sub-block
-# replays the whole schedule chunk with its digits as the loop carry, and
-# sub-blocks holding only padding rows are skipped.  On a v5e, a 9129-step
-# MAC tile over 4096 x 250 digits with counters took 10.6 / 9.3 / 9.0 /
-# 9.1 us per step at 256 / 512 / 1024 / 4096 rows.
+# Rows per inner sub-block of the compiled one-hot kernel (columns on the
+# lanes): each sub-block replays the whole schedule chunk with its digits
+# as the loop carry, and sub-blocks holding only padding rows are skipped.
+# On a v5e, a 9129-step MAC tile over 4096 x 250 digits with counters took
+# 10.6 / 9.3 / 9.0 / 9.1 us per step at 256 / 512 / 1024 / 4096 rows.  The
+# lanes body replays its whole block at once (see _tap_lanes_kernel).
 TPU_SUB_ROWS = 1024
 
 
@@ -311,20 +329,106 @@ def _program_block_body(block, row_ok, slot, lay: SlotLayout, n_slots: int, *,
         return block, counts
 
     step = step_gather if variant == "gather" else step_onehot
-    n_iter = n_slots // pack
+    return _replay(step, n_slots // pack, unroll,
+                   (block, jnp.zeros_like(lane)))
 
+
+def _replay(step, n_iter: int, unroll: int, carry):
+    """``step(t, carry)`` for ``t`` in ``range(n_iter)``, ``unroll`` steps
+    to a loop trip: Mosaic's loop lowering unrolls only fully or not at
+    all, so the steps of a trip are traced by hand."""
     def unrolled(t, carry):
-        # Mosaic's loop lowering unrolls only fully or not at all, so
-        # ``unroll`` steps are traced into one trip by hand
         for u in range(unroll):
             carry = step(t * unroll + u, carry)
         return carry
 
-    carry = jax.lax.fori_loop(0, n_iter // unroll, unrolled,
-                              (block, jnp.zeros_like(lane)))
+    carry = jax.lax.fori_loop(0, n_iter // unroll, unrolled, carry)
     for t in range(n_iter - n_iter % unroll, n_iter):
         carry = step(t, carry)
     return carry
+
+
+def _lanes_block_body(load, store, state, row_ok, slot, lay: SlotLayout,
+                      n_slots: int, *, collect_stats: bool, hist_bins: int,
+                      unroll: int):
+    """Replay ``n_slots`` schedule slots on one row-block held column-major.
+
+    ``load(state, c)`` returns digit column ``c`` (int32, rows on sublanes
+    x lanes, the shape of ``row_ok``) and ``store(state, c, v)`` writes it
+    back, returning the new ``state``: a VMEM ref in the pallas kernel, an
+    array in the XLA harness.  Each slot loads its C compare columns and
+    loads, blends and stores its W write columns, in place and one write
+    column after another as the gather body does, so a step costs O(C + W)
+    column loads whatever the block's width, and a packed group replays
+    slot by slot (``pack_steps`` makes that equal the group replay).
+    Counters are per-row int32 accumulators, reduced once at the end into
+    the (1, 2 + hist_bins) row [sets, resets, hist...]; a key compares at
+    most C columns, so bins above C stay empty and are not kept.  Returns
+    ``(state, counts)``.
+    """
+    n_bins = min(lay.n_c, hist_bins - 1) + 1
+    zero = jnp.zeros(row_ok.shape, jnp.int32)
+
+    def step(s, carry):
+        state, acc = carry
+        rd = slot(s)
+        cols = [rd(c) for c in range(lay.n_c)]
+        sub = [load(state, jnp.maximum(c, 0)) for c in cols]
+        on = [jnp.where(c >= 0, 1, 0) for c in cols]     # -1: padding
+        hist_on = rd(lay.hist) != 0
+        sets, resets, *hist = acc if collect_stats else (None, None)
+        tag = any_kv = None
+        for k in range(lay.n_k):
+            kv = rd(lay.kv + k) != 0
+            mm = zero
+            for c in range(lay.n_c):
+                key = rd(lay.key + k * lay.n_c + c)
+                miss = (sub[c] != key) & (sub[c] != DONT_CARE)
+                mm = mm + jnp.where(miss, on[c], 0)
+            # mismatch count doubles as the matcher: full match <=> mm == 0
+            hit = (mm == 0) & kv
+            tag = hit if tag is None else tag | hit
+            any_kv = kv if any_kv is None else any_kv | kv
+            if collect_stats:
+                # the top bin saturates (>= hist_bins-1 mismatches)
+                counted = row_ok & (kv & hist_on)
+                top = jnp.minimum(mm, hist_bins - 1)
+                hist = [h + jnp.where(counted & (top == b), 1, 0)
+                        for b, h in enumerate(hist)]
+        tag = row_ok if tag is None else (tag | ~any_kv) & row_ok
+        for w in range(lay.n_w):
+            wc = rd(lay.wc + w)
+            col = jnp.maximum(wc, 0)
+            v = rd(lay.wv + w)
+            old = load(state, col)
+            changed = tag & (old != v) & (wc >= 0)
+            if collect_stats:
+                sets = sets + jnp.where(changed, 1, 0)
+                resets = resets + jnp.where(changed & (old != DONT_CARE), 1,
+                                            0)
+            state = store(state, col, jnp.where(changed, v, old))
+        return state, ((sets, resets, *hist) if collect_stats else ())
+
+    acc = (zero,) * (2 + n_bins) if collect_stats else ()
+    state, acc = _replay(step, n_slots, unroll, (state, acc))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2 + hist_bins), 1)
+    counts = jnp.zeros_like(lane)
+    for idx, a in enumerate(acc):
+        counts = counts + jnp.where(
+            lane == idx, jnp.sum(a, axis=(0, 1), keepdims=True), 0)
+    return state, counts
+
+
+def _slot_reader(words_ref, lay: SlotLayout, interpret: bool):
+    """``slot(s)(off)``: the int32 word at field ``off`` of slot ``s`` of
+    the SMEM schedule chunk."""
+    def slot(s):
+        if not interpret:                       # Mosaic: SMEM scalar loads
+            return lambda off: words_ref[s * lay.width + off]
+        # interpreter: one slice per slot, then static indexing
+        row = words_ref[pl.ds(s * lay.width, lay.width)]
+        return lambda off: row[off]
+    return slot
 
 
 def _tap_program_kernel(n_valid_ref, words_ref, arr_ref, out_ref,
@@ -353,13 +457,7 @@ def _tap_program_kernel(n_valid_ref, words_ref, arr_ref, out_ref,
             stats_refs[0][...] = jnp.zeros(stats_refs[0].shape, jnp.int32)
 
     n_local = n_valid_ref[0] - i * block_rows
-
-    def slot(s):
-        if not interpret:                       # Mosaic: SMEM scalar loads
-            return lambda off: words_ref[s * lay.width + off]
-        # interpreter: one slice per slot, then static indexing
-        row = words_ref[pl.ds(s * lay.width, lay.width)]
-        return lambda off: row[off]
+    slot = _slot_reader(words_ref, lay, interpret)
 
     # Mosaic has no int8 vector compares on this path: digits are widened
     # to int32 for the replay and narrowed again on the store
@@ -384,6 +482,82 @@ def _tap_program_kernel(n_valid_ref, words_ref, arr_ref, out_ref,
         return carry
 
     jax.lax.fori_loop(0, block_rows // sub_rows, sub_block, 0)
+
+
+def _tap_lanes_kernel(n_valid_ref, words_ref, arr_ref, out_ref, *refs,
+                      lay: SlotLayout, chunk: int, n_chunks: int,
+                      interpret: bool, collect_stats: bool, hist_bins: int,
+                      unroll: int):
+    """Pallas body of the ``lanes`` variant: grid (row block ``i``,
+    schedule chunk ``j``).
+
+    ``arr_ref``/``out_ref`` are the block's ``[block_rows, cols]`` int8
+    digits.  The replay runs on a column-major int32 copy in VMEM scratch
+    (the last of ``refs``), ``[cols, block_rows / lane, lane]``: chunk 0
+    widens and transposes the block into it, 1024 rows at a time, and the
+    last chunk transposes and narrows it back into ``out_ref``.  A column
+    is then ``block_rows / 1024`` vregs at a dynamic leading-axis offset,
+    so a schedule step touches only the columns it names.  Rows past
+    ``n_valid`` are masked out of writes and counters, and a block holding
+    none of the valid rows is skipped.  The whole block replays at once: on
+    a v5e a step of the 250-column MAC tile took 0.088 us over 4096 rows,
+    and 0.175 us as four 1024-row sub-blocks (0.052 us with three of them
+    padding and skipped) — the scalar reads of a step dominate.
+    """
+    i, j = pl.program_id(0), pl.program_id(1)
+    blk_ref = refs[-1]
+    n_cols, nb, lane = blk_ref.shape
+    block_rows = nb * lane
+    # rows per transpose: 1024 (8 x 128) keeps each one a few vregs a column
+    tr_rows = 1024 if block_rows % 1024 == 0 else block_rows
+    tr_nb = tr_rows // lane
+
+    def each_tile(f):
+        def body(t, carry):
+            f(pl.ds(pl.multiple_of(t * tr_rows, tr_rows), tr_rows),
+              pl.ds(pl.multiple_of(t * tr_nb, tr_nb), tr_nb))
+            return carry
+        jax.lax.fori_loop(0, block_rows // tr_rows, body, 0)
+
+    @pl.when(j == 0)
+    def _init():
+        def widen(rows, planes):
+            blk_ref[:, planes, :] = arr_ref[rows, :].astype(
+                jnp.int32).T.reshape(n_cols, tr_nb, lane)
+        each_tile(widen)
+        if collect_stats:
+            refs[0][...] = jnp.zeros(refs[0].shape, jnp.int32)
+
+    n_local = n_valid_ref[0] - i * block_rows
+
+    @pl.when(n_local > 0)
+    def _run():
+        row = (lane * jax.lax.broadcasted_iota(jnp.int32, (nb, lane), 0)
+               + jax.lax.broadcasted_iota(jnp.int32, (nb, lane), 1))
+
+        def store(state, c, v):
+            blk_ref[c] = v
+            return state
+
+        _, counts = _lanes_block_body(
+            lambda state, c: blk_ref[c], store, (), row < n_local,
+            _slot_reader(words_ref, lay, interpret), lay, chunk,
+            collect_stats=collect_stats, hist_bins=hist_bins, unroll=unroll)
+        if collect_stats:
+            refs[0][0:1, :2 + hist_bins] += counts
+
+    @pl.when(j == n_chunks - 1)
+    def _store():
+        def narrow(rows, planes):
+            out_ref[rows, :] = blk_ref[:, planes, :].reshape(
+                n_cols, tr_rows).T.astype(out_ref.dtype)
+        each_tile(narrow)
+
+
+def _lane_width(block_rows: int) -> int:
+    """Rows per lane row of the ``lanes`` layout: the 128 lanes, or the
+    whole block when it is not a multiple of them."""
+    return 128 if block_rows % 128 == 0 else block_rows
 
 
 def _tap_program_pallas(arr, sched, n_valid, *, block_rows: int,
@@ -413,13 +587,26 @@ def _tap_program_pallas(arr, sched, n_valid, *, block_rows: int,
     if n_chunks * chunk > n_slots:
         words = jnp.concatenate(
             [words, _noop_slots(n_chunks * chunk - n_slots, lay)])
-    sub_rows = (TPU_SUB_ROWS if not interpret and block_rows > TPU_SUB_ROWS
-                and block_rows % TPU_SUB_ROWS == 0 else block_rows)
     grid = (rows // block_rows, n_chunks)
-    kernel = functools.partial(
-        _tap_program_kernel, lay=lay, chunk=chunk, block_rows=block_rows,
-        sub_rows=sub_rows, interpret=interpret, collect_stats=collect_stats,
-        hist_bins=hist_bins, unroll=unroll, variant=variant, pack=pack)
+    scratch = []
+    if variant == "lanes":
+        # rows onto sublanes x lanes: one [block_rows / lane, lane] plane
+        # per column
+        lane = _lane_width(block_rows)
+        kernel = functools.partial(
+            _tap_lanes_kernel, lay=lay, chunk=chunk, n_chunks=n_chunks,
+            interpret=interpret, collect_stats=collect_stats,
+            hist_bins=hist_bins, unroll=unroll)
+        scratch = [pltpu.VMEM((cols, block_rows // lane, lane), jnp.int32)]
+    else:
+        sub_rows = (TPU_SUB_ROWS if not interpret
+                    and block_rows > TPU_SUB_ROWS
+                    and block_rows % TPU_SUB_ROWS == 0 else block_rows)
+        kernel = functools.partial(
+            _tap_program_kernel, lay=lay, chunk=chunk,
+            block_rows=block_rows, sub_rows=sub_rows, interpret=interpret,
+            collect_stats=collect_stats, hist_bins=hist_bins, unroll=unroll,
+            variant=variant, pack=pack)
     digits = pl.BlockSpec((block_rows, cols), lambda i, j: (i, 0))
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
                 pl.BlockSpec((chunk * lay.width,), lambda i, j: (j,),
@@ -433,7 +620,7 @@ def _tap_program_pallas(arr, sched, n_valid, *, block_rows: int,
                                               jnp.int32))
     res = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, scratch_shapes=scratch, interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(jnp.reshape(n_valid, (1,)).astype(jnp.int32), words.reshape(-1),
@@ -464,6 +651,23 @@ def _tap_program_xla(padded, sched, n_valid, *, block_rows: int,
 
     def per_block(args):
         i, blk = args
+        if variant == "lanes":
+            lane = _lane_width(block_rows)
+            shape = (block_rows // lane, lane)
+            row = (i * block_rows
+                   + lane * jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                   + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+            planes, counts = _lanes_block_body(
+                lambda st, c: jax.lax.dynamic_index_in_dim(
+                    st, c, keepdims=False),
+                lambda st, c, v: jax.lax.dynamic_update_index_in_dim(
+                    st, v[None], c, 0),
+                blk.astype(jnp.int32).T.reshape(cols, *shape),
+                row < n_valid, slot, lay, words.shape[0],
+                collect_stats=collect_stats, hist_bins=hist_bins,
+                unroll=unroll)
+            out = planes.reshape(cols, block_rows).T.astype(jnp.int8)
+            return out, counts[0]
         row_ok = (i * block_rows + jax.lax.broadcasted_iota(
             jnp.int32, (block_rows, 1), 0)) < n_valid
         out, counts = _program_block_body(
@@ -498,12 +702,13 @@ def tap_run_program(arr: jax.Array, cmp_cols: jax.Array, keys: jax.Array,
     compiled kernel serves every program with the same packed shape.
 
     ``variant`` selects the step-body formulation (see module docstring);
-    ``pack`` > 1 (one-hot only) replays VLIW groups of that many slots per
-    loop iteration — the schedule tensors must be group-major with
+    ``pack`` > 1 marks group-major VLIW schedule tensors with
     ``n_slots % pack == 0`` (:meth:`repro.apc.lower.CompiledProgram.packed`
-    produces them).  ``interpret=None`` resolves per backend (see
-    :func:`resolve_interpret`); ``interpret=False`` off-TPU runs the jitted
-    XLA harness — same body, same per-block counter layout, bit-identical.
+    produces them): the one-hot body replays a group per loop iteration,
+    the lanes body its slots one by one.  ``interpret=None`` resolves per
+    backend (see :func:`resolve_interpret`); ``interpret=False`` off-TPU
+    runs the jitted XLA harness — same body, same per-block counter
+    layout, bit-identical.
     The gather body has no Mosaic lowering: compiled on TPU it raises.
     """
     if variant not in VARIANTS:

@@ -111,7 +111,7 @@ def ternary_matmul_ap(x: jax.Array, packed: jax.Array, scale: jax.Array,
     tiled programs on the single-array executor (the tiled-vs-untiled
     oracle); ``mesh`` shards the M*N row axis.  ``kernel_variant`` /
     ``interpret`` / ``unroll`` pick the program-kernel formulation
-    (gather / one-hot / one-hot+packed, interpreted or compiled; see
+    (gather / one-hot / one-hot+packed / lanes, interpreted or compiled; see
     :mod:`repro.apc.exec`) and default to the measured backend best —
     every combination is bit-exact.  Bit-exact vs
     :func:`~repro.kernels.ternary_matmul.ref.ternary_matmul_ref` on every

@@ -1,11 +1,12 @@
 """Kernel-variant + VLIW-packing equivalence suite (ISSUE 5).
 
-Contract: the one-hot and one-hot+packed program kernels are bit-identical
-to the gather kernel — digits AND APStats (sets/resets/cycles/mismatch
-histogram, including the saturating top bin) — on every program class, in
-both interpret (pallas) and compiled (interpret=False, jitted XLA on CPU)
-modes; the packing pass serializes every write-slot conflict; duplicate
-write/compare columns in one step fall back to the gather body.
+Contract: the one-hot, one-hot+packed and lanes program kernels are
+bit-identical to the gather kernel — digits AND APStats (sets/resets/cycles/
+mismatch histogram, including the saturating top bin) — on every program
+class, in both interpret (pallas) and compiled (interpret=False, jitted XLA
+on CPU) modes; the packing pass serializes every write-slot conflict;
+duplicate write/compare columns in one step fall back to the gather body
+under the one-hot variants, and the lanes body replays them itself.
 """
 import numpy as np
 import pytest
@@ -17,7 +18,7 @@ from repro.core import ap, build_lut_nonblocked
 from repro.core import truth_tables as tt
 from repro.apc.lower import PackedProgram, pack_steps, resolve_schedule
 
-VARIANTS = ("gather", "onehot", "onehot_packed")
+VARIANTS = ("gather", "onehot", "onehot_packed", "lanes")
 
 
 def _stats_equal(a: ap.APStats, b: ap.APStats) -> None:
@@ -194,7 +195,8 @@ def test_variants_parity_mac(radix):
     assert np.array_equal(got, (x * wt).sum(axis=1))
 
 
-@pytest.mark.parametrize("kernel_variant", ["onehot", "onehot_packed"])
+@pytest.mark.parametrize("kernel_variant",
+                         ["onehot", "onehot_packed", "lanes"])
 def test_variants_parity_tiled_mac_matmul(kernel_variant):
     """The tiled-MAC serving path (pool + reduction chain) stays bit-exact
     vs the jnp reference and counter-identical vs the gather run."""
@@ -235,8 +237,114 @@ def test_variants_parity_runtime_graph():
         got = np.asarray(apc.decode_signed_digits_jnp(digits, 3))
         assert np.array_equal(got, want)
         stats[kv] = st
-    _stats_equal(stats["gather"], stats["onehot"])
-    _stats_equal(stats["gather"], stats["onehot_packed"])
+    for kv in VARIANTS[1:]:
+        _stats_equal(stats["gather"], stats[kv])
+
+
+# ---------------------------------------------------------------------------
+# The lanes body at the served shapes: raw kernel output against gather
+# ---------------------------------------------------------------------------
+
+def _served_tiled(K: int):
+    """The TiledMac the served qwen3-0.6b MLP builds for a K-wide
+    projection (x_levels 7, radix 3) on a 256-column bank array."""
+    from repro.apc.mac import mac_acc_width
+    from repro.kernels.ternary_matmul.ap import default_k_tile
+    width = mac_acc_width(3, K, 7)
+    return apc.compile_mac_tiled(
+        3, K, width, min(default_k_tile(256, width), K), max_cols=256)
+
+
+def _wide_compare_program():
+    """Steps comparing 9 columns, so mismatch counts reach past the
+    histogram's top bin (HIST_BINS - 1), which must saturate."""
+    return apc.compile_program((
+        apc.CompareWrite(compare_cols=tuple(range(9)), key=(0,) * 9,
+                         write_cols=(9,), write_vals=(1,),
+                         count_mismatch=True),
+        apc.CompareWrite(compare_cols=tuple(range(1, 10)), key=(1,) * 9,
+                         write_cols=(0, 10), write_vals=(2, 0),
+                         count_mismatch=True),
+        apc.CompareWrite(compare_cols=(10,), key=(0,), write_cols=(3,),
+                         write_vals=(2,))))
+
+
+def _kernel_run(arr, tensors, n_valid, *, block_rows, variant, pack=1,
+                interpret):
+    from repro.apc.stats import HIST_BINS
+    from repro.kernels.tap_pass.kernel import tap_run_program
+    out, counts = tap_run_program(
+        jnp.asarray(arr), *map(jnp.asarray, tensors), jnp.int32(n_valid),
+        block_rows=block_rows, collect_stats=True, hist_bins=HIST_BINS,
+        interpret=interpret, variant=variant, pack=pack)
+    return np.asarray(out), np.asarray(counts)
+
+
+@pytest.mark.parametrize("case", [
+    "add_r3w20", "mac_k1024_tile", "mac_k1024_reduce", "packed_noop_slots",
+    "partial_blocks", "hist_top_bin"])
+def test_lanes_kernel_parity(case):
+    """The lanes body, interpreted and through the jitted-XLA harness,
+    returns the gather body's digits and per-block counters bit for bit
+    (every counter, the saturating top histogram bin included)."""
+    rows, block_rows, n_valid = 256, 128, 256
+    if case in ("add_r3w20", "partial_blocks"):
+        compiled = apc.compile_named("add", 3, 20)
+        if case == "partial_blocks":        # 300 % 128 != 0, 2 lane rows
+            rows, block_rows, n_valid = 512, 256, 300
+    elif case == "mac_k1024_tile":
+        compiled = _served_tiled(1024).programs[0]
+        rows, block_rows, n_valid = 128, 128, 97
+    elif case == "mac_k1024_reduce":
+        compiled = _served_tiled(1024).reduce_programs[0]
+        rows, block_rows, n_valid = 128, 128, 128
+    elif case == "packed_noop_slots":
+        compiled = apc.compile_named("negate", 3, 6)
+    else:
+        compiled = _wide_compare_program()
+    if case == "mac_k1024_tile":
+        assert compiled.min_cols == 250 and compiled.n_steps > 9000
+    rng = np.random.default_rng(sum(map(ord, case)))
+    arr = rng.integers(-1, 3, (rows, compiled.min_cols)).astype(np.int8)
+    want = _kernel_run(arr, compiled.schedule_tensors, n_valid,
+                       block_rows=block_rows, variant="gather",
+                       interpret=True)
+    tensors, pack = compiled.schedule_tensors, 1
+    if case == "packed_noop_slots":
+        p = compiled.packed()
+        assert p.pack > 1 and p.n_slots > compiled.n_steps   # no-op slots
+        tensors, pack = p.schedule_tensors, p.pack
+    if case == "hist_top_bin":
+        from repro.apc.stats import HIST_BINS
+        assert want[1][:, 2 + HIST_BINS - 1].sum() > 0
+    for interp in (True, False):
+        got = _kernel_run(arr, tensors, n_valid, block_rows=block_rows,
+                          variant="lanes", pack=pack, interpret=interp)
+        assert np.array_equal(got[0], want[0]), (case, interp)
+        assert np.array_equal(got[1], want[1]), (case, interp)
+
+
+def test_lanes_block_valid_launch_parity():
+    """A row-concatenated pool launch (``block_valid``): per-segment
+    digits and counters equal the gather body's."""
+    compiled = apc.compile_named("add", 3, 20)
+    rng = np.random.default_rng(14)
+    arr = jnp.asarray(rng.integers(0, 3, (3 * 128, compiled.min_cols)),
+                      jnp.int8)
+    got = {}
+    for kv in ("gather", "lanes"):
+        for interp in (True, False):
+            pool = apc.ArrayPool(n_arrays=2, rows=128, cols=64,
+                                 kernel_variant=kv, interpret=interp)
+            out, traced = pool.run(arr, compiled, collect_stats=True,
+                                   block_valid=(128, 70, 5))
+            got[kv, interp] = (np.asarray(out),
+                               np.asarray(traced.block_counts))
+    base = got["gather", True]
+    assert base[0].shape[0] == 128 + 70 + 5
+    for key, (digits, counts) in got.items():
+        assert np.array_equal(digits, base[0]), key
+        assert np.array_equal(counts, base[1]), key
 
 
 def test_compiled_path_interpret_false_parity_sharded():
@@ -263,8 +371,9 @@ def test_compiled_path_interpret_false_parity_sharded():
 
 def test_dup_write_cols_fall_back_to_gather_bit_exact():
     """Steps with duplicate write columns keep serial write semantics: the
-    resolver must route every variant request to the gather body, and the
-    result must equal the legacy jnp schedule oracle."""
+    resolver must route every one-hot request to the gather body (the
+    lanes body replays write columns one by one itself), and the result
+    must equal the legacy jnp schedule oracle."""
     from repro.kernels.tap_pass.ref import apply_schedule
     prog = (apc.CompareWrite(compare_cols=(0,), key=(1,),
                              write_cols=(2, 2), write_vals=(1, 2)),
@@ -274,13 +383,16 @@ def test_dup_write_cols_fall_back_to_gather_bit_exact():
     assert not compiled.writes_distinct and not compiled.compares_distinct
     for kv in VARIANTS:
         sched, variant, pack, name = resolve_schedule(compiled, kv)
-        assert (variant, pack, name) == ("gather", 1, "gather")
+        want = "lanes" if kv == "lanes" else "gather"
+        assert (variant, pack, name) == (want, 1, want)
     rng = np.random.default_rng(8)
     arr = jnp.asarray(rng.integers(0, 3, (64, 4)), jnp.int8)
     want = np.asarray(apply_schedule(arr, compiled.as_tap_steps()))
     for kv in VARIANTS:
-        out, _ = apc.execute(arr, compiled, kernel_variant=kv)
-        assert np.array_equal(np.asarray(out), want)
+        for interp in (True, False):
+            out, _ = apc.execute(arr, compiled, kernel_variant=kv,
+                                 interpret=interp)
+            assert np.array_equal(np.asarray(out), want)
 
 
 def test_runtime_route_rejects_unhonored_knobs():
@@ -428,7 +540,7 @@ def test_random_schedules_packed_parity_hypothesis():
         base, tr = apc.execute(arr, compiled, collect_stats=True,
                                kernel_variant="gather", interpret=True)
         s0 = apc.to_ap_stats(tr, compiled, rows, radix)
-        for kv in ("onehot", "onehot_packed"):
+        for kv in ("onehot", "onehot_packed", "lanes"):
             for interp in (True, False):
                 out, tr = apc.execute(arr, compiled, collect_stats=True,
                                       kernel_variant=kv, interpret=interp)

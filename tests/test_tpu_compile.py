@@ -4,10 +4,10 @@ Mosaic refuses what the pallas interpreter accepts (dynamic slices of
 loaded values, int8 matmul accumulators, rank-3 temporaries, unaligned
 SMEM blocks, VMEM over budget), so every program shape the served path
 launches on the chip is compiled here by the chip's own compiler: the toy
-bench tile, the full-width qwen3-0.6b MAC tiles on the default 4096 x 256
-bank block, a VLIW-packed program, the four-chip shard_map route and the
-packed-ternary matmul.  Nothing runs; these tests say nothing about
-results or times.
+bench tile, the vec cells' 20-trit add and the full-width qwen3-0.6b MAC
+tiles on a 4096-row bank block, a VLIW-packed program, the four-chip
+shard_map route and the packed-ternary matmul.  Nothing runs; these tests
+say nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and each pytest worker imports
@@ -66,8 +66,12 @@ def _assert_compiled(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compile_program(sharding, prog, rows: int, *, collect_stats: bool):
-    tensors, variant, pack, _ = resolve_schedule(prog, "onehot_packed")
+def _compile_program(sharding, prog, rows: int, *, collect_stats: bool,
+                     kernel_variant: str = "lanes", packed: bool = False):
+    tensors, variant, pack, _ = resolve_schedule(prog, kernel_variant)
+    if packed:                  # the lanes body replays a packed grid too
+        p = prog.packed()
+        tensors, pack = p.schedule_tensors, p.pack
 
     def run(arr, n_valid, *sched):
         return _tap_program_pallas(
@@ -90,7 +94,18 @@ def test_toy_bench_tile_compiles(one_chip, collect_stats):
     prog = _tiled(24, 64).programs[0]
     compiled, variant, pack = _compile_program(one_chip, prog, 64,
                                                collect_stats=collect_stats)
-    assert (variant, pack) == ("onehot", 1)
+    assert (variant, pack) == ("lanes", 1)
+    _assert_compiled(compiled)
+
+
+def test_vec_add_block_compiles(one_chip):
+    """The vec cells' 20-trit add (421 steps, 41 columns) on one 4096-row
+    bank block."""
+    prog = apc.compile_named("add", 3, 20)
+    assert prog.min_cols == 41
+    compiled, variant, _ = _compile_program(one_chip, prog, 4096,
+                                            collect_stats=True)
+    assert variant == "lanes"
     _assert_compiled(compiled)
 
 
@@ -101,17 +116,26 @@ def test_full_width_mac_tile_compiles(one_chip, K):
     streams through SMEM in chunks, the block fits VMEM."""
     tiled = _tiled(K, 256)
     assert tiled.programs[0].n_steps > 9000
+    assert tiled.programs[0].min_cols == {1024: 250, 3072: 253}[K]
     for prog in (tiled.programs[0], tiled.reduce_programs[0]):
         compiled, variant, _ = _compile_program(one_chip, prog, 4096,
                                                 collect_stats=True)
-        assert variant == "onehot"
+        assert variant == "lanes"
         _assert_compiled(compiled)
 
 
 def test_packed_program_compiles(one_chip):
+    """A VLIW-packed schedule under the one-hot body, and under the lanes
+    body, which replays its slots one by one."""
+    prog = apc.compile_named("max", 3, 8)
     compiled, variant, pack = _compile_program(
-        one_chip, apc.compile_named("max", 3, 8), 4096, collect_stats=True)
+        one_chip, prog, 4096, collect_stats=True,
+        kernel_variant="onehot_packed")
     assert variant == "onehot" and pack > 1
+    _assert_compiled(compiled)
+    compiled, variant, pack = _compile_program(
+        one_chip, prog, 4096, collect_stats=True, packed=True)
+    assert variant == "lanes" and pack > 1
     _assert_compiled(compiled)
 
 
@@ -125,7 +149,8 @@ def test_four_chip_route_compiles(topo, monkeypatch):
     mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1),
                 ("pod", "data", "model"))
     prog = _tiled(1024, 256).programs[0]
-    tensors, variant, pack, _ = resolve_schedule(prog, "onehot_packed")
+    tensors, variant, pack, _ = resolve_schedule(prog)
+    assert variant == "lanes"
 
     def run(arr, *sched):
         return sharded_program_run(arr, sched, mesh, ("pod", "data"),
@@ -156,7 +181,8 @@ def test_kernel_keeps_the_name_the_benchmark_reads(one_chip, monkeypatch):
     spec.loader.exec_module(work)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     prog = apc.compile_named("add", 3, 20)        # the vec cells' program
-    tensors, variant, pack, _ = resolve_schedule(prog, "onehot_packed")
+    tensors, variant, pack, _ = resolve_schedule(prog)
+    assert variant == "lanes"
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     compiled = K._tap_run_program_jit.lower(
         s((4096, prog.min_cols), jnp.int8),
@@ -180,12 +206,13 @@ def test_ternary_matmul_kernel_compiles(one_chip):
 
 
 # ---------------------------------------------------------------------------
-# Variant resolution on TPU: no served program takes the gather body
+# Variant resolution on TPU: every served program takes the lanes body
 # ---------------------------------------------------------------------------
 
-def test_served_programs_resolve_one_hot_on_tpu(monkeypatch):
+def test_served_programs_resolve_lanes_on_tpu(monkeypatch):
     """Every MAC tile, reduction and checksum program of the full-width
-    served MLP resolves to the one-hot body when the backend is a TPU."""
+    served MLP, and the vec cells' add, resolves to the lanes body when the
+    backend is a TPU."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     progs = []
     for K in (1024, 3072):
@@ -193,9 +220,10 @@ def test_served_programs_resolve_one_hot_on_tpu(monkeypatch):
         progs += list(tiled.programs) + list(tiled.reduce_programs)
         progs += [compile_checksum(p.min_cols, 3)
                   for p in (tiled.programs[0], tiled.reduce_programs[0])]
+    progs.append(apc.compile_named("add", 3, 20))
     for prog in progs:
-        _, variant, _, name = resolve_schedule(prog)
-        assert variant == "onehot" and name != "gather"
+        _, variant, pack, name = resolve_schedule(prog)
+        assert (variant, pack, name) == ("lanes", 1, "lanes")
 
 
 def test_duplicate_columns_raise_on_tpu(monkeypatch):
@@ -208,6 +236,8 @@ def test_duplicate_columns_raise_on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(ValueError, match="Mosaic"):
         resolve_schedule(prog, "onehot")
+    # the lanes body replays duplicate columns as the gather body does
+    assert resolve_schedule(prog)[1] == "lanes"
 
 
 def test_gather_body_refused_compiled_on_tpu(monkeypatch):
