@@ -93,8 +93,9 @@ from .faults import (FaultConfig, FaultDetected, FaultModel,
                      fault_config_from_env, faults_enabled)
 from .graph import (CARRIED, FoldStage, GraphNode, ProgramGraph,
                     fold_stage_input, graph_makespan, mac_fold_plan)
-from .layers import (APLinear, APServeContext, APSink, ap_moe_dispatch,
-                     ap_request_scope, ap_serving, current_ap_context)
+from .layers import (APExpertStack, APLinear, APServeContext, APSink,
+                     ap_layer, ap_moe_dispatch, ap_request_scope, ap_serving,
+                     current_ap_context, current_ap_layer, pair_slots)
 from .runtime import DevicePool, GraphResult, Runtime
 from .ir import (AffineCol, ApplyLUT, CompareWrite, ForDigit, Program,
                  RelCol, SetCol, ZeroCol, digit)
@@ -136,8 +137,9 @@ __all__ = [
     "faults_enabled", "drain_fault_charges",
     "CARRIED", "FoldStage", "GraphNode", "ProgramGraph", "fold_stage_input",
     "graph_makespan", "mac_fold_plan",
-    "APLinear", "APServeContext", "APSink", "ap_moe_dispatch",
-    "ap_request_scope", "ap_serving", "current_ap_context",
+    "APExpertStack", "APLinear", "APServeContext", "APSink", "ap_layer",
+    "ap_moe_dispatch", "ap_request_scope", "ap_serving",
+    "current_ap_context", "current_ap_layer", "pair_slots",
     "DevicePool", "GraphResult", "Runtime",
     "AffineCol", "ApplyLUT", "CompareWrite", "ForDigit", "Program", "RelCol",
     "SetCol", "ZeroCol", "digit",

@@ -44,7 +44,8 @@ from .caches import ResidentEvicted, ResidentHandle, ResidentStale
 from .lower import CompiledProgram
 from .metrics import get_registry
 from .mac import (TiledMac, assemble_mac_rows_jnp, encode_mac_rows_jnp,
-                  encode_mac_x_rows_jnp, mac_layout)
+                  encode_mac_x_rows_jnp, grouped_mac_tile_rows_jnp,
+                  mac_layout)
 
 T_COMPARE_NS = T_PRECHARGE_NS + T_EVALUATE_NS
 
@@ -304,21 +305,8 @@ class ProgramGraph:
                     f"resident plane is {rw}x{kw}, rows R={R} K={K} need "
                     f"a [R_w, K] plane with R_w dividing R")
         radix, width = tiled.radix, tiled.width
-        self.radix = radix if self.radix is None else self.radix
-        rkey = None if resident is None else (resident.key,
-                                              resident.generation)
-        if tiled.support is not None:
-            self.bump("pruned_write_cycles", tiled.n_pruned_write_cycles)
-            self.bump("pruned_compare_cycles",
-                      tiled.n_pruned_compare_cycles)
-        self.bump("emitted_passes", tiled.n_emitted_passes)
-        self.bump("pruned_passes", tiled.n_pruned_passes)
-        tile_ids: list[int] = []
-        for t, ((lo, hi), prog) in enumerate(zip(tiled.tiles,
-                                                 tiled.programs)):
-            kt = hi - lo
-            base = mac_layout(kt, width)["acc_base"]
-
+        builds = []
+        for lo, hi in tiled.tiles:
             if resident is None:
                 def build_tile(*, _lo=lo, _hi=hi):
                     return encode_mac_rows_jnp(x[:, _lo:_hi],
@@ -332,7 +320,65 @@ class ProgramGraph:
                     return assemble_mac_rows_jnp(
                         encode_mac_x_rows_jnp(x[:, _lo:_hi], radix, width),
                         wd, width)
+            builds.append(build_tile)
+        return self._add_mac_nodes(R, tiled, builds, label, resident,
+                                   charge_upload)
 
+    def add_grouped_mac(self, x_int: jax.Array, groups: jax.Array,
+                        tiled: TiledMac, resident: ResidentHandle, *,
+                        n_out: int, label: str = "",
+                        charge_upload: bool = False) -> int:
+        """Add one K-tiled MAC over rows of many projections at once: row
+        ``p * n_out + n`` is output ``n`` of ``x_int[p]`` [P, K] through
+        projection ``groups[p]``, whose weights are rows ``groups[p] *
+        n_out ...`` of the ``resident`` plane (projections stacked, see
+        :func:`~repro.apc.mac.grouped_mac_tile_rows_jnp`).  Rows of
+        different projections share every launch: one schedule (``tiled``,
+        compiled against the union of their digit support) replays them
+        all.  Every tile's rows are built by one call, on the first tile
+        node's build; returns the node id of the [P * n_out, width]
+        accumulator block."""
+        P, K = x_int.shape
+        rw, kw = resident.plane.shape
+        if K != tiled.K or kw != K or rw % n_out:
+            raise ValueError(
+                f"x has K={K}, plane is {rw}x{kw}, program K={tiled.K}: "
+                f"need one K and a plane of whole {n_out}-row projections")
+        built: list = []
+
+        def all_tiles():
+            if not built:
+                built.append(grouped_mac_tile_rows_jnp(
+                    x_int, groups, _resolve_or_repin(resident), n_out=n_out,
+                    tiles=tiled.tiles, radix=tiled.radix,
+                    width=tiled.width))
+            return built[0]
+
+        builds = [lambda *, _t=t: all_tiles()[_t]
+                  for t in range(len(tiled.tiles))]
+        return self._add_mac_nodes(P * n_out, tiled, builds, label,
+                                   resident, charge_upload)
+
+    def _add_mac_nodes(self, R: int, tiled: TiledMac, builds: list,
+                       label: str, resident: ResidentHandle | None,
+                       charge_upload: bool) -> int:
+        """The tile nodes (one per ``builds`` entry) and fold-stage nodes
+        of a K-tiled MAC over R rows; returns the final node id."""
+        width = tiled.width
+        self.radix = tiled.radix if self.radix is None else self.radix
+        rkey = None if resident is None else (resident.key,
+                                              resident.generation)
+        if tiled.support is not None:
+            self.bump("pruned_write_cycles", tiled.n_pruned_write_cycles)
+            self.bump("pruned_compare_cycles",
+                      tiled.n_pruned_compare_cycles)
+        self.bump("emitted_passes", tiled.n_emitted_passes)
+        self.bump("pruned_passes", tiled.n_pruned_passes)
+        tile_ids: list[int] = []
+        for t, ((lo, hi), prog, build_tile) in enumerate(
+                zip(tiled.tiles, tiled.programs, builds)):
+            kt = hi - lo
+            base = mac_layout(kt, width)["acc_base"]
             upload = 0
             if charge_upload:
                 upload = kt * width + (0 if resident is not None else kt)

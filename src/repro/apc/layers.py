@@ -19,9 +19,13 @@ draining them one by one.
   (``x_levels``) per call — the AP computes exact integer dot products on
   the quantized activations; fidelity is the quantization's, exactness the
   AP's.
-- :func:`ap_moe_dispatch` — sort tokens to experts and run every expert's
-  projections as independent nodes of one graph (the multi-array occupancy
-  workload of the AP-tutorial framing).
+- :class:`APExpertStack` — the held experts of one MoE projection as ONE
+  resident digit plane and one schedule: a grouped MAC whose rows belong
+  to many experts.
+- :func:`ap_moe_dispatch` — sort (token, expert) pairs on the host and run
+  every held pair's gate and up projections as one grouped MAC each, then
+  the down projections as a third (the multi-array occupancy workload of
+  the AP-tutorial framing).
 - :func:`ap_serving` — context manager the serve engine uses to flip
   ``models.mlp.mlp`` / ``models.moe.moe_ffn`` onto the AP path without
   threading a runtime handle through the whole model stack.
@@ -29,6 +33,7 @@ draining them one by one.
 from __future__ import annotations
 
 import contextvars
+import functools
 from contextlib import contextmanager
 from typing import Callable, NamedTuple
 
@@ -42,14 +47,17 @@ from ..kernels.ternary_matmul.ref import quantize_ternary, unpack_ternary
 from . import trace
 from .caches import ResidentHandle, ResidentStore
 from .graph import ProgramGraph
-from .mac import (compile_mac_tiled, decode_signed_digits_jnp,
-                  encode_weight_digits_jnp, mac_acc_width,
-                  mac_weight_support, matmul_mac_rows, weight_digest)
+from .mac import (W_MINUS, W_PLUS, W_ZERO, compile_mac_tiled,
+                  decode_signed_digits_jnp, encode_weight_digits_jnp,
+                  mac_acc_width, mac_weight_support, matmul_mac_rows,
+                  weight_digest)
+from .metrics import get_registry
 from .power import PowerAccum, graph_power
 from .runtime import Runtime
 
-__all__ = ["APLinear", "APServeContext", "APSink", "ap_moe_dispatch",
-           "ap_serving", "ap_request_scope", "current_ap_context",
+__all__ = ["APExpertStack", "APLinear", "APServeContext", "APSink",
+           "ap_layer", "ap_moe_dispatch", "ap_serving", "ap_request_scope",
+           "current_ap_context", "current_ap_layer", "pair_slots",
            "N_MASKED_MAC"]
 
 # compare-key mask width of the MAC sweeps: 3 LUT columns + 1 weight
@@ -205,6 +213,137 @@ class APLinear:
                              max_q=ctx.x_levels)
         res = ctx.run_graph(graph)
         return call.decode(res, s).astype(x.dtype)
+
+
+class APGroupedCall(NamedTuple):
+    """Handle to one grouped MAC added to a graph: decode after the run."""
+    node: int
+    radix: int
+    groups: jax.Array              # [P]: each row's projection
+    w_scale: jax.Array             # [G, N]: every projection's scale
+
+    def decode(self, results, x_scale) -> jax.Array:
+        """[P, N] float32; ``x_scale`` [P] (or [P, 1]) per input row."""
+        return _decode_rows(results[self.node], jnp.asarray(x_scale),
+                            self.w_scale, self.groups, radix=self.radix)
+
+
+@functools.partial(jax.jit, static_argnames=("radix",))
+def _decode_rows(digits, x_scale, w_scale, groups, *, radix: int
+                 ) -> jax.Array:
+    """Accumulator digits [P * N, width] -> [P, N] float32, scaled per
+    input row and by each row's projection's output scales (one compiled
+    call per shape)."""
+    p, n = groups.shape[0], w_scale.shape[1]
+    acc = decode_signed_digits_jnp(digits, radix)
+    y = acc.reshape(p, n).astype(jnp.float32)
+    return y * x_scale.astype(jnp.float32).reshape(p, 1) * w_scale[groups]
+
+
+@functools.partial(jax.jit, static_argnames=("levels",))
+def quantize_rows_jnp(x, *, levels: int) -> tuple[jax.Array, jax.Array]:
+    """x float [T, K] -> (x_int int32 [T, K], scale [T]): each row on its
+    own grid, ``|x_int| <= levels``."""
+    xf = jnp.asarray(x, jnp.float32)
+    s = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1) / levels, 1e-8)
+    xi = jnp.clip(jnp.round(xf / s[:, None]), -levels,
+                  levels).astype(jnp.int32)
+    return xi, s
+
+
+class APExpertStack:
+    """The experts of one MoE projection, served as ONE grouped MAC.
+
+    ``w_ter`` [E, K, N] in {-1, 0, +1}, ``w_scale`` [E, N] (absmean per
+    output channel of each expert).  The experts' weights stack into one
+    resident digit plane ``[E * N, K]`` (expert ``e``'s output ``n`` is
+    plane row ``e * N + n``), pinned into ``store`` once; one K-tiled
+    schedule, compiled against the union of every expert's digit support,
+    serves them all.  :meth:`add_call` puts the rows of every (input row,
+    expert) pair of a call into that one MAC, each row reading its own
+    expert's slice of the plane — so a call costs one MAC's launches
+    whichever and however many experts it hits.
+    """
+
+    def __init__(self, w_ter: jax.Array, w_scale: jax.Array, *,
+                 radix: int = 3, label: str = "",
+                 store: ResidentStore | None = None):
+        w_ter = jnp.asarray(w_ter, jnp.int8)
+        self.e, self.k, self.n = w_ter.shape
+        self.w_scale = jnp.asarray(w_scale, jnp.float32).reshape(self.e,
+                                                                 self.n)
+        self.radix = radix
+        self.label = label
+        rows = jnp.swapaxes(w_ter, 1, 2).reshape(self.e * self.n, self.k)
+        # the one weight-side encode; the support masks (bit v: digit v
+        # occurs at k in some expert, as mac_weight_support) on the device
+        self.plane = encode_weight_digits_jnp(rows)
+        masks = sum(jnp.any(self.plane == v, axis=0).astype(jnp.int32) << v
+                    for v in (W_MINUS, W_ZERO, W_PLUS))
+        self._support = tuple(int(m) for m in np.asarray(masks))
+        self._digest = weight_digest(np.asarray(rows))
+        zeros = jnp.sum(w_ter == 0, axis=(1, 2))
+        self._n_zero = [int(z) for z in np.asarray(zeros)]
+        self._res_key = f"lin:{label}" if label else f"lin:{self._digest}"
+        self._store = store if store is not None else \
+            ResidentStore(maxsize=1, name=self._res_key)
+        self._handle = self._store.pin(self._res_key, self._digest,
+                                       lambda: self.plane)
+
+    @classmethod
+    def from_dense(cls, w_stack: jax.Array, **kw) -> "APExpertStack":
+        """Ternarize each expert of a float stack [E, K, N] (absmean per
+        output channel, :func:`quantize_ternary`), in float32."""
+        w_ter, scale = jax.vmap(quantize_ternary)(
+            jnp.asarray(w_stack, jnp.float32))
+        return cls(w_ter, scale, **kw)
+
+    @classmethod
+    def from_linears(cls, lins: list["APLinear"], **kw) -> "APExpertStack":
+        """Stack per-expert projections of one shape."""
+        return cls(jnp.stack([lin.w_ter for lin in lins]),
+                   jnp.stack([lin.w_scale for lin in lins]),
+                   radix=lins[0].radix, **kw)
+
+    def __repr__(self) -> str:
+        return (f"APExpertStack({self.e}x{self.k}x{self.n}, "
+                f"radix={self.radix}{', ' + self.label if self.label else ''})")
+
+    def tiled(self, max_cols: int, max_q: int, k_tile: int | None = None):
+        """The one schedule of every call: the K-tiled MAC at the width
+        that inputs ``|x| <= max_q`` need, pruned against the union of the
+        experts' digit support."""
+        from ..kernels.ternary_matmul.ap import default_k_tile
+        width = mac_acc_width(self.radix, self.k, max_q)
+        kt = k_tile if k_tile is not None else default_k_tile(max_cols,
+                                                              width)
+        return compile_mac_tiled(self.radix, self.k, width, min(kt, self.k),
+                                 max_cols=max_cols, support=self._support)
+
+    def add_call(self, graph: ProgramGraph, x_int: jax.Array,
+                 experts: np.ndarray, *, max_cols: int, max_q: int,
+                 k_tile: int | None = None) -> APGroupedCall:
+        """Add rows ``x_int`` [P, K] (|x| <= max_q), row ``p`` through
+        expert ``experts[p]`` (host ints), to the graph as one K-tiled MAC
+        over all P * N output rows; returns the decode handle."""
+        k = x_int.shape[1]
+        if k != self.k:
+            raise ValueError(f"x has K={k}, experts have K={self.k}")
+        tiled = self.tiled(max_cols, max_q, k_tile)
+        prev = self._handle
+        self._handle = self._store.pin(self._res_key, self._digest,
+                                       lambda: self.plane)
+        graph.bump("resident_hits" if self._handle is prev
+                   else "resident_misses", 1)
+        hit = sorted(set(int(e) for e in experts))
+        graph.bump("weight_zeros", sum(self._n_zero[e] for e in hit))
+        graph.bump("weight_digits", len(hit) * self.k * self.n)
+        groups = jnp.asarray(np.asarray(experts, np.int32))
+        node = graph.add_grouped_mac(
+            x_int, groups, tiled, self._handle, n_out=self.n,
+            label=f"{self.label}:" if self.label else "",
+            charge_upload=True)
+        return APGroupedCall(node, self.radix, groups, self.w_scale)
 
 
 class APSink:
@@ -374,6 +513,12 @@ class APServeContext:
         # feeding fresh arrays per request cannot grow it without bound
         self._linears: dict = {}
         self._max_linears = 64
+        # MoE expert stacks, keyed by layer and projection: converted once
+        # (bounded by the model: one per MoE layer and projection)
+        self._stacks: dict[str, APExpertStack] = {}
+        # called with each MoE layer's dispatch record (see
+        # ap_moe_dispatch), for checks that replay the layer
+        self.on_moe: Callable[[dict], None] | None = None
         self._default_sink = APSink(radix=self.radix)
 
     def reset(self) -> None:
@@ -433,20 +578,21 @@ class APServeContext:
             self._cache_put(ck, hit)       # pin packed so id() stays valid
         return hit[1]
 
-    def expert_linears(self, key, w_stack: jax.Array,
-                       label: str = "") -> list[APLinear]:
-        """Cached per-expert APLinears from stacked dense [E, K, N];
-        every expert's weights pin resident at construction."""
-        ck = (key, id(w_stack))
-        hit = self._linears.get(ck)
+    def expert_stack(self, key: str, w_stack: jax.Array | None = None
+                     ) -> APExpertStack:
+        """The expert stack of ``key`` (one MoE layer's projection), made
+        on first use from the float stack ``w_stack`` [E, K, N] and pinned
+        resident under the label ``key``; later calls return it whatever
+        they are handed."""
+        hit = self._stacks.get(key)
         if hit is None:
-            lins = [APLinear.from_dense(w_stack[e], radix=self.radix,
-                                        label=f"{label}e{e}",
-                                        store=self._resident_store())
-                    for e in range(w_stack.shape[0])]
-            hit = (w_stack, lins)
-            self._cache_put(ck, hit)
-        return hit[1]
+            if w_stack is None:
+                raise KeyError(f"no expert stack {key!r} converted yet")
+            hit = APExpertStack.from_dense(w_stack, radix=self.radix,
+                                           label=key,
+                                           store=self._resident_store())
+            self._stacks[key] = hit
+        return hit
 
     def _cache_put(self, ck, value) -> None:
         while len(self._linears) >= self._max_linears:    # FIFO evict
@@ -462,6 +608,11 @@ class APServeContext:
         xi = jnp.clip(jnp.round(xf / s), -self.x_levels,
                       self.x_levels).astype(jnp.int32)
         return xi, s
+
+    def quantize_rows(self, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """x float [T, K] -> (x_int int32 [T, K], scale [T]): each row on
+        its own grid, |x_int| <= x_levels."""
+        return quantize_rows_jnp(x, levels=self.x_levels)
 
     # -- execution + aggregation --------------------------------------------
 
@@ -499,6 +650,7 @@ class APServeContext:
             "pool_schedules_max": self.runtime.pool._max_schedules,
             "linears": len(self._linears),
             "linears_max": self._max_linears,
+            "expert_stacks": len(self._stacks),
         }
         store = self._resident_store()
         if store is not None:
@@ -516,76 +668,142 @@ class APServeContext:
 
 
 # ---------------------------------------------------------------------------
-# MoE dispatch: every expert an independent subgraph of one ProgramGraph
+# MoE dispatch: every held (token, expert) pair's rows in one grouped MAC
 # ---------------------------------------------------------------------------
+
+def pair_slots(n: int, capacity: int, cap: int) -> int:
+    """The (token, expert) pairs a grouped call launches for ``n`` held
+    ones: the next power of two of ``max(n, capacity, 1)``, at most ``cap``
+    (every routed pair).  The call pads to it with zero rows, so it has one
+    of a few shapes whatever its routing, and launches at least the chip's
+    expert ``capacity`` whether few pairs or none are held here."""
+    return min(cap, 1 << (max(n, capacity, 1) - 1).bit_length())
+
+
+@jax.jit
+def _take_rows(x_int, scale, tok, n_real):
+    """The rows (and row scales) of the tokens ``tok``; rows from
+    ``n_real`` on are padding, zero (scale 1)."""
+    real = jnp.arange(tok.shape[0]) < n_real
+    return (jnp.where(real[:, None], x_int[tok], 0),
+            jnp.where(real, scale[tok], 1.0))
+
+
+@functools.partial(jax.jit, static_argnames=("act",))
+def _glu(gate, up, *, act):
+    return act(gate) * up
+
+
+@functools.partial(jax.jit, static_argnames=("t",))
+def _gated_sum(y, gates, pairs, tok, n_real, *, t: int) -> jax.Array:
+    """Each held pair's row ``y`` [P, d] times its gate, summed per token
+    into [t, d]; rows from ``n_real`` on are padding (gate 0)."""
+    real = jnp.arange(pairs.shape[0]) < n_real
+    g = jnp.where(real, gates[pairs].astype(jnp.float32), 0.0)
+    return jnp.zeros((t, y.shape[1]), jnp.float32).at[tok].add(y * g[:, None])
+
+
+def _expert_stack_of(w) -> APExpertStack:
+    """A projection's experts as a stack (lists of per-expert
+    :class:`APLinear` are stacked)."""
+    return w if isinstance(w, APExpertStack) else \
+        APExpertStack.from_linears(list(w))
+
 
 def ap_moe_dispatch(ctx: APServeContext, x2d: jax.Array,
                     expert_ids: jax.Array, gates: jax.Array,
-                    w1_lins: list[APLinear], w3_lins: list[APLinear],
-                    w2_lins: list[APLinear],
-                    act: Callable[[jax.Array], jax.Array]) -> jax.Array:
-    """SwiGLU MoE FFN with every expert projection AP-served.
+                    w1, w3, w2,
+                    act: Callable[[jax.Array], jax.Array], *,
+                    first: int = 0, capacity: int = 0,
+                    layer: int | None = None) -> jax.Array:
+    """SwiGLU MoE FFN over the experts held here, AP-served.
 
-    ``x2d`` [T, d] float, ``expert_ids``/``gates`` [T, k] (router top-k).
-    Token rows sort to their experts on the host (the AP path is the
-    functional simulator — exactness over dispatch latency), then TWO
-    graphs run: one with all experts' gate+up projections (2E independent
-    tiled-MAC subgraphs, interleaved across the bank), one with the down
-    projections after the float combine.  Returns [T, d_out].
+    ``x2d`` [T, d] float, ``expert_ids``/``gates`` [T, k] (the router's
+    top-k over ALL experts, global ids, gates already normalised).
+    ``w1``/``w3``/``w2`` are :class:`APExpertStack` (or equal-length lists
+    of per-expert :class:`APLinear`) holding experts ``first ..
+    first + E - 1``; pairs routed to other experts are left out — the
+    result is ``sum_{e in top-k, held} g_e * FFN_e(x)``.
 
-    Degenerate inputs short-circuit before any graph is built: empty
-    expert lists raise, and when no (token, expert) pair routes anywhere
-    (T == 0, or top-k == 0) the result is all-zeros and ``ctx.n_graphs``
-    does not move — an empty dispatch runs zero graphs, not two empty
-    ones.
+    The held pairs sort by expert on the host and pad, with zero rows that
+    take no gate, to :func:`pair_slots` pairs: at least ``capacity`` (the
+    chip's expert capacity), so the bank's work per call does not follow
+    the routing below it, and a call has one of a few shapes.  Then TWO
+    graphs run: the gate and up projections as one grouped MAC each over
+    the rows of every pair (:meth:`APExpertStack.add_call`), then, after
+    the float combine, the down projections as a third.  ``x`` quantises
+    per token and the hidden ``h`` per (token, expert) row, ``|q| <=
+    x_levels``.  Returns [T, d_out] float32.
+
+    Counters: ``moe.pairs_routed`` and ``moe.pairs_held`` (pairs of the
+    call), ``moe.layer_calls`` and ``moe.layers_empty`` (calls with a held
+    pair and with none; both run on the bank).  With no token or no
+    routing (T == 0 or k == 0) nothing runs and nothing counts.
+    ``ctx.on_moe``, when set, gets the call's record: ``layer``,
+    ``experts`` (the routed ids, [T, k]), ``x_levels`` [T, d], ``pairs``
+    (token, global expert) [P, 2] of the held pairs, ``launched`` (the
+    padded pair count) and ``h_levels`` [launched, d_ff], whose first P
+    rows are the held pairs'.
     """
-    if not (len(w1_lins) == len(w3_lins) == len(w2_lins)):
+    if not isinstance(w1, APExpertStack) and not (
+            len(w1) == len(w3) == len(w2)):
         raise ValueError(
-            f"expert list lengths disagree: w1={len(w1_lins)} "
-            f"w3={len(w3_lins)} w2={len(w2_lins)}")
-    if not w2_lins:
+            f"expert list lengths disagree: w1={len(w1)} "
+            f"w3={len(w3)} w2={len(w2)}")
+    if not isinstance(w2, APExpertStack) and not w2:
         raise ValueError("ap_moe_dispatch needs at least one expert")
     t, k = expert_ids.shape
-    n_out = w2_lins[0].n
-    eids = np.asarray(expert_ids).reshape(-1)              # host dispatch
-    flat_gates = gates.reshape(-1)
-    groups = []                                            # (e, pair_idx)
-    for e in range(len(w1_lins)):
-        pair_idx = np.nonzero(eids == e)[0]
-        if pair_idx.size:
-            groups.append((e, pair_idx))
-    if not groups:                         # T == 0 or k == 0: nothing routed
+    n_out = w2.n if isinstance(w2, APExpertStack) else w2[0].n
+    if t == 0 or k == 0:                   # nothing routed: nothing runs
         return jnp.zeros((t, n_out), jnp.float32)
+    w1, w3, w2 = (_expert_stack_of(w) for w in (w1, w3, w2))
+    if not w1.e == w3.e == w2.e:
+        raise ValueError(f"expert stacks disagree: {w1}, {w3}, {w2}")
+    reg = get_registry()
+    with trace.annotate("ap.moe.route"):
+        local = np.asarray(expert_ids).reshape(-1) - first   # host sort
+        held = np.nonzero((local >= 0) & (local < w1.e))[0]
+        pairs = held[np.argsort(local[held], kind="stable")]
+        tok, ex = pairs // k, local[pairs]
+    reg.counter("moe.pairs_routed").inc(t * k)
+    reg.counter("moe.pairs_held").inc(len(pairs))
+    reg.counter("moe.layer_calls" if len(pairs) else
+                "moe.layers_empty").inc()
+    record = {"layer": layer, "experts": np.asarray(expert_ids),
+              "pairs": np.stack([tok, ex + first], axis=1)}
 
-    x_int, s_x = ctx.quantize(x2d)
-    g1 = ProgramGraph()
-    calls = []
-    for e, pair_idx in groups:
-        tok = jnp.asarray(pair_idx // k, jnp.int32)
-        sub = x_int[tok]
-        c1 = w1_lins[e].add_call(g1, sub, max_cols=ctx.max_cols,
-                                 max_q=ctx.x_levels)
-        c3 = w3_lins[e].add_call(g1, sub, max_cols=ctx.max_cols,
-                                 max_q=ctx.x_levels)
-        calls.append((e, pair_idx, tok, c1, c3))
+    with trace.annotate("ap.moe.dispatch"):
+        # padding rows: a zero input through the first held expert (or
+        # expert 0), taking no gate
+        n_pad = pair_slots(len(pairs), capacity, t * k)
+        tok_p, ex_p, pairs_p = (np.concatenate(
+            [a, np.full(n_pad - len(a), a[0] if len(a) else 0, a.dtype)])
+            for a in (tok, ex, pairs))
+        tok_j, pairs_j = jnp.asarray(tok_p, jnp.int32), \
+            jnp.asarray(pairs_p, jnp.int32)
+        n_real = jnp.int32(len(pairs))
+        x_int, s_x = ctx.quantize_rows(x2d)
+        xp, sp = _take_rows(x_int, s_x, tok_j, n_real)
+        g1 = ProgramGraph()
+        c1 = w1.add_call(g1, xp, ex_p, max_cols=ctx.max_cols,
+                         max_q=ctx.x_levels)
+        c3 = w3.add_call(g1, xp, ex_p, max_cols=ctx.max_cols,
+                         max_q=ctx.x_levels)
     res1 = ctx.run_graph(g1)
-
-    g2 = ProgramGraph()
-    down = []
-    for e, pair_idx, tok, c1, c3 in calls:
-        h = act(c1.decode(res1, s_x)) * c3.decode(res1, s_x)
-        h_int, s_h = ctx.quantize(h)
-        c2 = w2_lins[e].add_call(g2, h_int, max_cols=ctx.max_cols,
-                                 max_q=ctx.x_levels)
-        down.append((pair_idx, s_h, c2))
+    with trace.annotate("ap.moe.combine"):
+        h = _glu(c1.decode(res1, sp), c3.decode(res1, sp), act=act)
+    with trace.annotate("ap.moe.dispatch"):
+        h_int, s_h = ctx.quantize_rows(h)
+        g2 = ProgramGraph()
+        c2 = w2.add_call(g2, h_int, ex_p, max_cols=ctx.max_cols,
+                         max_q=ctx.x_levels)
     res2 = ctx.run_graph(g2)
-
-    y2d = jnp.zeros((t, n_out), jnp.float32)
-    for pair_idx, s_h, c2 in down:
-        y_e = c2.decode(res2, s_h)
-        gsel = flat_gates[jnp.asarray(pair_idx, jnp.int32)]
-        tok = jnp.asarray(pair_idx // k, jnp.int32)
-        y2d = y2d.at[tok].add(y_e * gsel[:, None].astype(jnp.float32))
+    with trace.annotate("ap.moe.combine"):
+        y2d = _gated_sum(c2.decode(res2, s_h), gates.reshape(-1), pairs_j,
+                         tok_j, n_real, t=t)
+    if ctx.on_moe is not None:
+        ctx.on_moe(dict(record, x_levels=x_int, h_levels=h_int,
+                        launched=n_pad))
     return y2d
 
 
@@ -600,6 +818,27 @@ _AP_CTX: contextvars.ContextVar[APServeContext | None] = \
 # many requests can share one APServeContext without sharing accounting
 _AP_SCOPE: contextvars.ContextVar[tuple | None] = \
     contextvars.ContextVar("ap_request_scope", default=None)
+
+
+# the index of the model layer being served, set by the model's layer loop
+# under an AP context (keys of per-layer state such as expert stacks)
+_AP_LAYER: contextvars.ContextVar[int | None] = \
+    contextvars.ContextVar("ap_layer", default=None)
+
+
+@contextmanager
+def ap_layer(index: int):
+    """Mark the work inside as model layer ``index``'s."""
+    token = _AP_LAYER.set(index)
+    try:
+        yield index
+    finally:
+        _AP_LAYER.reset(token)
+
+
+def current_ap_layer() -> int | None:
+    """The model layer being served, or None outside the layer loop."""
+    return _AP_LAYER.get()
 
 
 @contextmanager

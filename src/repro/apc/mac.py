@@ -391,6 +391,33 @@ def matmul_mac_rows(x_int: jax.Array, w_ter: jax.Array
             jnp.tile(w_ter.T, (t, 1)))
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("n_out", "tiles", "radix", "width"))
+def grouped_mac_tile_rows_jnp(x_int: jax.Array, groups: jax.Array,
+                              plane: jax.Array, *, n_out: int,
+                              tiles: tuple[tuple[int, int], ...],
+                              radix: int, width: int
+                              ) -> tuple[jax.Array, ...]:
+    """Every tile's MAC rows of a grouped matmul, in one call.
+
+    ``plane`` [G * n_out, K] stacks the weight digit planes of G
+    projections (group ``g``'s output ``n`` is plane row ``g * n_out + n``);
+    row ``p * n_out + n`` multiplies ``x_int[p]`` [P, K] with the weights
+    of output ``n`` of group ``groups[p]`` — :func:`matmul_mac_rows`'s
+    layout, each row's weight digits taken from its own group.  Returns
+    one ``[P * n_out, cols]`` block per ``tiles`` entry, laid out as
+    :func:`assemble_mac_rows_jnp` lays out a tile's rows."""
+    x_digits = encode_mac_x_rows_jnp(jnp.repeat(x_int, n_out, axis=0),
+                                     radix, width)       # k-major, once
+    rows = (groups[:, None] * n_out
+            + jnp.arange(n_out, dtype=groups.dtype)[None, :]).reshape(-1)
+    w_rows = plane[rows]
+    acc = jnp.zeros((rows.shape[0], width + 1), jnp.int8)   # ACC and C
+    return tuple(jnp.concatenate([x_digits[:, lo * width:hi * width],
+                                  w_rows[:, lo:hi], acc], axis=1)
+                 for lo, hi in tiles)
+
+
 # ---------------------------------------------------------------------------
 # K-tiling: per-tile partial-sum programs + ripple-add reduction
 # ---------------------------------------------------------------------------

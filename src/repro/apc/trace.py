@@ -27,6 +27,12 @@ and whether the host works or waits inside it:
 - ``ap.model.step`` — one model step of a request, its token sync included;
 - ``ap.model.graph_build`` — quantize and projection graph build, and
   each graph node's input build (the encode) where the runtime runs it;
+- ``ap.moe.route`` — a MoE layer's router and top-k, and the host sort of
+  its (token, expert) pairs onto the held experts;
+- ``ap.moe.dispatch`` — a MoE layer's grouped-MAC graph builds (with the
+  quantize of its inputs);
+- ``ap.moe.combine`` — a MoE layer's float work between and after its
+  graphs: gate and up decoded and combined, the gated sum of the down rows;
 - ``ap.runtime.run_graph`` — one program graph through the runtime;
 - ``ap.pool.run`` — one program over the bank, output concatenate included;
 - ``ap.pool.launch`` — one launch: slice, pad, kernel dispatch;

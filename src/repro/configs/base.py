@@ -20,6 +20,27 @@ class MoECfg:
     capacity_factor: float = 1.25
     norm_topk: bool = True         # renormalize top-k gate values
     parallelism: str = "tp"        # "tp" (baseline) | "ep" (hillclimb)
+    # the experts this chip holds, [first, stop) of n_experts (one chip's
+    # share under expert parallelism); None holds all.  The router keeps
+    # all n_experts outputs and top_k; the layer returns only the held
+    # experts' part of the gated sum.
+    held: tuple[int, int] | None = None
+
+    def __post_init__(self):
+        if self.held is not None:
+            first, stop = self.held
+            if not 0 <= first < stop <= self.n_experts:
+                raise ValueError(f"held experts [{first}, {stop}) outside "
+                                 f"the {self.n_experts} experts")
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        first, stop = self.held_range
+        return stop - first
 
 
 @dataclass(frozen=True)
@@ -111,6 +132,20 @@ class ModelConfig:
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
+
+    def expert_share(self, chip: int, n_chips: int) -> "ModelConfig":
+        """This configuration as chip ``chip`` of ``n_chips`` serves it
+        under expert parallelism: the MoE layers hold the chip's contiguous
+        share of the experts; everything else is replicated."""
+        if self.moe is None:
+            raise ValueError(f"{self.name} has no experts to share")
+        e = self.moe.n_experts
+        if e % n_chips or not 0 <= chip < n_chips:
+            raise ValueError(f"{e} experts do not split into chip {chip} "
+                             f"of {n_chips}")
+        per = e // n_chips
+        return replace(self, moe=replace(self.moe,
+                                         held=(chip * per, (chip + 1) * per)))
 
     # -- parameter counting (for 6ND model flops) ---------------------------
     def param_counts(self) -> dict[str, int]:

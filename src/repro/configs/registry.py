@@ -30,5 +30,14 @@ def get_smoke_config(arch: str) -> ModelConfig:
     return _MODULES[arch].smoke_config()
 
 
+def get_share_config(arch: str, chip: int = 0) -> ModelConfig:
+    """The configuration as one chip of its expert-parallel serving host
+    holds it (architectures with a ``share_config``)."""
+    mod = _MODULES[arch]
+    if not hasattr(mod, "share_config"):
+        raise KeyError(f"{arch} has no expert share")
+    return mod.share_config(chip)
+
+
 def all_configs() -> dict[str, ModelConfig]:
     return {a: get_config(a) for a in ARCH_IDS}

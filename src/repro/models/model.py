@@ -11,9 +11,16 @@ gemma3's 5:1 local:global) unroll the pattern INSIDE the scan body.
 
 Activation-checkpoint policy per cfg.remat: "none" | "dots" | "full",
 applied to the super-block body.
+
+AP serving (an active :func:`repro.apc.layers.ap_serving` context): the
+bank's projections are host-orchestrated and cannot run under ``lax.scan``
+or ``jax.checkpoint`` (traced, they would fall back to float), so
+``forward`` and ``decode_step`` then run the stack layer by layer, each
+layer marked with its index (:func:`repro.apc.layers.ap_layer`).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any
 
@@ -51,6 +58,20 @@ def _layer_plan(cfg: ModelConfig) -> tuple[int, list[tuple[str, str]], int]:
     n_sb = cfg.n_layers // period
     n_rest = cfg.n_layers - n_sb * period
     return n_sb, pattern, n_rest
+
+
+def _ap_layers() -> bool:
+    """True while an AP serving context is active (see the module doc)."""
+    from ..apc.layers import current_ap_context
+    return current_ap_context() is not None
+
+
+def _layer_scope(index: int | None):
+    """Mark the layer being served for the AP context (nothing when None)."""
+    if index is None:
+        return contextlib.nullcontext()
+    from ..apc.layers import ap_layer
+    return ap_layer(index)
 
 
 def remat_wrap(fn, cfg: ModelConfig):
@@ -129,21 +150,28 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array, positions,
     if prefix == "enc_":
         n_sb, pattern, n_rest = cfg.enc_layers, [("attn", "mlp")], 0
     cdt = dtype_of(cfg.compute_dtype)
+    ap = _ap_layers()
+    period = len(pattern)
 
-    def sb_body(x, sb_params):
+    def sb_body(x, sb_params, sb=None):
         for i, (mk, fk) in enumerate(pattern):
             p_i = jax.tree.map(lambda a: a.astype(cdt) if a.dtype
                                in (jnp.float32, jnp.bfloat16) else a,
                                sb_params[f"pos_{i}"])
-            x = blk.block_forward(p_i, x, cfg, mk, fk, positions, mesh,
-                                  causal=causal, enc_out=enc_out)
+            with _layer_scope(None if sb is None else sb * period + i):
+                x = blk.block_forward(p_i, x, cfg, mk, fk, positions, mesh,
+                                      causal=causal, enc_out=enc_out)
         x = _constrain(x, mesh, None, None)
         return x, None
 
     body = remat_wrap(sb_body, cfg)
     stack_key = prefix + "stack"
     if stack_key in params and n_sb > 0:
-        if n_sb <= 2:          # unrolled: exact cost analysis (dry-run probes)
+        if ap:                 # AP serving: layer by layer, never traced
+            for sb in range(n_sb):
+                x, _ = sb_body(x, jax.tree.map(lambda a: a[sb],
+                                               params[stack_key]), sb)
+        elif n_sb <= 2:        # unrolled: exact cost analysis (dry-run probes)
             for sb in range(n_sb):
                 x, _ = body(x, jax.tree.map(lambda a: a[sb],
                                             params[stack_key]))
@@ -153,8 +181,9 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array, positions,
     for j in range(n_rest):
         mk, fk = pattern[j % len(pattern)]
         p_j = jax.tree.map(lambda a: a.astype(cdt), params[f"rest_{j}"])
-        x = blk.block_forward(p_j, x, cfg, mk, fk, positions, mesh,
-                              causal=causal, enc_out=enc_out)
+        with _layer_scope(n_sb * period + j if ap else None):
+            x = blk.block_forward(p_j, x, cfg, mk, fk, positions, mesh,
+                                  causal=causal, enc_out=enc_out)
     return x
 
 
@@ -228,24 +257,29 @@ def decode_step(cfg: ModelConfig, params: Params, cache: dict,
         x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
     x = _constrain(x, mesh, None, None)
 
-    def sb_body(x, scanned):
+    ap = _ap_layers()
+    period = len(pattern)
+
+    def sb_body(x, scanned, sb=None):
         sb_params, sb_cache = scanned
         new_cache = {}
         for i, (mk, fk) in enumerate(pattern):
             p_i = jax.tree.map(lambda a: a.astype(cdt) if a.dtype
                                in (jnp.float32, jnp.bfloat16) else a,
                                sb_params[f"pos_{i}"])
-            x, new_cache[f"pos_{i}"] = blk.block_decode(
-                p_i, x, sb_cache[f"pos_{i}"], cfg, mk, fk, pos, mesh)
+            with _layer_scope(None if sb is None else sb * period + i):
+                x, new_cache[f"pos_{i}"] = blk.block_decode(
+                    p_i, x, sb_cache[f"pos_{i}"], cfg, mk, fk, pos, mesh)
         return x, new_cache
 
     new_cache: dict = {}
     if n_sb > 0:
-        if n_sb <= 2:
+        if n_sb <= 2 or ap:    # AP serving: layer by layer, never traced
             outs = []
             for sb in range(n_sb):
                 x, c_sb = sb_body(x, jax.tree.map(
-                    lambda a: a[sb], (params["stack"], cache["stack"])))
+                    lambda a: a[sb], (params["stack"], cache["stack"])),
+                    sb if ap else None)
                 outs.append(c_sb)
             new_cache["stack"] = jax.tree.map(
                 lambda *xs: jnp.stack(xs), *outs)
@@ -255,8 +289,9 @@ def decode_step(cfg: ModelConfig, params: Params, cache: dict,
     for j in range(n_rest):
         mk, fk = pattern[j % len(pattern)]
         p_j = jax.tree.map(lambda a: a.astype(cdt), params[f"rest_{j}"])
-        x, new_cache[f"rest_{j}"] = blk.block_decode(
-            p_j, x, cache[f"rest_{j}"], cfg, mk, fk, pos, mesh)
+        with _layer_scope(n_sb * period + j if ap else None):
+            x, new_cache[f"rest_{j}"] = blk.block_decode(
+                p_j, x, cache[f"rest_{j}"], cfg, mk, fk, pos, mesh)
     x = rms_norm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = x[:, 0] @ params["embed"]["table"].astype(cdt).T
@@ -264,6 +299,37 @@ def decode_step(cfg: ModelConfig, params: Params, cache: dict,
         logits = x[:, 0] @ params["lm_head"]["w"].astype(cdt)
     logits = _constrain(logits, mesh, "model")
     return logits, new_cache
+
+
+def prepare_ap(cfg: ModelConfig, params: Params, ctx) -> Params:
+    """Convert, once, what an AP serving context serves from the weights
+    as given: every MoE layer's held experts, keyed by layer index (see
+    :func:`repro.models.moe.prepare_moe_ap`).  Returns the parameters to
+    serve: ``params`` without the converted expert weights, which the bank
+    holds from now on (the same leaves as given for dense models)."""
+    from .moe import EXPERT_LEAVES, prepare_moe_ap
+
+    def drop(p):
+        moe = {n: a for n, a in p["moe"].items() if n not in EXPERT_LEAVES}
+        return dict(p, moe=moe)
+
+    n_sb, pattern, n_rest = _layer_plan(cfg)
+    period = len(pattern)
+    out = dict(params)
+    for i, (_, fk) in enumerate(pattern):
+        if fk != "moe" or "stack" not in params:
+            continue
+        pos = params["stack"][f"pos_{i}"]
+        for sb in range(n_sb):
+            prepare_moe_ap(ctx, sb * period + i,
+                           {n: a[sb] for n, a in pos["moe"].items()
+                            if n in EXPERT_LEAVES})
+        out["stack"] = dict(out["stack"], **{f"pos_{i}": drop(pos)})
+    for j in range(n_rest):
+        if pattern[j % period][1] == "moe":
+            prepare_moe_ap(ctx, n_sb * period + j, params[f"rest_{j}"]["moe"])
+            out[f"rest_{j}"] = drop(params[f"rest_{j}"])
+    return out
 
 
 # ---------------------------------------------------------------------------

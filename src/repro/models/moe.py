@@ -18,10 +18,15 @@ Both paths use the same local sort-based dispatch:
   router -> top-k -> flat (token, expert) pairs sorted by expert ->
   position-in-expert via rank-within-segment -> capacity-dropped scatter into
   an [E, C, d] buffer -> block-diagonal expert einsum -> weighted combine.
+
+One chip's share (``MoECfg.held``): the layer holds only experts [first,
+stop), routes over all of them, and returns the held experts' part of the
+gated sum, on the AP bank (:func:`moe_ffn_ap`); it is served only there.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -32,10 +37,11 @@ from .common import MODEL_AXIS, act_fn, dense_init, mesh_data_axes
 
 
 def init_moe(key, d_model: int, cfg: MoECfg, dtype=jnp.float32) -> dict:
+    """Router over all experts; expert weights of the held ones."""
     k0, k1, k2, k3 = jax.random.split(key, 4)
-    e, ff = cfg.n_experts, cfg.d_ff
+    e, ff = cfg.n_held, cfg.d_ff
     return {
-        "router": dense_init(k0, (d_model, e), 0, jnp.float32),
+        "router": dense_init(k0, (d_model, cfg.n_experts), 0, jnp.float32),
         "w1": dense_init(k1, (e, d_model, ff), 1, dtype),
         "w3": dense_init(k2, (e, d_model, ff), 1, dtype),
         "w2": dense_init(k3, (e, ff, d_model), 1, dtype),
@@ -160,24 +166,54 @@ def _local_moe_ep(x, router, w1, w3, w2, *, cfg: MoECfg, act: str,
     return y_full.reshape(b, s, d)
 
 
+def expert_key(layer: int | None, name: str) -> str:
+    """The AP serving context's key (and resident label) of a MoE layer's
+    projection ``name``."""
+    return f"moe.{name}" if layer is None else f"moe.L{layer}.{name}"
+
+
+EXPERT_LEAVES = ("w1", "w3", "w2")
+
+
+def held_capacity(cfg: MoECfg, n_tokens: int) -> int:
+    """The (token, expert) pairs the held experts take at capacity:
+    ``capacity_factor`` times their share of ``n_tokens`` x top_k pairs
+    under even routing."""
+    return math.ceil(cfg.capacity_factor * n_tokens * cfg.top_k
+                     * cfg.n_held / cfg.n_experts)
+
+
+def prepare_moe_ap(ctx, layer: int | None, p: dict) -> None:
+    """Convert one MoE layer's held experts for the AP bank, once, from the
+    weights given (ternary, absmean per output channel, in float32); a
+    projection whose weights ``p`` leaves out must be converted already."""
+    for name in EXPERT_LEAVES:
+        ctx.expert_stack(expert_key(layer, name), p.get(name))
+
+
 def moe_ffn_ap(p: dict, x: jax.Array, cfg: MoECfg, act: str,
                ctx) -> jax.Array:
-    """AP-served MoE: router runs in float, then every routed expert's
-    SwiGLU projections go through :func:`repro.apc.layers.ap_moe_dispatch`
-    as independent tiled-MAC subgraphs of one ProgramGraph — tiles of
-    different experts interleave across the array bank, the multi-matmul
-    occupancy workload the AP runtime exists for.  Expert weights ternarize
-    (absmean per-channel) via the context's per-stack cache."""
-    from ..apc.layers import ap_moe_dispatch
+    """AP-served MoE: the router runs in float over all experts (top-k,
+    renormalised), then the held experts' SwiGLU projections go through
+    :func:`repro.apc.layers.ap_moe_dispatch` as grouped MACs — one per
+    projection over every held (token, expert) pair, launching at least
+    the held experts' capacity (:func:`held_capacity`).  The expert stacks
+    are the context's, keyed by the layer being served
+    (:func:`~repro.apc.layers.current_ap_layer`) and converted once
+    (:func:`prepare_moe_ap`, or here on first use)."""
+    from ..apc import trace
+    from ..apc.layers import ap_moe_dispatch, current_ap_layer
     b, s, d = x.shape
     x2d = x.reshape(b * s, d)
-    gates, experts = _route(x2d, p["router"], cfg)
-    w1l = ctx.expert_linears("moe.w1", p["w1"], label="moe.w1.")
-    w3l = ctx.expert_linears("moe.w3", p["w3"], label="moe.w3.")
-    w2l = ctx.expert_linears("moe.w2", p["w2"], label="moe.w2.")
-    y2d = ap_moe_dispatch(ctx, x2d, experts, gates, w1l, w3l, w2l,
-                          act_fn(act))
-    return y2d.reshape(b, s, d).astype(x.dtype)
+    with trace.annotate("ap.moe.route"):
+        gates, experts = _route(x2d, p["router"], cfg)
+    layer = current_ap_layer()
+    w1, w3, w2 = (ctx.expert_stack(expert_key(layer, n), p.get(n))
+                  for n in EXPERT_LEAVES)
+    y2d = ap_moe_dispatch(ctx, x2d, experts, gates, w1, w3, w2,
+                          act_fn(act), first=cfg.held_range[0],
+                          capacity=held_capacity(cfg, b * s), layer=layer)
+    return y2d.reshape(b, s, -1).astype(x.dtype)
 
 
 def moe_ffn(p: dict, x: jax.Array, cfg: MoECfg, act: str,
@@ -187,6 +223,10 @@ def moe_ffn(p: dict, x: jax.Array, cfg: MoECfg, act: str,
     ctx = current_ap_context()
     if ctx is not None:                      # AP-backed serving path
         return moe_ffn_ap(p, x, cfg, act, ctx)
+    if cfg.held is not None:
+        raise ValueError(f"experts {cfg.held} of {cfg.n_experts} are one "
+                         f"chip's share, served only on the AP bank "
+                         f"(an ap_serving context)")
     tp_size = mesh.shape[MODEL_AXIS]
     da = mesh_data_axes(mesh)
     dp = 1

@@ -21,7 +21,9 @@ away.
 
 AP-backed serving: constructing the engine with ``ap_ctx`` (an
 :class:`repro.apc.layers.APServeContext`) routes every packed-ternary MLP /
-MoE projection of the forward pass through the AP program-graph runtime —
+MoE projection of the forward pass through the AP program-graph runtime
+(MoE experts converted once, at construction, by ``model.prepare_ap``, and
+dropped from the served parameters) —
 the step runs eagerly (the AP path is the functional simulator, with host
 syncs), and :meth:`Engine.ap_report` returns the request's aggregated
 write/compare cycles, Table XI energy, and graph-scheduler makespan.
@@ -181,7 +183,9 @@ class Engine:
     def __init__(self, cfg: ModelConfig, params, mesh, serve: ServeCfg,
                  ap_ctx=None, slo=None):
         self.cfg = cfg
-        self.params = params
+        # convert what the bank serves, once; the bank then holds it
+        self.params = (params if ap_ctx is None
+                       else M.prepare_ap(cfg, params, ap_ctx))
         self.mesh = mesh
         self.serve = serve
         self.ap_ctx = ap_ctx

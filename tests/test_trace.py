@@ -437,6 +437,74 @@ def test_pool_spans_on_the_profiler_clock(profiled):
     assert run[2] <= evs[-1][1]                 # the sync follows the run
 
 
+def _moe_layer(seed=0, t=3, e_all=8, k=3, held=(2, 6), d=8, ff=6):
+    """A seeded MoE layer call's inputs: tokens, the router's top-k over
+    all ``e_all`` experts, and the held share's stacks."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs.base import MoECfg
+    from repro.models.moe import _route
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(0, 1, (t, d)), jnp.float32)
+    router = jnp.asarray(rng.normal(0, 1, (d, e_all)), jnp.float32)
+    gates, ids = _route(x, router, MoECfg(n_experts=e_all, top_k=k,
+                                          d_ff=ff, held=held))
+    pool = apc.ArrayPool(n_arrays=2, rows=64, cols=96)
+    ctx = apc.APServeContext(apc.Runtime(pool), x_levels=7)
+    n = held[1] - held[0]
+    stacks = [apc.APExpertStack.from_dense(
+        jnp.asarray(rng.normal(0, 1, shape), jnp.float32), store=pool.resident)
+        for shape in ((n, d, ff), (n, d, ff), (n, ff, d))]
+    return ctx, x, ids, gates, stacks
+
+
+def test_moe_spans_on_the_profiler_clock(profiled):
+    """A MoE layer call opens ap.moe.route (the host sort of pairs),
+    ap.moe.dispatch (graph builds) and ap.moe.combine (float combine) on
+    the calling thread, in order, around the two graph runs."""
+    import jax
+    ctx, x, ids, gates, stacks = _moe_layer()
+    call = lambda: apc.ap_moe_dispatch(ctx, x, ids, gates, *stacks,  # noqa
+                                       jax.nn.silu, first=2)
+    call()                                                     # compile
+    with trace.disabled():
+        _, spans = profiled(call)
+    (thread, evs), = spans.items()
+    moe = [e[0] for e in evs if e[0].startswith("ap.moe.")]
+    assert moe == ["ap.moe.route", "ap.moe.dispatch", "ap.moe.combine",
+                   "ap.moe.dispatch", "ap.moe.combine"]
+    graphs = [e for e in evs if e[0] == "ap.runtime.run_graph"]
+    first_dispatch, first_combine = [e for e in evs
+                                     if e[0].startswith("ap.moe.")][1:3]
+    assert len(graphs) == 2
+    assert first_dispatch[2] <= graphs[0][1] <= graphs[0][2] \
+        <= first_combine[1]
+
+
+def test_moe_counters_count_routed_and_held_pairs():
+    """moe.pairs_routed / moe.pairs_held count a seeded call's (token,
+    expert) pairs, all and on the held share; a call with a held pair is
+    one moe.layer_calls, one with none one moe.layers_empty."""
+    import jax
+    import jax.numpy as jnp
+    reg = metrics.get_registry()
+    ctx, x, ids, gates, stacks = _moe_layer(seed=1)
+    e = np.asarray(ids)
+    names = ("moe.pairs_routed", "moe.pairs_held", "moe.layer_calls",
+             "moe.layers_empty")
+    before = reg.counter_values(names)
+    apc.ap_moe_dispatch(ctx, x, ids, gates, *stacks, jax.nn.silu, first=2)
+    outside = jnp.asarray([[0, 1, 7]] * 3, jnp.int32)
+    apc.ap_moe_dispatch(ctx, x, outside, gates, *stacks, jax.nn.silu,
+                        first=2)
+    after = reg.counter_values(names)
+    got = {n: after[n] - before[n] for n in names}
+    assert got == {"moe.pairs_routed": 2 * e.size,
+                   "moe.pairs_held": int(((e >= 2) & (e < 6)).sum()),
+                   "moe.layer_calls": 1, "moe.layers_empty": 1}
+    assert got["moe.pairs_held"] > 0
+
+
 def test_attribution_sums_bit_exactly_to_ap_stats():
     x, w = _mac_inputs(seed=5)
     radix, width, K = 3, 8, x.shape[1]
