@@ -146,8 +146,7 @@ def print_tables(tracer: trace.Tracer, report: dict) -> None:
               f"size={info['currsize']}/{info['maxsize']}")
     print(f"  pool_schedules        "
           f"{cache.get('pool_schedules')}/{cache.get('pool_schedules_max')}")
-    print(f"  linears               "
-          f"{cache.get('linears')}/{cache.get('linears_max')}")
+    print(f"  stacks                {cache.get('stacks')}")
 
     print("\n== scheduler ==")
     print(f"  makespan_cycles       {report['makespan_cycles']}")

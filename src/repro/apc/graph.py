@@ -293,6 +293,9 @@ class ProgramGraph:
         weight digit column, resident tile nodes charge the x columns
         only, reduce nodes their fresh partial columns.  The default
         (False) keeps the historical upload-free model.
+
+        Each tile node builds its own rows, eagerly (counted by the
+        ``mac.tile_rows_eager`` counter).
         """
         R, K = x.shape
         if K != tiled.K:
@@ -309,11 +312,13 @@ class ProgramGraph:
         for lo, hi in tiled.tiles:
             if resident is None:
                 def build_tile(*, _lo=lo, _hi=hi):
+                    get_registry().counter("mac.tile_rows_eager").inc()
                     return encode_mac_rows_jnp(x[:, _lo:_hi],
                                                w_ter[:, _lo:_hi],
                                                radix, width)
             else:
                 def build_tile(*, _lo=lo, _hi=hi, _h=resident):
+                    get_registry().counter("mac.tile_rows_eager").inc()
                     wd = _resolve_or_repin(_h)[:, _lo:_hi]
                     if R // wd.shape[0] > 1:
                         wd = jnp.tile(wd, (R // wd.shape[0], 1))
@@ -336,8 +341,10 @@ class ProgramGraph:
         different projections share every launch: one schedule (``tiled``,
         compiled against the union of their digit support) replays them
         all.  Every tile's rows are built by one call, on the first tile
-        node's build; returns the node id of the [P * n_out, width]
-        accumulator block."""
+        node's build (counted by ``mac.tile_row_builds``; each tile of
+        :meth:`add_mac_tiled` is built on its own, counted by
+        ``mac.tile_rows_eager``); returns the node id of the
+        [P * n_out, width] accumulator block."""
         P, K = x_int.shape
         rw, kw = resident.plane.shape
         if K != tiled.K or kw != K or rw % n_out:
@@ -348,6 +355,7 @@ class ProgramGraph:
 
         def all_tiles():
             if not built:
+                get_registry().counter("mac.tile_row_builds").inc()
                 built.append(grouped_mac_tile_rows_jnp(
                     x_int, groups, _resolve_or_repin(resident), n_out=n_out,
                     tiles=tiled.tiles, radix=tiled.radix,

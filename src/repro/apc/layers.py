@@ -21,7 +21,9 @@ draining them one by one.
   AP's.
 - :class:`APExpertStack` — the held experts of one MoE projection as ONE
   resident digit plane and one schedule: a grouped MAC whose rows belong
-  to many experts.
+  to many experts.  A dense MLP projection is served as a stack of one
+  (``models/mlp.py``), converted once and kept by the context under its
+  layer and projection (:meth:`APServeContext.stack`).
 - :func:`ap_moe_dispatch` — sort (token, expert) pairs on the host and run
   every held pair's gate and up projections as one grouped MAC each, then
   the down projections as a third (the multi-array occupancy workload of
@@ -223,7 +225,8 @@ class APGroupedCall(NamedTuple):
     w_scale: jax.Array             # [G, N]: every projection's scale
 
     def decode(self, results, x_scale) -> jax.Array:
-        """[P, N] float32; ``x_scale`` [P] (or [P, 1]) per input row."""
+        """[P, N] float32; ``x_scale`` [P] (or [P, 1]) per input row, or
+        one scale for every row."""
         return _decode_rows(results[self.node], jnp.asarray(x_scale),
                             self.w_scale, self.groups, radix=self.radix)
 
@@ -232,12 +235,12 @@ class APGroupedCall(NamedTuple):
 def _decode_rows(digits, x_scale, w_scale, groups, *, radix: int
                  ) -> jax.Array:
     """Accumulator digits [P * N, width] -> [P, N] float32, scaled per
-    input row and by each row's projection's output scales (one compiled
-    call per shape)."""
+    input row (or by one scale) and by each row's projection's output
+    scales (one compiled call per shape)."""
     p, n = groups.shape[0], w_scale.shape[1]
     acc = decode_signed_digits_jnp(digits, radix)
     y = acc.reshape(p, n).astype(jnp.float32)
-    return y * x_scale.astype(jnp.float32).reshape(p, 1) * w_scale[groups]
+    return y * x_scale.astype(jnp.float32).reshape(-1, 1) * w_scale[groups]
 
 
 @functools.partial(jax.jit, static_argnames=("levels",))
@@ -252,7 +255,8 @@ def quantize_rows_jnp(x, *, levels: int) -> tuple[jax.Array, jax.Array]:
 
 
 class APExpertStack:
-    """The experts of one MoE projection, served as ONE grouped MAC.
+    """The experts of one MoE projection (or one dense projection, a
+    stack of one), served as ONE grouped MAC.
 
     ``w_ter`` [E, K, N] in {-1, 0, +1}, ``w_scale`` [E, N] (absmean per
     output channel of each expert).  The experts' weights stack into one
@@ -297,6 +301,14 @@ class APExpertStack:
         w_ter, scale = jax.vmap(quantize_ternary)(
             jnp.asarray(w_stack, jnp.float32))
         return cls(w_ter, scale, **kw)
+
+    @classmethod
+    def from_packed(cls, packed: jax.Array, scale: jax.Array,
+                    **kw) -> "APExpertStack":
+        """A stack of one projection, from its 16-per-int32 packed serving
+        weights ``[K'/16, N]`` and scale ``[N]`` (the digits as served)."""
+        return cls(unpack_ternary(packed, dtype=jnp.int8)[None],
+                   jnp.asarray(scale)[None], **kw)
 
     @classmethod
     def from_linears(cls, lins: list["APLinear"], **kw) -> "APExpertStack":
@@ -508,13 +520,9 @@ class APServeContext:
         self.x_levels = int(x_levels)
         self.max_cols = max_cols if max_cols is not None \
             else runtime.pool.cols
-        # weight -> APLinear cache, id()-keyed with the source array pinned
-        # in the value; FIFO-capped like ArrayPool._schedules so a caller
-        # feeding fresh arrays per request cannot grow it without bound
-        self._linears: dict = {}
-        self._max_linears = 64
-        # MoE expert stacks, keyed by layer and projection: converted once
-        # (bounded by the model: one per MoE layer and projection)
+        # projection stacks (MoE experts, dense MLPs as stacks of one),
+        # keyed by layer and projection: converted once (bounded by the
+        # model: one per layer and projection)
         self._stacks: dict[str, APExpertStack] = {}
         # called with each MoE layer's dispatch record (see
         # ap_moe_dispatch), for checks that replay the layer
@@ -559,45 +567,35 @@ class APServeContext:
     def n_programs(self) -> int:
         return self._sink().n_programs
 
-    # -- projection cache ---------------------------------------------------
+    # -- projection stacks --------------------------------------------------
 
     def _resident_store(self) -> ResidentStore | None:
         return getattr(self.runtime.pool, "resident", None)
 
-    def linear(self, key, packed: jax.Array, scale: jax.Array,
-               label: str = "") -> APLinear:
-        """Cached APLinear for packed weights (one unpack per weight);
-        weights pin resident into the pool's bank at construction."""
-        ck = (key, id(packed))
-        hit = self._linears.get(ck)
+    def stack(self, key: str,
+              make: Callable[..., APExpertStack] | None = None
+              ) -> APExpertStack:
+        """The projection stack of ``key`` (one layer's projection), made on
+        first use by ``make(radix=, label=, store=)`` and pinned resident
+        under the label ``key``; later calls return it whatever they are
+        handed."""
+        hit = self._stacks.get(key)
         if hit is None:
-            hit = (packed, APLinear.from_packed(packed, scale,
-                                                radix=self.radix,
-                                                label=label,
-                                                store=self._resident_store()))
-            self._cache_put(ck, hit)       # pin packed so id() stays valid
-        return hit[1]
+            if make is None:
+                raise KeyError(f"no projection stack {key!r} converted yet")
+            hit = make(radix=self.radix, label=key,
+                       store=self._resident_store())
+            self._stacks[key] = hit
+        return hit
 
     def expert_stack(self, key: str, w_stack: jax.Array | None = None
                      ) -> APExpertStack:
         """The expert stack of ``key`` (one MoE layer's projection), made
-        on first use from the float stack ``w_stack`` [E, K, N] and pinned
-        resident under the label ``key``; later calls return it whatever
-        they are handed."""
-        hit = self._stacks.get(key)
-        if hit is None:
-            if w_stack is None:
-                raise KeyError(f"no expert stack {key!r} converted yet")
-            hit = APExpertStack.from_dense(w_stack, radix=self.radix,
-                                           label=key,
-                                           store=self._resident_store())
-            self._stacks[key] = hit
-        return hit
-
-    def _cache_put(self, ck, value) -> None:
-        while len(self._linears) >= self._max_linears:    # FIFO evict
-            self._linears.pop(next(iter(self._linears)))
-        self._linears[ck] = value
+        on first use from the float stack ``w_stack`` [E, K, N] (see
+        :meth:`stack`)."""
+        return self.stack(key, None if w_stack is None else
+                          functools.partial(APExpertStack.from_dense,
+                                            w_stack))
 
     # -- quantization -------------------------------------------------------
 
@@ -641,16 +639,14 @@ class APServeContext:
     def cache_stats(self) -> dict:
         """Occupancy of every compilation/serving cache this context rides:
         the process-wide bounded compile caches (:mod:`repro.apc.caches`),
-        the pool's uploaded-schedule store, and the per-context APLinear
-        cache — the numbers to watch in a long-running serve.Engine."""
+        the pool's uploaded-schedule store, and the context's projection
+        stacks — the numbers to watch in a long-running serve.Engine."""
         from .caches import cache_stats
         out = {
             "compile": cache_stats(),
             "pool_schedules": len(self.runtime.pool._schedules),
             "pool_schedules_max": self.runtime.pool._max_schedules,
-            "linears": len(self._linears),
-            "linears_max": self._max_linears,
-            "expert_stacks": len(self._stacks),
+            "stacks": len(self._stacks),
         }
         store = self._resident_store()
         if store is not None:

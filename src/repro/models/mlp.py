@@ -9,11 +9,15 @@ with the Pallas kernel validated separately as the TPU execution path.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..kernels.ternary_matmul.ref import quantize_ternary
 from .common import act_fn, dense_init
+from .quant import MLP_KEYS
 
 
 def ternary_linear(x: jax.Array, w: jax.Array, qat: bool) -> jax.Array:
@@ -42,38 +46,67 @@ def init_mlp(key, d_model: int, d_ff: int, dtype=jnp.float32) -> dict:
     }
 
 
+def mlp_key(layer: int | None, name: str) -> str:
+    """The AP serving context's key (and resident label) of a dense MLP
+    layer's projection ``name``."""
+    return f"mlp.{name}" if layer is None else f"mlp.L{layer}.{name}"
+
+
+def _packed_stack(p: dict, name: str):
+    """``make`` for :meth:`~repro.apc.layers.APServeContext.stack`: the
+    served packed weights of projection ``name`` as a stack of one."""
+    from ..apc.layers import APExpertStack
+    return functools.partial(APExpertStack.from_packed, p[f"{name}_packed"],
+                             p[f"{name}_scale"])
+
+
+def prepare_mlp_ap(ctx, layer: int | None, p: dict) -> None:
+    """Convert one dense MLP layer's packed projections for the AP bank,
+    once: each a stack of one projection, keyed by layer and projection,
+    its digits and scale those served (``p`` as the layer's step sees
+    it)."""
+    for name in MLP_KEYS:
+        ctx.stack(mlp_key(layer, name), _packed_stack(p, name))
+
+
 def mlp_ap(p: dict, x: jax.Array, act: str, ctx) -> jax.Array:
     """AP-served SwiGLU on packed ternary weights: gate and up projections
     are INDEPENDENT tiled-MAC subgraphs of one ProgramGraph (the runtime
     interleaves their tiles across the array bank); the down projection
-    runs in a second graph after the float combine.  Activations quantize
-    to ``ctx.x_levels`` integers per projection — the AP arithmetic on the
+    runs in a second graph after the float combine.  Each projection is
+    the context's stack of one, keyed by the layer being served
+    (:func:`~repro.apc.layers.current_ap_layer`) and converted once
+    (:func:`prepare_mlp_ap`, or here on first use), so a tile node's build
+    makes every tile's rows in one call.  Activations quantize to
+    ``ctx.x_levels`` integers per projection — the AP arithmetic on the
     quantized grid is exact, and every compare/write cycle lands in
     ``ctx.stats`` for the per-request Table XI report."""
     from ..apc import trace
     from ..apc.graph import ProgramGraph
+    from ..apc.layers import current_ap_layer
     lead, d = x.shape[:-1], x.shape[-1]
     x2d = x.reshape(-1, d)
+    layer = current_ap_layer()
+    rows = np.zeros(x2d.shape[0], np.int32)   # every row on projection 0
+
+    def add(graph, w, q):
+        if q.shape[1] < w.k:              # pack-time padding rows: w == 0
+            q = jnp.pad(q, ((0, 0), (0, w.k - q.shape[1])))
+        return w.add_call(graph, q, rows, max_cols=ctx.max_cols,
+                          max_q=ctx.x_levels)
+
     with trace.annotate("ap.model.graph_build"):
-        lin1 = ctx.linear("w1", p["w1_packed"], p["w1_scale"],
-                          label="mlp.w1")
-        lin3 = ctx.linear("w3", p["w3_packed"], p["w3_scale"],
-                          label="mlp.w3")
-        lin2 = ctx.linear("w2", p["w2_packed"], p["w2_scale"],
-                          label="mlp.w2")
+        w1, w3, w2 = (ctx.stack(mlp_key(layer, n), _packed_stack(p, n))
+                      for n in MLP_KEYS)
         x_int, s_x = ctx.quantize(x2d)
         g1 = ProgramGraph()
-        c1 = lin1.add_call(g1, x_int, max_cols=ctx.max_cols,
-                           max_q=ctx.x_levels)
-        c3 = lin3.add_call(g1, x_int, max_cols=ctx.max_cols,
-                           max_q=ctx.x_levels)
+        c1, c3 = add(g1, w1, x_int), add(g1, w3, x_int)
     res1 = ctx.run_graph(g1)
     h = act_fn(act)(c1.decode(res1, s_x)) * c3.decode(res1, s_x)
     with trace.annotate("ap.model.graph_build"):
         h_int, s_h = ctx.quantize(h)
         g2 = ProgramGraph()
-        c2 = lin2.add_call(g2, h_int, max_cols=ctx.max_cols,
-                           max_q=ctx.x_levels)
+        c2 = add(g2, w2, h_int)
     res2 = ctx.run_graph(g2)
     y = c2.decode(res2, s_h)
     return y.reshape(*lead, y.shape[-1]).astype(x.dtype)

@@ -74,6 +74,14 @@ def _layer_scope(index: int | None):
     return ap_layer(index)
 
 
+def _served(p: Params, cdt) -> Params:
+    """A layer's parameters as its step computes with them: float leaves
+    in the compute dtype, integer leaves (packed ternary weights) as
+    they are."""
+    return jax.tree.map(lambda a: a.astype(cdt) if a.dtype
+                        in (jnp.float32, jnp.bfloat16) else a, p)
+
+
 def remat_wrap(fn, cfg: ModelConfig):
     if cfg.remat == "none":
         return fn
@@ -152,13 +160,15 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array, positions,
     cdt = dtype_of(cfg.compute_dtype)
     ap = _ap_layers()
     period = len(pattern)
+    # AP layer index of the stack's first layer: encoder layers follow the
+    # decoder's, so no two layers share a key of the AP context
+    first = cfg.n_layers if prefix == "enc_" else 0
 
     def sb_body(x, sb_params, sb=None):
         for i, (mk, fk) in enumerate(pattern):
-            p_i = jax.tree.map(lambda a: a.astype(cdt) if a.dtype
-                               in (jnp.float32, jnp.bfloat16) else a,
-                               sb_params[f"pos_{i}"])
-            with _layer_scope(None if sb is None else sb * period + i):
+            p_i = _served(sb_params[f"pos_{i}"], cdt)
+            with _layer_scope(None if sb is None
+                              else first + sb * period + i):
                 x = blk.block_forward(p_i, x, cfg, mk, fk, positions, mesh,
                                       causal=causal, enc_out=enc_out)
         x = _constrain(x, mesh, None, None)
@@ -180,8 +190,8 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array, positions,
                                 params[stack_key])
     for j in range(n_rest):
         mk, fk = pattern[j % len(pattern)]
-        p_j = jax.tree.map(lambda a: a.astype(cdt), params[f"rest_{j}"])
-        with _layer_scope(n_sb * period + j if ap else None):
+        p_j = _served(params[f"rest_{j}"], cdt)
+        with _layer_scope(first + n_sb * period + j if ap else None):
             x = blk.block_forward(p_j, x, cfg, mk, fk, positions, mesh,
                                   causal=causal, enc_out=enc_out)
     return x
@@ -264,9 +274,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: dict,
         sb_params, sb_cache = scanned
         new_cache = {}
         for i, (mk, fk) in enumerate(pattern):
-            p_i = jax.tree.map(lambda a: a.astype(cdt) if a.dtype
-                               in (jnp.float32, jnp.bfloat16) else a,
-                               sb_params[f"pos_{i}"])
+            p_i = _served(sb_params[f"pos_{i}"], cdt)
             with _layer_scope(None if sb is None else sb * period + i):
                 x, new_cache[f"pos_{i}"] = blk.block_decode(
                     p_i, x, sb_cache[f"pos_{i}"], cfg, mk, fk, pos, mesh)
@@ -288,7 +296,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: dict,
                 sb_body, x, (params["stack"], cache["stack"]))
     for j in range(n_rest):
         mk, fk = pattern[j % len(pattern)]
-        p_j = jax.tree.map(lambda a: a.astype(cdt), params[f"rest_{j}"])
+        p_j = _served(params[f"rest_{j}"], cdt)
         with _layer_scope(n_sb * period + j if ap else None):
             x, new_cache[f"rest_{j}"] = blk.block_decode(
                 p_j, x, cache[f"rest_{j}"], cfg, mk, fk, pos, mesh)
@@ -303,11 +311,28 @@ def decode_step(cfg: ModelConfig, params: Params, cache: dict,
 
 def prepare_ap(cfg: ModelConfig, params: Params, ctx) -> Params:
     """Convert, once, what an AP serving context serves from the weights
-    as given: every MoE layer's held experts, keyed by layer index (see
-    :func:`repro.models.moe.prepare_moe_ap`).  Returns the parameters to
-    serve: ``params`` without the converted expert weights, which the bank
-    holds from now on (the same leaves as given for dense models)."""
+    as given, keyed by layer index: every MoE layer's held experts (see
+    :func:`repro.models.moe.prepare_moe_ap`) and every packed dense MLP's
+    projections, as its step serves them (see
+    :func:`repro.models.mlp.prepare_mlp_ap`).  Returns the parameters to
+    serve: ``params`` without the converted expert weights, which the
+    bank holds from now on (the same leaves as given for dense models)."""
+    from .mlp import prepare_mlp_ap
     from .moe import EXPERT_LEAVES, prepare_moe_ap
+
+    cdt = dtype_of(cfg.compute_dtype)
+
+    def ffn_of(p, fk):
+        """The leaves of a layer's FFN that the bank serves."""
+        if fk == "moe":
+            return {n: a for n, a in p["moe"].items() if n in EXPERT_LEAVES}
+        return p["mlp"] if "w1_packed" in p["mlp"] else {}
+
+    def convert(layer, fk, ffn):
+        if fk == "moe":
+            prepare_moe_ap(ctx, layer, ffn)
+        elif ffn:
+            prepare_mlp_ap(ctx, layer, _served(ffn, cdt))
 
     def drop(p):
         moe = {n: a for n, a in p["moe"].items() if n not in EXPERT_LEAVES}
@@ -317,17 +342,19 @@ def prepare_ap(cfg: ModelConfig, params: Params, ctx) -> Params:
     period = len(pattern)
     out = dict(params)
     for i, (_, fk) in enumerate(pattern):
-        if fk != "moe" or "stack" not in params:
+        if fk not in ("moe", "mlp") or "stack" not in params:
             continue
         pos = params["stack"][f"pos_{i}"]
+        ffn = ffn_of(pos, fk)
         for sb in range(n_sb):
-            prepare_moe_ap(ctx, sb * period + i,
-                           {n: a[sb] for n, a in pos["moe"].items()
-                            if n in EXPERT_LEAVES})
-        out["stack"] = dict(out["stack"], **{f"pos_{i}": drop(pos)})
+            convert(sb * period + i, fk, {n: a[sb] for n, a in ffn.items()})
+        if fk == "moe":
+            out["stack"] = dict(out["stack"], **{f"pos_{i}": drop(pos)})
     for j in range(n_rest):
-        if pattern[j % period][1] == "moe":
-            prepare_moe_ap(ctx, n_sb * period + j, params[f"rest_{j}"]["moe"])
+        fk = pattern[j % period][1]
+        if fk in ("moe", "mlp"):
+            convert(n_sb * period + j, fk, ffn_of(params[f"rest_{j}"], fk))
+        if fk == "moe":
             out[f"rest_{j}"] = drop(params[f"rest_{j}"])
     return out
 

@@ -392,7 +392,7 @@ def test_smoke_share_is_served_only_on_the_bank(smoke_mesh):
     ctx = _tiny_ctx()
     served = M.prepare_ap(cfg, params, ctx)
     assert set(served["stack"]["pos_0"]["moe"]) == {"router"}
-    assert ctx.cache_stats()["expert_stacks"] == 3 * cfg.n_layers
+    assert ctx.cache_stats()["stacks"] == 3 * cfg.n_layers
     before = counters()
     with smoke_mesh, apc.ap_serving(ctx):
         logits, _ = M.decode_step(cfg, served, *args)

@@ -393,7 +393,7 @@ def test_ap_linear_exact_on_integer_grid():
     w = jnp.asarray(rng.normal(0, 0.05, (k, n)), jnp.float32)
     packed, scale = quantize_and_pack(w)
     ctx = _tiny_ctx()
-    lin = ctx.linear("w", packed, scale)
+    lin = apc.APLinear.from_packed(packed, scale)
     x = rng.integers(-7, 8, (t, k)).astype(np.float32)
     x[0, 0] = 7.0                          # pin the grid scale to exactly 1
     y = lin(jnp.asarray(x), ctx)
