@@ -22,12 +22,7 @@ structural HBM-traffic/bytes arithmetic for the TPU roofline story).
    makespan vs sequential wall-cycle sum ("ap_runtime" trajectory).
    n_devices > 1 rows appear when the process sees multiple devices
    (XLA_FLAGS=--xla_force_host_platform_device_count=4).
-7. ap kernel: the program-kernel formulation matrix ("ap_kernel"
-   trajectory) — gather (seed baseline, pallas interpret) vs the compiled
-   one-hot and one-hot+VLIW-packed bodies (interpret=False), across
-   program classes and row counts, with the list scheduler's trip-count /
-   group-width statistics per program.
-8. ap sparse: sparsity-compressed MAC programs + the weight-stationary
+7. ap sparse: sparsity-compressed MAC programs + the weight-stationary
    resident bank ("ap_sparse" trajectory) — schedule cycles and wall
    clock vs weight zero-fraction (0.0 -> 0.9), streaming vs resident
    dataflow, with the host-side row-encode cost of each.
@@ -159,71 +154,6 @@ def _encode_named(fn: str, radix: int, width: int, rows: int, rng):
     extra = 0 if fn in ("min", "max", "modsum", "nor", "nand") else 1
     return jnp.asarray(ap.encode_operands(a, b, radix, width,
                                           extra_cols=extra))
-
-
-def bench_ap_kernel(programs=(("add", 3, 20), ("mul", 3, 5), ("max", 3, 8)),
-                    rows_list=(4096, 65536), n_timing: int = 3,
-                    collect_stats: bool = True) -> list[dict]:
-    """Program-kernel formulation matrix: gather vs one-hot vs one-hot+packed
-    ("ap_kernel" trajectory).
-
-    The BASELINE column (``gather_interp_us``) is the seed default — the
-    dynamic-gather body under the pallas interpreter; the other columns run
-    the compiled paths (``interpret=False``: jitted XLA on this host,
-    Mosaic on TPU).  Two structural columns tell the packing story
-    (``packed_groups``/``pack``: the VLIW trip count and group width the
-    list scheduler reached — carry-ripple programs are critical-path-bound
-    near 1x, digitwise programs pack ~width x).  Digits are asserted
-    bit-equal across every variant each run.  On CPU hosts the gather body
-    stays fastest (its per-step work is O(rows x C) vs the one-hot body's
-    O(rows x n_cols) — the host has cheap gathers and no lane hazard), so
-    expect speedup_* < 1 here; the one-hot columns are the TPU-native
-    formulation the ROADMAP asked to benchmark, measured honestly on
-    whatever backend runs the bench.
-    """
-    results = []
-    for fn, radix, width in programs:
-        compiled = apc.compile_named(fn, radix, width)
-        packed = compiled.packed()
-        for rows in rows_list:
-            rng = np.random.default_rng(rows + width)
-            arr = _encode_named(fn, radix, width, rows, rng)
-            row = {"bench": "ap_kernel", "op": fn, "radix": radix,
-                   "width": width, "rows": rows,
-                   "n_steps": compiled.n_steps,
-                   "packed_groups": packed.n_groups, "pack": packed.pack,
-                   "pack_efficiency": round(packed.efficiency, 3),
-                   "collect_stats": collect_stats}
-            outs = {}
-            for label, kv, interp in (
-                    ("gather_interp_us", "gather", True),
-                    ("gather_us", "gather", False),
-                    ("onehot_us", "onehot", False),
-                    ("onehot_packed_us", "onehot_packed", False)):
-                f = lambda: apc.execute(arr, compiled,
-                                        collect_stats=collect_stats,
-                                        kernel_variant=kv, interpret=interp)
-                # the compile warm-up run doubles as the parity capture —
-                # the interp baseline at the big shapes costs minutes, so
-                # never run a cell more than 1 + n_timing times
-                outs[label] = np.asarray(jax.block_until_ready(f()[0]))
-                t0 = time.perf_counter()
-                for _ in range(n_timing):
-                    jax.block_until_ready(f()[0])
-                row[label] = round((time.perf_counter() - t0)
-                                   / n_timing * 1e6)
-            base = outs["gather_interp_us"]
-            assert all(np.array_equal(o, base) for o in outs.values())
-            for label in ("gather_us", "onehot_us", "onehot_packed_us"):
-                row[f"speedup_{label[:-3]}_x"] = round(
-                    row["gather_interp_us"] / max(1, row[label]), 2)
-            results.append(row)
-            print(f"ap_kernel_{fn}{radix}x{width}_{rows},"
-                  f"{row['onehot_packed_us']},"
-                  f"interp_base={row['gather_interp_us']}us_"
-                  f"groups={row['packed_groups']}/{row['n_steps']}"
-                  f"_pack={row['pack']}")
-    return results
 
 
 def bench_ap_matmul(mk_list=((4, 16), (16, 16), (16, 64)), n: int = 8,
@@ -538,7 +468,7 @@ def bench_ap_sparse(m: int = 4, k: int = 40, n: int = 4, radix: int = 3,
 def bench_trace_overhead(fn: str = "add", radix: int = 3, width: int = 20,
                          rows: int = 16384, n_timing: int = 5,
                          json_path: str | None = None) -> dict:
-    """Telemetry cost on the ap_kernel workload ("trace_overhead" row).
+    """Telemetry cost on one compiled 20-trit add ("trace_overhead" row).
 
     Times the same compiled-program replay three ways: spans hard-off
     (``trace.disabled()`` — the REPRO_AP_TRACE=0 production path), spans
@@ -620,7 +550,6 @@ def main():
     # persist after each stage: the interpreted-replay baseline takes
     # minutes, so a later-stage failure must not discard it
     apc_rows = bench_apc(rows_list=rows, json_path=args.json)
-    kernel_rows = bench_ap_kernel()
     matmul_rows = bench_ap_matmul()
     pool_rows = bench_ap_pool()
     n_dev = len(jax.devices())
@@ -630,7 +559,7 @@ def main():
     trace_row = bench_trace_overhead()
     with open(args.json, "w") as f:
         json.dump({"bench": "apc_vs_replay", "results": apc_rows,
-                   "ap_kernel": kernel_rows, "ap_matmul": matmul_rows,
+                   "ap_matmul": matmul_rows,
                    "ap_pool": pool_rows, "ap_runtime": runtime_rows,
                    "ap_sparse": sparse_rows,
                    "trace_overhead": trace_row}, f, indent=2)

@@ -5,11 +5,11 @@ recorded by :mod:`kernels_bench` / :mod:`serve_bench` the day a feature
 landed.  This sentinel keeps that story honest two ways:
 
 **Structural re-derivation** (``--smoke``, the CI gate): every recorded
-column that is *schedule-static* — compile trip counts, VLIW pack widths,
-cycle totals, occupancy-model makespans, admission schema — is recomputed
-from the CURRENT code (compile the program again, price the graph again)
-and compared to the recorded value exactly.  A code change that silently
-alters cycle counts, packing, pruning, or the occupancy model trips the
+column that is *schedule-static* — step counts, cycle totals,
+occupancy-model makespans, admission schema — is recomputed from the
+CURRENT code (compile the program again, price the graph again) and
+compared to the recorded value exactly.  A code change that silently
+alters cycle counts, pruning, or the occupancy model trips the
 sentinel without running a single benchmark.  Wall-clock columns are only
 sanity-checked (positive, p50 <= p99) because the recording host is not
 this host.
@@ -57,13 +57,6 @@ TRAJECTORIES = {
         "key": ("op", "radix", "rows", "width"),
         "timing": ("replay_stats_us", "apc_stats_us", "apc_us"),
         "exact": (),
-        "rel": 3.0,
-    },
-    "ap_kernel": {
-        "key": ("op", "radix", "width", "rows"),
-        "timing": ("gather_interp_us", "gather_us", "onehot_us",
-                   "onehot_packed_us"),
-        "exact": ("n_steps", "packed_groups", "pack"),
         "rel": 3.0,
     },
     "ap_matmul": {
@@ -121,22 +114,6 @@ def _mac_setup(radix: int, k: int, k_tile: int, max_abs: int = 3):
 # ---------------------------------------------------------------------------
 # Structural re-derivation per trajectory
 # ---------------------------------------------------------------------------
-
-def check_ap_kernel(rows: list[dict]) -> list[str]:
-    problems = []
-    for r in rows:
-        compiled = apc.compile_named(r["op"], r["radix"], r["width"])
-        packed = compiled.packed()
-        got = {"n_steps": compiled.n_steps,
-               "packed_groups": packed.n_groups, "pack": packed.pack,
-               "pack_efficiency": round(packed.efficiency, 3)}
-        for col, val in got.items():
-            if r.get(col) != val:
-                problems.append(
-                    f"ap_kernel {r['op']}r{r['radix']}w{r['width']}: "
-                    f"{col} recorded {r.get(col)} != derived {val}")
-    return problems
-
 
 def check_ap_matmul(rows: list[dict]) -> list[str]:
     from repro.kernels.ternary_matmul.ap import ap_matmul_cycle_counts
@@ -338,7 +315,6 @@ def check_results(rows: list[dict]) -> list[str]:
 
 STRUCTURAL_CHECKS = {
     "results": check_results,
-    "ap_kernel": check_ap_kernel,
     "ap_matmul": check_ap_matmul,
     "ap_pool": check_ap_pool,
     "ap_runtime": check_ap_runtime,

@@ -22,10 +22,10 @@ a mesh of four chips and on a one-device ``ArrayPool``: digits, decoded
 accumulators and ``APStats`` must be identical, and the work must land on
 all four devices.
 
-The kernel variant and interpret mode are read from the resolvers and
-printed, never set.  The last line of stdout is one JSON object
-``{"ok": true, "device": {...}}``; a failed check, or a JAX backend that is
-not a TPU, exits non-zero before printing it.
+The interpret mode is read from its resolver and printed, never set.
+The last line of stdout is one JSON object ``{"ok": true, "device":
+{...}}``; a failed check, or a JAX backend that is not a TPU, exits
+non-zero before printing it.
 """
 from __future__ import annotations
 
@@ -92,15 +92,6 @@ def serve_config():
                                    ternary=TernaryCfg(enabled=True))
 
 
-def resolved_kernel(compiled) -> tuple[str, int, bool, int]:
-    """(variant, pack, interpret, fori trips per launch) the resolvers
-    pick for ``compiled``."""
-    from repro.apc.lower import resolve_schedule
-    from repro.kernels.tap_pass.kernel import resolve_interpret
-    tensors, variant, pack, _ = resolve_schedule(compiled)
-    return variant, pack, resolve_interpret(None), len(tensors[0]) // pack
-
-
 def projection_programs(K: int, max_cols: int, x_levels: int):
     """The TiledMac an ``APLinear`` compiles for a K-wide projection."""
     from repro.apc.mac import compile_mac_tiled, mac_acc_width
@@ -111,20 +102,20 @@ def projection_programs(K: int, max_cols: int, x_levels: int):
 
 
 def describe_kernels(cfg, pool, x_levels: int) -> None:
-    """Print the resolved kernel and fori-loop trip counts of every program
-    the served MLP runs, and check none takes the gather body."""
+    """Print the schedule lengths of every program the served MLP runs,
+    and check the program kernel runs compiled."""
+    from repro.kernels.tap_pass.kernel import resolve_interpret
+    interp = resolve_interpret(None)
     for name, K in (("gate/up", cfg.d_model), ("down", cfg.d_ff)):
         tiled = projection_programs(K, pool.cols, x_levels)
-        kinds = [resolved_kernel(p)
-                 for p in tiled.programs + tiled.reduce_programs]
+        steps = {p.n_steps for p in tiled.programs + tiled.reduce_programs}
         print(f"  {name}: K={K} width={tiled.width} k_tile={tiled.k_tile} "
               f"tiles={len(tiled.tiles)} reduce={tiled.reduce_groups} "
               f"compare_cycles={tiled.n_compare_cycles} "
               f"write_cycles={tiled.n_write_cycles}", flush=True)
-        print(f"    (variant, pack, interpret, fori trips per launch): "
-              f"{sorted(set(kinds))}", flush=True)
-        check(all(v == "lanes" and not interp for v, _, interp, _ in kinds),
-              f"{name} programs resolve to the compiled lanes body")
+        print(f"    interpret={interp}, schedule steps per launch: "
+              f"{sorted(steps)}", flush=True)
+    check(not interp, "the program kernel runs compiled")
 
 
 def phase_serve(*, rows: int = 4096, cols: int = 256, seed: int = 0) -> None:
@@ -229,13 +220,12 @@ def phase_program(*, rows: int = 1000, width: int = 8, seed: int = 2
     from repro import apc
     from repro.core import ap, build_lut_nonblocked
     from repro.core import truth_tables as tt
+    from repro.kernels.tap_pass.kernel import resolve_interpret
     compiled = apc.compile_named("add", 3, width)
-    variant, pack, interp, trips = resolved_kernel(compiled)
+    interp = resolve_interpret(None)
     print(f"program: add r3 w{width}, {compiled.n_steps} steps, {rows} rows; "
-          f"kernel variant={variant} pack={pack} interpret={interp}, "
-          f"{trips} fori trips", flush=True)
-    check(variant == "lanes" and not interp,
-          "the program resolves to the compiled lanes body")
+          f"interpret={interp}", flush=True)
+    check(not interp, "the program runs the compiled kernel")
     rng = np.random.default_rng(seed)
     a = rng.integers(0, 3 ** width, rows)
     b = rng.integers(0, 3 ** width, rows)
@@ -289,18 +279,17 @@ def phase_four_chips(devices, *, rows: int = 4096, cols: int = 256,
     # shard_map scaffolding, each device's output shard checked against the
     # one-device kernel on the rows it holds
     from repro.apc.exec import sharded_program_run
-    from repro.apc.lower import resolve_schedule
     prog = projection_programs(K, cols, 7).programs[0]
     n_rows = T * N
     rng = np.random.default_rng(seed)
     arr = rng.integers(0, 3, (4 * rows, prog.min_cols)).astype(np.int8)
     arr[n_rows:] = -1
-    tensors, variant, pack, _ = resolve_schedule(prog)
     with mesh:
         out, _ = sharded_program_run(
-            jax.numpy.asarray(arr), tuple(map(jax.numpy.asarray, tensors)),
+            jax.numpy.asarray(arr),
+            tuple(map(jax.numpy.asarray, prog.schedule_tensors)),
             mesh, dpool.axes, n_rows, rows, collect_stats=False,
-            interpret=None, variant=variant, pack=pack)
+            interpret=None)
     with jax.default_device(devices[0]):
         one, _ = apc.execute(jax.numpy.asarray(arr[:n_rows]), prog)
     one = np.asarray(one)

@@ -99,11 +99,9 @@ from .layers import (APExpertStack, APLinear, APServeContext, APSink,
 from .runtime import DevicePool, GraphResult, Runtime
 from .ir import (AffineCol, ApplyLUT, CompareWrite, ForDigit, Program,
                  RelCol, SetCol, ZeroCol, digit)
-from .lower import (KERNEL_VARIANTS, CompiledProgram, PackedProgram, Step,
-                    compile_named, compile_program, default_kernel_variant,
+from .lower import (CompiledProgram, Step, compile_named, compile_program,
                     elementwise_program, lower as lower_program,
-                    multiply_program, negate_program, pack_steps,
-                    resolve_schedule, ripple_add_program,
+                    multiply_program, negate_program, ripple_add_program,
                     ripple_sub_program)
 from .mac import (SUPPORT_DENSE, TiledMac, assemble_mac_rows_jnp,
                   compile_mac, compile_mac_reduce, compile_mac_tiled,
@@ -143,11 +141,9 @@ __all__ = [
     "DevicePool", "GraphResult", "Runtime",
     "AffineCol", "ApplyLUT", "CompareWrite", "ForDigit", "Program", "RelCol",
     "SetCol", "ZeroCol", "digit",
-    "KERNEL_VARIANTS", "CompiledProgram", "PackedProgram", "Step",
-    "compile_named", "compile_program", "default_kernel_variant",
+    "CompiledProgram", "Step", "compile_named", "compile_program",
     "elementwise_program", "lower_program", "multiply_program",
-    "negate_program", "pack_steps", "resolve_schedule",
-    "ripple_add_program", "ripple_sub_program",
+    "negate_program", "ripple_add_program", "ripple_sub_program",
     "SUPPORT_DENSE", "TiledMac", "assemble_mac_rows_jnp", "compile_mac",
     "compile_mac_reduce", "compile_mac_tiled",
     "decode_mac_acc", "decode_mac_acc_jnp", "decode_signed_digits_jnp",

@@ -1,4 +1,4 @@
-"""Lowering: microcode IR -> one static flat Step schedule (+ packing).
+"""Lowering: microcode IR -> one static flat Step schedule -> dense tensors.
 
 ``lower`` unrolls :class:`~repro.apc.ir.ForDigit` loops, resolves affine
 column expressions, and flattens every op into :class:`Step`s — the same
@@ -7,18 +7,11 @@ replays, plus an ``in_hist`` flag so the traced stats reproduce the
 functional simulator's counters exactly (repair compares are charged as
 cycles but not histogrammed).
 
-``pack`` turns a Step schedule into dense int tensors (keys / columns padded
-to the schedule-wide maxima) so the executor can ``lax.fori_loop`` over steps
-instead of unrolling hundreds of passes into the trace.
-
-``pack_steps`` / :class:`PackedProgram` add a second, VLIW-style packing on
-top: dependence-aware list scheduling groups mutually independent steps
-(disjoint compare/write column interactions) into wide slots replayed in one
-fori_loop trip — digitwise programs pack ~width x, carry-ripple chains stay
-serial (the dependence critical path is real).  :func:`resolve_schedule`
-maps an executor-level ``kernel_variant`` (gather / onehot / onehot_packed)
-onto schedule tensors + kernel statics, falling back whenever a program's
-steps violate a formulation's preconditions.
+:class:`CompiledProgram` packs a Step schedule into dense int tensors (keys
+/ columns padded to the schedule-wide maxima), which the program kernel
+fori-loops over instead of unrolling hundreds of passes into the trace;
+executors hand :attr:`CompiledProgram.schedule_tensors` to
+:func:`~repro.kernels.tap_pass.kernel.tap_run_program` as they are.
 
 ``compile_program`` caches (lower + pack) per program identity;
 ``compile_named`` caches whole (fn, radix, width) programs — e.g. the 20-trit
@@ -29,7 +22,6 @@ here (and in :mod:`repro.apc.mac` / the LUT builders) is size-bounded;
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 
 import numpy as np
@@ -138,8 +130,6 @@ class CompiledProgram:
         self.wr_cols = np.full((S, W), -1, np.int32)
         self.wr_vals = np.zeros((S, W), np.int8)
         cols_seen = 0
-        self.writes_distinct = True
-        self.compares_distinct = True
         for s, st in enumerate(steps):
             nc = len(st.compare_cols)
             self.cmp_cols[s, :nc] = st.compare_cols
@@ -150,22 +140,11 @@ class CompiledProgram:
             nw = len(st.write_cols)
             self.wr_cols[s, :nw] = st.write_cols
             self.wr_vals[s, :nw] = st.write_vals
-            if len(set(st.write_cols)) != nw:
-                # duplicate write columns in one step apply serially (last
-                # value wins, every change charged) — only the gather body
-                # reproduces that; the one-hot blend needs distinct columns
-                self.writes_distinct = False
-            if len(set(st.compare_cols)) != nc:
-                # duplicate compare columns count one mismatch per position;
-                # the one-hot plane holds one key value per column, so only
-                # the gather body reproduces the per-position histogram
-                self.compares_distinct = False
             cols_seen = max(cols_seen, *(c + 1 for c in st.compare_cols),
                             *(c + 1 for c in st.write_cols), 1)
         self.min_cols = max(min_cols, cols_seen)
         self.n_compare_cycles = int(self.key_valid.sum())
         self.n_write_cycles = S
-        self._packed: dict[int, "PackedProgram"] = {}
 
     @property
     def n_steps(self) -> int:
@@ -177,192 +156,10 @@ class CompiledProgram:
         return (self.cmp_cols, self.keys, self.key_valid, self.hist_flag,
                 self.wr_cols, self.wr_vals)
 
-    def packed(self, max_pack: int | None = None) -> "PackedProgram":
-        """The VLIW-packed schedule (cached per program per pack cap)."""
-        mp = DEFAULT_MAX_PACK if max_pack is None else max_pack
-        hit = self._packed.get(mp)
-        if hit is None:
-            while len(self._packed) >= 8:             # FIFO-bound the memo
-                self._packed.pop(next(iter(self._packed)))
-            hit = self._packed.setdefault(mp, PackedProgram(self, mp))
-        return hit
-
     def as_tap_steps(self):
-        """Legacy 4-tuple form for kernels.tap_pass.{ref,kernel} oracles."""
+        """The 4-tuple step form of the :mod:`kernels.tap_pass.ref` oracle."""
         return tuple((s.keys, s.compare_cols, s.write_cols, s.write_vals)
                      for s in self.steps)
-
-
-# ---------------------------------------------------------------------------
-# VLIW step packing: dependence-aware list scheduling of the flat schedule
-# ---------------------------------------------------------------------------
-
-DEFAULT_MAX_PACK = 8         # slots per packed group (kernel unrolls them)
-
-KERNEL_VARIANTS = ("gather", "onehot", "onehot_packed", "lanes")
-
-
-def default_kernel_variant() -> str:
-    """What the executors run when no ``kernel_variant`` is requested.
-
-    On TPU the ``lanes`` body: digits column-major with rows on sublanes x
-    lanes, so a step loads and stores only the columns it names, at a
-    dynamic leading-axis offset Mosaic compiles.  On CPU/GPU hosts the
-    gather body stays the measured-fastest simulator path: its per-step
-    work is O(rows x C) against the one-hot body's O(rows x n_cols), and
-    XLA lowers host-side gathers cheaply (bench_ap_kernel records the
-    matrix).  ``REPRO_AP_KERNEL_VARIANT`` overrides — CI uses it to run the
-    kernel shard through the compiled one-hot path.  All variants are
-    bit-identical (tests/test_pack.py).
-    """
-    import jax                          # local: keep lowering importable
-    env = os.environ.get("REPRO_AP_KERNEL_VARIANT")
-    if env:
-        return env
-    return "lanes" if jax.default_backend() == "tpu" else "gather"
-
-
-def pack_steps(steps: tuple[Step, ...], max_pack: int = DEFAULT_MAX_PACK
-               ) -> list[list[int]]:
-    """Greedy list scheduling of steps into VLIW groups of independent slots.
-
-    A step conflicts with an earlier step when it reads a column the earlier
-    one writes (RAW), writes a column the earlier one writes (WAW), or
-    writes a column the earlier one compares (WAR) — conflicting steps land
-    in strictly ordered groups, so replaying groups in order with all of a
-    group's compares taken against the pre-group array (then all its writes
-    landed at once) is step-for-step equivalent to the flat schedule,
-    counters included.  Steps with no conflict pack into the earliest group
-    with a free slot, which is what shrinks serial tails like the multiply
-    repair sweeps (independent per digit) to ``ceil(n / max_pack)`` groups.
-    """
-    if max_pack < 1:
-        raise ValueError(f"max_pack must be >= 1, got {max_pack}")
-    groups: list[list[int]] = []
-    last_write: dict[int, int] = {}       # col -> newest group writing it
-    last_cmp: dict[int, int] = {}         # col -> newest group comparing it
-    for idx, st in enumerate(steps):
-        g0 = 0
-        for c in st.compare_cols:
-            g0 = max(g0, last_write.get(c, -1) + 1)             # RAW
-        for c in st.write_cols:
-            g0 = max(g0, last_write.get(c, -1) + 1,             # WAW
-                     last_cmp.get(c, -1) + 1)                   # WAR
-        g = g0
-        while g < len(groups) and len(groups[g]) >= max_pack:
-            g += 1
-        if g == len(groups):
-            groups.append([])
-        groups[g].append(idx)
-        for c in st.compare_cols:
-            last_cmp[c] = max(last_cmp.get(c, -1), g)
-        for c in st.write_cols:
-            last_write[c] = max(last_write.get(c, -1), g)
-    return groups
-
-
-class PackedProgram:
-    """A :class:`CompiledProgram` schedule list-scheduled into VLIW groups.
-
-    Same dense tensor layout, but group-major: slot ``g * pack + p`` is slot
-    ``p`` of group ``g`` (``pack`` = widest group), padded with no-op slots
-    (all write columns -1, no valid keys, hist_flag off) that write and
-    count nothing.  Cycle accounting stays on the source program — packing
-    is a kernel wall-clock optimization, the modeled hardware still charges
-    one write cycle per original step.
-    """
-
-    def __init__(self, compiled: CompiledProgram,
-                 max_pack: int = DEFAULT_MAX_PACK):
-        if not compiled.writes_distinct:
-            raise ValueError(
-                "cannot pack a program with duplicate write columns in one "
-                "step (serial write semantics); run the gather kernel")
-        self.compiled = compiled
-        self.max_pack = max_pack
-        groups = pack_steps(compiled.steps, max_pack)
-        self.n_groups = len(groups)
-        self.pack = max(len(g) for g in groups)
-        S, C = compiled.cmp_cols.shape
-        K = compiled.keys.shape[1]
-        W = compiled.wr_cols.shape[1]
-        n_slots = self.n_groups * self.pack
-        self.cmp_cols = np.full((n_slots, C), -1, np.int32)
-        self.keys = np.zeros((n_slots, K, C), np.int8)
-        self.key_valid = np.zeros((n_slots, K), bool)
-        self.hist_flag = np.zeros((n_slots,), bool)
-        self.wr_cols = np.full((n_slots, W), -1, np.int32)
-        self.wr_vals = np.zeros((n_slots, W), np.int8)
-        perm = []                     # flat step index per occupied slot
-        slots = []
-        for g, members in enumerate(groups):
-            for p, idx in enumerate(members):
-                perm.append(idx)
-                slots.append(g * self.pack + p)
-        perm = np.asarray(perm, np.int64)
-        slots = np.asarray(slots, np.int64)
-        self.cmp_cols[slots] = compiled.cmp_cols[perm]
-        self.keys[slots] = compiled.keys[perm]
-        self.key_valid[slots] = compiled.key_valid[perm]
-        self.hist_flag[slots] = compiled.hist_flag[perm]
-        self.wr_cols[slots] = compiled.wr_cols[perm]
-        self.wr_vals[slots] = compiled.wr_vals[perm]
-
-    @property
-    def n_slots(self) -> int:
-        return self.n_groups * self.pack
-
-    @property
-    def efficiency(self) -> float:
-        """Occupied fraction of the padded slot grid."""
-        return self.compiled.n_steps / max(1, self.n_slots)
-
-    @property
-    def schedule_tensors(self) -> tuple[np.ndarray, ...]:
-        return (self.cmp_cols, self.keys, self.key_valid, self.hist_flag,
-                self.wr_cols, self.wr_vals)
-
-
-def resolve_schedule(compiled: CompiledProgram,
-                     kernel_variant: str | None = None,
-                     max_pack: int | None = None):
-    """Map an executor-level ``kernel_variant`` to kernel arguments.
-
-    Returns ``(schedule_tensors, variant, pack, resolved_name)`` — the
-    tensors to feed :func:`~repro.kernels.tap_pass.kernel.tap_run_program`
-    plus its ``variant``/``pack`` statics.  ``None`` resolves to
-    :func:`default_kernel_variant`.  ``lanes`` runs the flat schedule:
-    it replays slots one by one, so packing would only add no-op slots.
-    The one-hot variants need distinct write and compare columns in a
-    step; a program without them falls back to the gather body (serial
-    same-column writes, per-position mismatch counting) off TPU, and
-    raises on TPU, where that body has no compiled lowering (``lanes``
-    replays such steps as the gather body does, and compiles).
-    ``onehot_packed`` additionally falls back to the flat one-hot schedule
-    when list scheduling found nothing to pack, or when group-width padding
-    would inflate the slot grid faster than the trip count shrinks
-    (carry-ripple chains pin most slots to 1-wide groups).
-    """
-    kv = default_kernel_variant() if kernel_variant is None else kernel_variant
-    if kv not in KERNEL_VARIANTS:
-        raise ValueError(
-            f"kernel_variant must be one of {KERNEL_VARIANTS}, got {kv!r}")
-    if kv in ("gather", "lanes"):
-        return compiled.schedule_tensors, kv, 1, kv
-    if not (compiled.writes_distinct and compiled.compares_distinct):
-        import jax                      # local: keep lowering importable
-        if jax.default_backend() == "tpu":
-            raise ValueError(
-                "program has duplicate write or compare columns in one "
-                "step; the one-hot bodies cannot replay those, and the "
-                "gather body does not compile under Mosaic: run 'lanes'")
-        return compiled.schedule_tensors, "gather", 1, "gather"
-    if kv == "onehot_packed":
-        p = compiled.packed(max_pack)
-        if p.pack > 1 and p.n_slots <= 1.25 * compiled.n_steps:
-            return p.schedule_tensors, "onehot", p.pack, kv
-        kv = "onehot"                 # no useful packing: skip padded copy
-    return compiled.schedule_tensors, kv, 1, kv
 
 
 @functools.lru_cache(maxsize=256)

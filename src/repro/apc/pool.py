@@ -17,7 +17,7 @@ program executor:
   ``2 * n_arrays`` launches stay in flight before backpressure (the oldest
   launch is drained first), which is exactly the two-deep per-array buffer
   a hardware sequencer would keep.
-- **One schedule tensor.**  The packed schedule of a
+- **One schedule tensor.**  The dense schedule of a
   :class:`~repro.apc.lower.CompiledProgram` is uploaded once per pool and
   shared by every launch (the AP sequencer's single microcode store), so
   per-block dispatch moves only digit rows.
@@ -53,7 +53,7 @@ from .caches import (ResidentEvicted, ResidentHandle, ResidentStale,
                      ResidentStore)
 from .faults import (FaultConfig, FaultDetected, FaultModel, expected_checksum,
                      fault_config_from_env, faults_enabled, validate_digits)
-from .lower import CompiledProgram, compile_checksum, resolve_schedule
+from .lower import CompiledProgram, compile_checksum
 from .metrics import get_registry
 from .mac import (TiledMac, assemble_mac_rows_jnp, decode_signed_digits_jnp,
                   encode_mac_rows_jnp, encode_mac_x_rows_jnp,
@@ -75,8 +75,7 @@ class ArrayPool:
     """A bank of ``n_arrays`` MvCAM arrays of ``rows`` x ``cols`` digits."""
 
     def __init__(self, n_arrays: int = 4, rows: int = 4096,
-                 cols: int = 256, *, kernel_variant: str | None = None,
-                 interpret: bool | None = None, unroll: int | None = None,
+                 cols: int = 256, *, interpret: bool | None = None,
                  resident_slots: int = 256,
                  faults: FaultConfig | None = None):
         if n_arrays < 1:
@@ -103,18 +102,14 @@ class ArrayPool:
         # into the bank once and reused across calls (bounded, visible in
         # caches.cache_stats)
         self.resident = ResidentStore(maxsize=resident_slots)
-        # pool-level execution knobs: per-call kwargs override, None means
-        # the measured backend default (kernels.tap_pass.kernel)
-        self.kernel_variant = kernel_variant
+        # pool-level execution knob: a per-call interpret= overrides, None
+        # means the backend default (kernels.tap_pass.kernel)
         self.interpret = interpret
-        self.unroll = unroll
-        # one uploaded schedule per (compiled program, resolved variant),
-        # shared by every launch; the CompiledProgram is pinned in the
-        # value so its id (the key) can never be recycled onto a
-        # different program
+        # one uploaded schedule per compiled program, shared by every
+        # launch; the CompiledProgram is pinned in the value so its id (the
+        # key) can never be recycled onto a different program
         self._schedules: dict[
-            tuple[int, str],
-            tuple[CompiledProgram, tuple[jax.Array, ...], str, int]] = {}
+            int, tuple[CompiledProgram, tuple[jax.Array, ...]]] = {}
         self._max_schedules = 64
 
     def __repr__(self) -> str:
@@ -150,29 +145,21 @@ class ArrayPool:
 
     # -- schedule store -----------------------------------------------------
 
-    def _device_schedule(self, compiled: CompiledProgram,
-                         kernel_variant: str | None = None
-                         ) -> tuple[tuple[jax.Array, ...], str, int]:
-        """Device-resident schedule tensors for the resolved kernel variant
-        (uploaded once per (program, variant)); returns
-        ``(sched, variant, pack)`` ready for ``tap_run_program``."""
-        kernel_variant = (self.kernel_variant if kernel_variant is None
-                          else kernel_variant)
-        host, variant, pack, name = resolve_schedule(compiled,
-                                                     kernel_variant)
-        key = (id(compiled), name)
-        hit = self._schedules.get(key)
+    def _device_schedule(self, compiled: CompiledProgram
+                         ) -> tuple[jax.Array, ...]:
+        """Device-resident schedule tensors of ``compiled`` (uploaded once
+        per program), ready for ``tap_run_program``."""
+        hit = self._schedules.get(id(compiled))
         if hit is not None:
             get_registry().counter("pool.schedule_reuse").inc()
-            return hit[1], hit[2], hit[3]
-        sched = tuple(jnp.asarray(t) for t in host)
+            return hit[1]
+        sched = tuple(jnp.asarray(t) for t in compiled.schedule_tensors)
         while len(self._schedules) >= self._max_schedules:   # FIFO evict
             self._schedules.pop(next(iter(self._schedules)))
-        self._schedules[key] = (compiled, sched, variant, pack)
+        self._schedules[id(compiled)] = (compiled, sched)
         get_registry().counter("pool.schedule_uploads").inc()
-        trace.instant("schedule_upload", cat="pool", program=name,
-                      steps=compiled.n_steps, variant=variant)
-        return sched, variant, pack
+        trace.instant("schedule_upload", cat="pool", steps=compiled.n_steps)
+        return sched
 
     # -- bank health --------------------------------------------------------
 
@@ -261,16 +248,15 @@ class ArrayPool:
 
     def run(self, arr: jax.Array, compiled: CompiledProgram, *,
             collect_stats: bool = False, interpret: bool | None = None,
-            kernel_variant: str | None = None, unroll: int | None = None,
             block_valid: tuple[int, ...] | None = None,
             radix: int | None = None
             ) -> tuple[jax.Array, TracedStats | None]:
         """Stream [rows, cols] digit rows through the pool.
 
         Output and (when ``collect_stats``) accumulated APStats are
-        bit-identical to single-array :func:`~repro.apc.exec.execute` for
-        every kernel variant; ``interpret``/``kernel_variant``/``unroll``
-        default to the pool-level knobs, then the backend defaults.
+        bit-identical to single-array :func:`~repro.apc.exec.execute`;
+        ``interpret`` defaults to the pool-level knob, then the backend
+        default.
 
         ``block_valid`` marks a row-concatenated launch (see
         :class:`~repro.apc.graph.GraphNode`): block ``b`` carries
@@ -288,12 +274,10 @@ class ArrayPool:
         if self.fault_model is not None:
             return self._run_faulty(
                 arr, compiled, collect_stats=collect_stats,
-                interpret=interpret, kernel_variant=kernel_variant,
-                unroll=unroll, block_valid=block_valid, radix=radix)
+                interpret=interpret, block_valid=block_valid, radix=radix)
         n_rows, n_cols = arr.shape
         self.validate(compiled, n_cols=n_cols)
         interpret = self.interpret if interpret is None else interpret
-        unroll = self.unroll if unroll is None else unroll
         if block_valid is not None:
             if n_rows == 0 or n_rows % self.rows:
                 raise ValueError(
@@ -314,16 +298,13 @@ class ArrayPool:
         with trace.annotate("ap.pool.run"):
             return self._run_blocks(arr, compiled, n_rows, block_valid,
                                     collect_stats=collect_stats,
-                                    interpret=interpret,
-                                    kernel_variant=kernel_variant,
-                                    unroll=unroll)
+                                    interpret=interpret)
 
     def _run_blocks(self, arr, compiled, n_rows, block_valid, *,
-                    collect_stats, interpret, kernel_variant, unroll):
+                    collect_stats, interpret):
         """The launch loop of :meth:`run`, from schedule upload to the
         concatenated outputs and counters."""
-        sched, variant, pack = self._device_schedule(compiled,
-                                                     kernel_variant)
+        sched = self._device_schedule(compiled)
         arr = jnp.asarray(arr, jnp.int8)
         in_flight: list[tuple[jax.Array, jax.Array | None, int]] = []
         outs: list[jax.Array] = []
@@ -349,7 +330,7 @@ class ArrayPool:
             run_span = tr.span(
                 "pool.run", cat="pool", rows=n_rows, blocks=n_blocks,
                 n_arrays=self.n_arrays, steps=compiled.n_steps,
-                variant=variant, predicted_waves=wall["waves"],
+                predicted_waves=wall["waves"],
                 predicted_compare_cycles=wall["compare_cycles"],
                 predicted_write_cycles=wall["write_cycles"],
                 predicted_ns=wall["waves"] * program_ns).__enter__()
@@ -387,8 +368,7 @@ class ArrayPool:
                     out, raw = tap_run_program(
                         padded, *sched, jnp.int32(valid),
                         block_rows=self.rows, collect_stats=collect_stats,
-                        hist_bins=HIST_BINS, interpret=interpret,
-                        unroll=unroll, variant=variant, pack=pack)
+                        hist_bins=HIST_BINS, interpret=interpret)
                 in_flight.append((out, raw, valid))
                 # bound in-flight launches to 2 per array
                 if len(in_flight) >= 2 * self.n_arrays:
@@ -416,7 +396,7 @@ class ArrayPool:
     # -- faulty execution ---------------------------------------------------
 
     def _run_faulty(self, arr, compiled, *, collect_stats, interpret,
-                    kernel_variant, unroll, block_valid, radix):
+                    block_valid, radix):
         """:meth:`run` over a bank with an installed fault model.
 
         Synchronous per-block execution (recovery needs the stored digits
@@ -435,7 +415,6 @@ class ArrayPool:
         n_rows, n_cols = arr.shape
         self.validate(compiled, n_cols=n_cols)
         interpret = self.interpret if interpret is None else interpret
-        unroll = self.unroll if unroll is None else unroll
         if block_valid is not None:
             if n_rows == 0 or n_rows % self.rows:
                 raise ValueError(
@@ -453,14 +432,13 @@ class ArrayPool:
             empty = jnp.zeros((1, 2 + HIST_BINS), jnp.int32)
             return (jnp.asarray(arr, jnp.int8),
                     TracedStats(empty) if collect_stats else None)
-        sched, variant, pack = self._device_schedule(compiled,
-                                                     kernel_variant)
+        sched = self._device_schedule(compiled)
         arr = jnp.asarray(arr, jnp.int8)
         reg = get_registry()
         n_blocks = self.n_blocks(n_rows)
         outs, counts = [], []
         with trace.span("pool.run_faulty", cat="pool", prof="ap.pool.run",
-                        rows=n_rows, blocks=n_blocks, variant=variant):
+                        rows=n_rows, blocks=n_blocks):
             for b in range(n_blocks):
                 with trace.annotate("ap.pool.launch"):
                     lo = b * self.rows
@@ -471,8 +449,7 @@ class ArrayPool:
                     out, raw = tap_run_program(
                         padded, *sched, jnp.int32(valid),
                         block_rows=self.rows, collect_stats=collect_stats,
-                        hist_bins=HIST_BINS, interpret=interpret,
-                        unroll=unroll, variant=variant, pack=pack)
+                        hist_bins=HIST_BINS, interpret=interpret)
                 with trace.annotate("ap.pool.drain"):
                     true_np = np.asarray(out)   # write driver's intent
                 healthy = self.healthy_arrays()
@@ -496,8 +473,7 @@ class ArrayPool:
                                     attempt=attempt)
                     cand = fm.corrupt(true_np, a, r)
                     bad = self._verify_block(cand, true_np, valid, r,
-                                             interpret=interpret,
-                                             unroll=unroll)
+                                             interpret=interpret)
                     if bad is None:
                         stored = cand
                         break
@@ -525,8 +501,7 @@ class ArrayPool:
             traced = TracedStats(jnp.concatenate(counts, axis=0))
         return out, traced
 
-    def _verify_block(self, stored, true_np, valid, radix, *,
-                      interpret, unroll):
+    def _verify_block(self, stored, true_np, valid, radix, *, interpret):
         """Detection: digit-range validation + mod-r checksum verify of a
         stored block against the write driver's intent.  Returns None when
         clean, else the failing row indices.
@@ -548,12 +523,11 @@ class ArrayPool:
             cs_prog = compile_checksum(n_cols, radix)
             cs_in = np.concatenate(
                 [stored, np.zeros((stored.shape[0], 1), np.int8)], axis=1)
-            sched, variant, pack = self._device_schedule(cs_prog)
             out, raw = tap_run_program(
-                jnp.asarray(cs_in, jnp.int8), *sched, jnp.int32(valid),
+                jnp.asarray(cs_in, jnp.int8),
+                *self._device_schedule(cs_prog), jnp.int32(valid),
                 block_rows=self.rows, collect_stats=True,
-                hist_bins=HIST_BINS, interpret=interpret, unroll=unroll,
-                variant=variant, pack=pack)
+                hist_bins=HIST_BINS, interpret=interpret)
             got = np.asarray(out)[:valid, n_cols].astype(np.int64)
             self._charge(TracedStats(raw), cs_prog, self.rows,
                          "fault_checksum")
@@ -567,18 +541,14 @@ class ArrayPool:
 
 def run_pooled(arr: jax.Array, compiled: CompiledProgram, pool: ArrayPool,
                *, stats: APStats | None = None,
-               interpret: bool | None = None,
-               kernel_variant: str | None = None,
-               unroll: int | None = None) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """Driver-style front door: pool.run + optional APStats accumulate
     (mirrors :func:`repro.apc.exec.run` for the single-array path).
     ``pool.run`` validates the column budget before any schedule upload."""
     with trace.span("run_pooled", cat="pool", rows=arr.shape[0]):
         out, traced = pool.run(arr, compiled,
                                collect_stats=stats is not None,
-                               interpret=interpret,
-                               kernel_variant=kernel_variant,
-                               unroll=unroll)
+                               interpret=interpret)
         if stats is not None:
             accumulate(stats, traced, compiled, n_rows=arr.shape[0])
         drain_fault_charges(pool, stats)
@@ -603,8 +573,6 @@ def run_mac_tiled(x: jax.Array, w_ter: jax.Array, tiled: TiledMac, *,
                   stats: APStats | None = None,
                   block_rows: int | None = None,
                   interpret: bool | None = None,
-                  kernel_variant: str | None = None,
-                  unroll: int | None = None,
                   resident: ResidentHandle | None = None) -> jax.Array:
     """ACC = sum_k w_k * x_k through the K-tiled programs, over a pool.
 
@@ -675,9 +643,7 @@ def run_mac_tiled(x: jax.Array, w_ter: jax.Array, tiled: TiledMac, *,
         if pool is not None:
             out, traced = pool.run(arr, compiled,
                                    collect_stats=stats is not None,
-                                   interpret=interpret,
-                                   kernel_variant=kernel_variant,
-                                   unroll=unroll, radix=radix)
+                                   interpret=interpret, radix=radix)
             drain_fault_charges(pool, stats)
         else:
             with trace.annotate("ap.pool.run"), \
@@ -685,9 +651,7 @@ def run_mac_tiled(x: jax.Array, w_ter: jax.Array, tiled: TiledMac, *,
                 out, traced = execute(arr, compiled,
                                       collect_stats=stats is not None,
                                       block_rows=block_rows,
-                                      interpret=interpret,
-                                      kernel_variant=kernel_variant,
-                                      unroll=unroll)
+                                      interpret=interpret)
         if stats is not None:
             accumulate(stats, traced, compiled, n_rows=R, label=label)
         return out

@@ -53,12 +53,10 @@ class DevicePool(ArrayPool):
     """
 
     def __init__(self, mesh=None, *, n_arrays: int = 4, rows: int = 4096,
-                 cols: int = 256, kernel_variant: str | None = None,
-                 interpret: bool | None = None, unroll: int | None = None,
+                 cols: int = 256, interpret: bool | None = None,
                  resident_slots: int = 256, faults=None):
         super().__init__(n_arrays=n_arrays, rows=rows, cols=cols,
-                         kernel_variant=kernel_variant, interpret=interpret,
-                         unroll=unroll, resident_slots=resident_slots,
+                         interpret=interpret, resident_slots=resident_slots,
                          faults=faults)
         if mesh is not None and self.fault_model is not None:
             raise NotImplementedError(
@@ -97,7 +95,6 @@ class DevicePool(ArrayPool):
 
     def run(self, arr: jax.Array, compiled: CompiledProgram, *,
             collect_stats: bool = False, interpret: bool | None = None,
-            kernel_variant: str | None = None, unroll: int | None = None,
             block_valid: tuple[int, ...] | None = None,
             radix: int | None = None
             ) -> tuple[jax.Array, TracedStats | None]:
@@ -109,9 +106,8 @@ class DevicePool(ArrayPool):
         """
         if self.mesh is None:
             return super().run(arr, compiled, collect_stats=collect_stats,
-                               interpret=interpret,
-                               kernel_variant=kernel_variant, unroll=unroll,
-                               block_valid=block_valid, radix=radix)
+                               interpret=interpret, block_valid=block_valid,
+                               radix=radix)
         if block_valid is not None:
             raise NotImplementedError(
                 "row-concatenated (block_valid) launches run on the host "
@@ -119,13 +115,11 @@ class DevicePool(ArrayPool):
         n_rows, n_cols = arr.shape
         self.validate(compiled, n_cols=n_cols)
         interpret = self.interpret if interpret is None else interpret
-        unroll = self.unroll if unroll is None else unroll
         if n_rows == 0:
             empty = jnp.zeros((1, 2 + HIST_BINS), jnp.int32)
             return (jnp.asarray(arr, jnp.int8),
                     TracedStats(empty) if collect_stats else None)
-        sched, variant, pack = self._device_schedule(compiled,
-                                                     kernel_variant)
+        sched = self._device_schedule(compiled)
         d = self.n_devices
         # per-device shard: whole blocks of self.rows (kernel grid splits
         # the shard back into per-array blocks); padding rows are masked
@@ -135,14 +129,13 @@ class DevicePool(ArrayPool):
         with trace.annotate("ap.pool.run"):
             with trace.span("devicepool.run", cat="pool",
                             prof="ap.pool.launch", rows=n_rows, n_devices=d,
-                            n_arrays=self.n_arrays, steps=compiled.n_steps,
-                            variant=variant):
+                            n_arrays=self.n_arrays,
+                            steps=compiled.n_steps):
                 padded, _ = _pad_rows(jnp.asarray(arr, jnp.int8),
                                       d * shard_rows)
                 out, raw = sharded_program_run(
                     padded, sched, self.mesh, self.axes, n_rows, self.rows,
-                    collect_stats=collect_stats, interpret=interpret,
-                    variant=variant, pack=pack, unroll=unroll)
+                    collect_stats=collect_stats, interpret=interpret)
             out = out[:n_rows]
         if collect_stats:
             return out, TracedStats(raw)
@@ -182,13 +175,9 @@ class Runtime:
     graph charges exactly what running each program alone would.
     """
 
-    def __init__(self, pool: ArrayPool, *, interpret: bool | None = None,
-                 kernel_variant: str | None = None,
-                 unroll: int | None = None):
+    def __init__(self, pool: ArrayPool, *, interpret: bool | None = None):
         self.pool = pool
         self.interpret = interpret
-        self.kernel_variant = kernel_variant
-        self.unroll = unroll
         self.last_report: dict[str, float] | None = None
 
     def __repr__(self) -> str:
@@ -198,37 +187,25 @@ class Runtime:
     def n_devices(self) -> int:
         return getattr(self.pool, "n_devices", 1)
 
-    def check_knobs(self, *, interpret: bool | None = None,
-                    kernel_variant: str | None = None,
-                    unroll: int | None = None) -> None:
-        """Reject per-call execution knobs the runtime route cannot honor.
+    def check_knobs(self, *, interpret: bool | None = None) -> None:
+        """Reject a per-call ``interpret`` the runtime route cannot honor.
 
-        Graph execution always runs with the knobs configured on the
-        Runtime itself; a caller passing a different explicit value would
-        otherwise be silently ignored — raise instead and point at the
-        constructor.  An explicit value that merely restates what an
-        unconfigured (None) Runtime resolves to anyway is compatible —
-        e.g. ``interpret=True`` against a default Runtime on a CPU host,
-        the pre-knob API's documented default.
+        Graph execution always runs with the Runtime's own ``interpret``; a
+        caller passing a different explicit value would otherwise be
+        silently ignored — raise instead and point at the constructor.  An
+        explicit value that merely restates what an unconfigured (None)
+        Runtime resolves to anyway is compatible — e.g. ``interpret=True``
+        against a default Runtime on a CPU host.
         """
         from ..kernels.tap_pass.kernel import resolve_interpret
-        from .lower import default_kernel_variant
-        checks = (
-            ("interpret", interpret, self.interpret,
-             lambda v: v == resolve_interpret(None)),
-            ("kernel_variant", kernel_variant, self.kernel_variant,
-             lambda v: v == default_kernel_variant()),
-            ("unroll", unroll, self.unroll, lambda v: False),
-        )
-        for name, val, own, matches_default in checks:
-            if val is None or val == own:
-                continue
-            if own is None and matches_default(val):
-                continue
-            raise ValueError(
-                f"{name}={val!r} conflicts with Runtime({name}={own!r}) "
-                f"— the graph route runs with the Runtime's knobs; set "
-                f"it on the Runtime constructor")
+        if interpret is None or interpret == self.interpret:
+            return
+        if self.interpret is None and interpret == resolve_interpret(None):
+            return
+        raise ValueError(
+            f"interpret={interpret!r} conflicts with "
+            f"Runtime(interpret={self.interpret!r}) — the graph route runs "
+            f"with the Runtime's own; set it on the Runtime constructor")
 
     def makespan(self, graph: ProgramGraph,
                  record: list | None = None) -> dict[str, float]:
@@ -321,8 +298,6 @@ class Runtime:
                                     arr, node.compiled,
                                     collect_stats=collect,
                                     interpret=self.interpret,
-                                    kernel_variant=self.kernel_variant,
-                                    unroll=self.unroll,
                                     block_valid=node.block_valid,
                                     radix=graph.radix)
                                 break

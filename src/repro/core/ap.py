@@ -251,9 +251,7 @@ def ripple_add(arr: Array, lut: LUT, width: int, carry_col: int,
                a_base: int = 0, b_base: int | None = None,
                stats: APStats | None = None, zero_carry: bool = True,
                engine: str = "replay", pool=None,
-               interpret: bool | None = None,
-               kernel_variant: str | None = None,
-               unroll: int | None = None) -> Array:
+               interpret: bool | None = None) -> Array:
     """In-place p-digit add: B <- A + B, digit-serial over positions.
 
     ``lut`` is a full-adder schedule over logical columns (A, B, C); the
@@ -264,10 +262,10 @@ def ripple_add(arr: Array, lut: LUT, width: int, carry_col: int,
     ``engine="apc"`` compiles the whole program and runs it fused
     (:mod:`repro.apc`) — identical digits and counters, no per-pass syncs;
     ``pool`` (an :class:`repro.apc.ArrayPool`, apc engine only) streams
-    row blocks over the array bank.  ``interpret`` / ``kernel_variant`` /
-    ``unroll`` (apc engine only, also on the other drivers below) pick the
-    program-kernel formulation (:mod:`repro.apc.exec`); every combination
-    is bit-exact and ``None`` means the measured backend default.
+    row blocks over the array bank.  ``interpret`` (apc engine only, also
+    on the other drivers below) picks how the program kernel runs
+    (:mod:`repro.apc.exec`); both modes are bit-exact and ``None`` means
+    the backend default.
     """
     b_base = width if b_base is None else b_base
     with _tspan("ripple_add", rows=int(arr.shape[0]), width=width,
@@ -276,8 +274,7 @@ def ripple_add(arr: Array, lut: LUT, width: int, carry_col: int,
             from .. import apc
             return apc.run(arr, apc.ripple_add_program(
                 lut, width, carry_col, a_base, b_base, zero_carry),
-                stats=stats, pool=pool, interpret=interpret,
-                kernel_variant=kernel_variant, unroll=unroll)
+                stats=stats, pool=pool, interpret=interpret)
         if zero_carry:
             arr = _zero_col(arr, carry_col, stats)
         for i in range(width):
@@ -289,9 +286,7 @@ def ripple_add(arr: Array, lut: LUT, width: int, carry_col: int,
 def ripple_sub(arr: Array, lut_sub: LUT, width: int, borrow_col: int,
                stats: APStats | None = None, zero_carry: bool = True,
                engine: str = "replay", pool=None,
-               interpret: bool | None = None,
-               kernel_variant: str | None = None,
-               unroll: int | None = None) -> Array:
+               interpret: bool | None = None) -> Array:
     """In-place p-digit subtract: B <- A - B (mod r^p), borrow ripples."""
     with _tspan("ripple_sub", rows=int(arr.shape[0]), width=width,
                 engine=engine):
@@ -299,8 +294,7 @@ def ripple_sub(arr: Array, lut_sub: LUT, width: int, borrow_col: int,
             from .. import apc
             return apc.run(arr, apc.ripple_sub_program(
                 lut_sub, width, borrow_col, zero_carry=zero_carry),
-                stats=stats, pool=pool, interpret=interpret,
-                kernel_variant=kernel_variant, unroll=unroll)
+                stats=stats, pool=pool, interpret=interpret)
         if zero_carry:
             arr = _zero_col(arr, borrow_col, stats)
         for i in range(width):
@@ -311,9 +305,7 @@ def ripple_sub(arr: Array, lut_sub: LUT, width: int, borrow_col: int,
 def negate(arr: Array, lut_not_copy: LUT, lut_half: LUT, width: int,
            b_base: int, r_base: int, carry_col: int,
            stats: APStats | None = None, engine: str = "replay",
-           pool=None, interpret: bool | None = None,
-           kernel_variant: str | None = None,
-           unroll: int | None = None) -> Array:
+           pool=None, interpret: bool | None = None) -> Array:
     """R <- (-B) mod r^p (radix complement).
 
     Digitwise diminished-radix complement of B into R via the 2-column
@@ -327,8 +319,7 @@ def negate(arr: Array, lut_not_copy: LUT, lut_half: LUT, width: int,
             from .. import apc
             return apc.run(arr, apc.negate_program(
                 lut_not_copy, lut_half, width, b_base, r_base, carry_col),
-                stats=stats, pool=pool, interpret=interpret,
-                kernel_variant=kernel_variant, unroll=unroll)
+                stats=stats, pool=pool, interpret=interpret)
         for i in range(width):
             arr = _apply(arr, lut_not_copy, (b_base + i, r_base + i),
                          (), stats)
@@ -341,9 +332,7 @@ def negate(arr: Array, lut_not_copy: LUT, lut_half: LUT, width: int,
 def elementwise(arr: Array, lut2: LUT, width: int, a_base: int = 0,
                 b_base: int | None = None, stats: APStats | None = None,
                 engine: str = "replay", pool=None,
-                interpret: bool | None = None,
-                kernel_variant: str | None = None,
-                unroll: int | None = None) -> Array:
+                interpret: bool | None = None) -> Array:
     """Digitwise 2-input MVL op B_i <- f(A_i, B_i) (min/max/modsum/...)."""
     b_base = width if b_base is None else b_base
     with _tspan("elementwise", rows=int(arr.shape[0]), width=width,
@@ -352,8 +341,7 @@ def elementwise(arr: Array, lut2: LUT, width: int, a_base: int = 0,
             from .. import apc
             return apc.run(arr, apc.elementwise_program(
                 lut2, width, a_base, b_base), stats=stats, pool=pool,
-                interpret=interpret, kernel_variant=kernel_variant,
-                unroll=unroll)
+                interpret=interpret)
         for i in range(width):
             arr = _apply(arr, lut2, (a_base + i, b_base + i), (), stats)
         return arr
@@ -363,9 +351,7 @@ def multiply(arr: Array, lut_add: LUT, lut_half: LUT, width: int, radix: int,
              a_base: int, acopy_base: int, b_base: int, r_base: int,
              carry_col: int, stats: APStats | None = None,
              engine: str = "replay", pool=None,
-             interpret: bool | None = None,
-             kernel_variant: str | None = None,
-             unroll: int | None = None) -> Array:
+             interpret: bool | None = None) -> Array:
     """R <- A * B by AP shift-and-add (the §IV LUT methodology, extended).
 
     For each multiplier digit B_j and each weight t in {1..r-1}, run t
@@ -390,8 +376,7 @@ def multiply(arr: Array, lut_add: LUT, lut_half: LUT, width: int, radix: int,
             return apc.run(arr, apc.multiply_program(
                 lut_add, lut_half, width, radix, a_base, acopy_base, b_base,
                 r_base, carry_col), stats=stats, pool=pool,
-                interpret=interpret, kernel_variant=kernel_variant,
-                unroll=unroll)
+                interpret=interpret)
         adder_writes_a = any(0 in p.write_cols for p in lut_add.passes)
         for j in range(width):
             for t in range(1, radix):
@@ -425,9 +410,8 @@ def mac(arr: Array, lut_add: LUT, lut_rsub: LUT, K: int, width: int,
         x_base: int = 0, w_base: int | None = None,
         acc_base: int | None = None, carry_col: int | None = None,
         stats: APStats | None = None, zero_acc: bool = True,
-        engine: str = "replay", pool=None, interpret: bool | None = None,
-        kernel_variant: str | None = None,
-        unroll: int | None = None) -> Array:
+        engine: str = "replay", pool=None,
+        interpret: bool | None = None) -> Array:
     """ACC <- sum_k w_k * X_k: ternary multiply-accumulate (dot product).
 
     Weight digits W_k store trit+1 in {0,1,2}; for each k one full-adder
@@ -448,8 +432,7 @@ def mac(arr: Array, lut_add: LUT, lut_rsub: LUT, K: int, width: int,
             return apc.run(arr, apc.mac_program(
                 lut_add, lut_rsub, K, width, x_base, w_base, acc_base,
                 carry_col, zero_acc), stats=stats, pool=pool,
-                interpret=interpret, kernel_variant=kernel_variant,
-                unroll=unroll)
+                interpret=interpret)
         if zero_acc:
             for j in range(width):
                 arr = _set_col(arr, acc_base + j, 0, stats)
@@ -473,9 +456,8 @@ def mac(arr: Array, lut_add: LUT, lut_rsub: LUT, K: int, width: int,
 def mac_tiled(x: Array, w_ter: Array, radix: int, width: int, *,
               k_tile: int, pool=None, runtime=None,
               stats: APStats | None = None,
-              blocked: bool = False, interpret: bool | None = None,
-              kernel_variant: str | None = None,
-              unroll: int | None = None) -> Array:
+              blocked: bool = False,
+              interpret: bool | None = None) -> Array:
     """Driver for the K-tiled ternary MAC: ACC = sum_k w_k * x_k from raw
     operands (``x`` [R, K] integers, ``w_ter`` [R, K] in {-1, 0, +1}).
 
@@ -500,8 +482,7 @@ def mac_tiled(x: Array, w_ter: Array, radix: int, width: int, *,
             if pool is not None:
                 raise ValueError("runtime= already carries a pool; pass one "
                                  "of pool= or runtime=")
-            runtime.check_knobs(interpret=interpret,
-                                kernel_variant=kernel_variant, unroll=unroll)
+            runtime.check_knobs(interpret=interpret)
             tiled = apc.compile_mac_tiled(radix, K, width, k_tile,
                                           blocked=blocked,
                                           max_cols=runtime.pool.cols)
@@ -512,6 +493,4 @@ def mac_tiled(x: Array, w_ter: Array, radix: int, width: int, *,
         tiled = apc.compile_mac_tiled(radix, K, width, k_tile,
                                       blocked=blocked, max_cols=max_cols)
         return apc.run_mac_tiled(x, w_ter, tiled, pool=pool, stats=stats,
-                                 interpret=interpret,
-                                 kernel_variant=kernel_variant,
-                                 unroll=unroll)
+                                 interpret=interpret)

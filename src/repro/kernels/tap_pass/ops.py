@@ -5,17 +5,8 @@ import jax
 import jax.numpy as jnp
 
 from ...core.lut import LUT
-from .kernel import (BLOCK_ROWS, resolve_interpret, tap_apply_schedule,
-                     tap_run_program)
+from .kernel import BLOCK_ROWS, tap_run_program
 from .ref import ripple_add_schedule, schedule_from_lut
-
-# Schedules longer than this run through the packed fori_loop program kernel
-# (tap_run_program) instead of unrolling every pass into the trace: a 20-trit
-# non-blocked add is 421 steps, and the unrolled trace costs minutes to
-# build/compile per (schedule, shape) while the packed kernel traces one
-# generic step.  Short schedules keep the unrolled path (no gather overhead)
-# when interpreted.
-UNROLL_STEP_LIMIT = 64
 
 
 def _pad_rows(arr: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
@@ -29,52 +20,41 @@ def _pad_rows(arr: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
 
 
 def _run_schedule(arr: jax.Array, sched, block_rows: int,
-                  interpret: bool | None,
-                  kernel_variant: str | None = None) -> jax.Array:
-    """Dispatch a flat schedule to the unrolled or fori_loop kernel."""
+                  interpret: bool | None) -> jax.Array:
+    """Compile a flat schedule and run it through the program kernel."""
     padded, rows = _pad_rows(arr, block_rows)
-    # The unrolled kernel runs on the pallas interpreter only; a compiled
-    # run (Mosaic on TPU, the jitted-XLA harness elsewhere) takes the
-    # program kernel, which compiles on both.
-    if len(sched) <= UNROLL_STEP_LIMIT and resolve_interpret(interpret):
-        out = tap_apply_schedule(padded, sched, block_rows=block_rows)
-        return out[:rows]
-    from ...apc.lower import (Step, _compile_steps,     # lazy: import cycle
-                              resolve_schedule)
+    from ...apc.lower import Step, _compile_steps     # lazy: import cycle
     compiled = _compile_steps(tuple(
         Step(keys=k, compare_cols=c, write_cols=w, write_vals=v,
              in_hist=bool(k)) for k, c, w, v in sched))
-    tensors, variant, pack, _ = resolve_schedule(compiled, kernel_variant)
     out, _ = tap_run_program(
-        padded, *tensors, jnp.int32(rows), block_rows=block_rows,
-        interpret=interpret, variant=variant, pack=pack)
+        padded, *compiled.schedule_tensors, jnp.int32(rows),
+        block_rows=block_rows, interpret=interpret)
     return out[:rows]
 
 
 def tap_apply_lut(arr: jax.Array, lut: LUT, col_map: tuple[int, ...],
                   block_rows: int = BLOCK_ROWS,
-                  interpret: bool | None = None,
-                  kernel_variant: str | None = None) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """One LUT application (single digit position) on the kernel path."""
     sched = schedule_from_lut(lut, col_map)
-    return _run_schedule(arr, sched, block_rows, interpret, kernel_variant)
+    return _run_schedule(arr, sched, block_rows, interpret)
 
 
 def tap_ripple_add(arr: jax.Array, lut: LUT, width: int, carry_col: int,
                    a_base: int = 0, b_base: int | None = None,
                    block_rows: int = BLOCK_ROWS,
-                   interpret: bool | None = None,
-                   kernel_variant: str | None = None) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Fused p-digit in-place add: B <- A + B in ONE kernel launch.
 
     This is the flagship fusion: a 20-trit non-blocked add is 441 compare +
     441 write passes; the naive path moves the array to/from HBM for each,
     while this launch streams each row-block through VMEM exactly once.
-    Wide adds route through the packed fori_loop program kernel (see
-    ``UNROLL_STEP_LIMIT``) so trace time stays O(1) in width.
+    The program kernel fori-loops over the schedule, so trace time stays
+    O(1) in width.
     """
     sched = ripple_add_schedule(lut, width, carry_col, a_base, b_base)
-    return _run_schedule(arr, sched, block_rows, interpret, kernel_variant)
+    return _run_schedule(arr, sched, block_rows, interpret)
 
 
 def hbm_traffic_model(n_rows: int, n_cols: int, lut: LUT, width: int

@@ -88,9 +88,7 @@ def ternary_matmul_ap(x: jax.Array, packed: jax.Array, scale: jax.Array,
                       k_tile: int | None = None,
                       stats=None, block_rows: int | None = None,
                       blocked: bool = False,
-                      interpret: bool | None = None,
-                      kernel_variant: str | None = None,
-                      unroll: int | None = None) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """y[M, N] = (x @ unpack(packed)) * scale on the AP program executor.
 
     ``x`` [M, K] integer-valued; ``packed``/``scale`` as produced by
@@ -109,11 +107,10 @@ def ternary_matmul_ap(x: jax.Array, packed: jax.Array, scale: jax.Array,
     (possibly device-spanning) bank — same digits, same counters, plus the
     graph makespan in ``runtime.last_report``; ``k_tile`` alone runs the
     tiled programs on the single-array executor (the tiled-vs-untiled
-    oracle); ``mesh`` shards the M*N row axis.  ``kernel_variant`` /
-    ``interpret`` / ``unroll`` pick the program-kernel formulation
-    (gather / one-hot / one-hot+packed / lanes, interpreted or compiled; see
-    :mod:`repro.apc.exec`) and default to the measured backend best —
-    every combination is bit-exact.  Bit-exact vs
+    oracle); ``mesh`` shards the M*N row axis.  ``interpret`` picks how
+    the program kernel runs (interpreted or compiled; see
+    :mod:`repro.apc.exec`) and defaults per backend; both are bit-exact.
+    Bit-exact vs
     :func:`~repro.kernels.ternary_matmul.ref.ternary_matmul_ref` on every
     route because the integer accumulator converts to float32 exactly and
     the final scale-multiply is the same float32 op.
@@ -148,16 +145,14 @@ def ternary_matmul_ap(x: jax.Array, packed: jax.Array, scale: jax.Array,
         acc = _run_routed(apc, x_rows, w_rows, radix, kp, width,
                           mesh=mesh, pool=pool, runtime=runtime,
                           k_tile=k_tile, stats=stats, block_rows=block_rows,
-                          blocked=blocked, interpret=interpret,
-                          kernel_variant=kernel_variant, unroll=unroll)
+                          blocked=blocked, interpret=interpret)
     y = (acc.reshape(m, n).astype(jnp.float32)
          * jnp.asarray(scale, jnp.float32)[None, :])
     return y.astype(x.dtype)
 
 
 def _run_routed(apc, x_rows, w_rows, radix, kp, width, *, mesh, pool,
-                runtime, k_tile, stats, block_rows, blocked, interpret,
-                kernel_variant, unroll):
+                runtime, k_tile, stats, block_rows, blocked, interpret):
     if runtime is not None:
         if mesh is not None or pool is not None:
             raise ValueError("runtime= already carries a pool; pass one of "
@@ -165,8 +160,7 @@ def _run_routed(apc, x_rows, w_rows, radix, kp, width, *, mesh, pool,
         if block_rows is not None:
             raise ValueError("block_rows only applies without runtime=; "
                              "the runtime pool's own rows govern blocks")
-        runtime.check_knobs(interpret=interpret,
-                            kernel_variant=kernel_variant, unroll=unroll)
+        runtime.check_knobs(interpret=interpret)
         max_cols = runtime.pool.cols
         kt = k_tile if k_tile is not None else default_k_tile(max_cols,
                                                               width)
@@ -186,14 +180,11 @@ def _run_routed(apc, x_rows, w_rows, radix, kp, width, *, mesh, pool,
                                       blocked=blocked, max_cols=max_cols)
         return apc.run_mac_tiled(x_rows, w_rows, tiled, pool=pool,
                                  stats=stats, block_rows=block_rows,
-                                 interpret=interpret,
-                                 kernel_variant=kernel_variant,
-                                 unroll=unroll)
+                                 interpret=interpret)
     compiled = apc.compile_mac(radix, kp, width, blocked=blocked)
     arr = apc.encode_mac_rows_jnp(x_rows, w_rows, radix, width)
     out = apc.run(arr, compiled, stats=stats, mesh=mesh,
-                  block_rows=block_rows, interpret=interpret,
-                  kernel_variant=kernel_variant, unroll=unroll)
+                  block_rows=block_rows, interpret=interpret)
     return apc.decode_mac_acc_jnp(out, radix, kp, width)           # [M*N]
 
 

@@ -90,58 +90,6 @@ def test_bench_ap_runtime_smoke_schema():
     assert rows[1]["makespan_cycles"] < rows[1]["sequential_cycles"]
 
 
-def test_bench_ap_kernel_smoke_schema():
-    """CI smoke: the ap_kernel trajectory rows keep their schema at toy
-    sizes; bit-equality across variants is asserted inside the bench."""
-    from benchmarks.kernels_bench import bench_ap_kernel
-    rows = bench_ap_kernel(programs=(("add", 3, 4), ("max", 3, 6)),
-                           rows_list=(64,), n_timing=1)
-    assert len(rows) == 2
-    keys = {"bench", "op", "radix", "width", "rows", "n_steps",
-            "packed_groups", "pack", "pack_efficiency", "gather_interp_us",
-            "gather_us", "onehot_us", "onehot_packed_us",
-            "speedup_gather_x", "speedup_onehot_x",
-            "speedup_onehot_packed_x"}
-    for r in rows:
-        assert keys <= set(r)
-        assert r["bench"] == "ap_kernel"
-        assert 1 <= r["packed_groups"] <= r["n_steps"]
-        assert r["pack"] >= 1
-    # the digitwise program must pack; the carry ripple must not
-    by_op = {r["op"]: r for r in rows}
-    assert by_op["max"]["packed_groups"] * 2 <= by_op["max"]["n_steps"]
-    assert by_op["add"]["pack"] == 1
-
-
-def test_apc_bench_json_recorded_ap_kernel_rows():
-    """The RECORDED benchmarks/apc_bench.json must carry the ap_kernel
-    variant matrix with its structural invariants intact."""
-    import json
-    import os
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks", "apc_bench.json")
-    with open(path) as f:
-        data = json.load(f)
-    rows = data.get("ap_kernel", [])
-    assert rows, "apc_bench.json is missing the ap_kernel trajectory"
-    ops = set()
-    for r in rows:
-        ops.add(r["op"])
-        for col in ("gather_interp_us", "gather_us", "onehot_us",
-                    "onehot_packed_us"):
-            assert r[col] > 0
-        assert 1 <= r["packed_groups"] <= r["n_steps"]
-        assert r["pack"] >= 1
-        want = r["gather_interp_us"] / max(1, r["onehot_packed_us"])
-        assert r["speedup_onehot_packed_x"] == pytest.approx(
-            want, rel=0.02, abs=0.011)      # column is rounded to 2dp
-        if r["op"] == "max":            # digitwise: list scheduling engaged
-            assert r["packed_groups"] * 4 <= r["n_steps"]
-            assert r["pack"] > 1
-    # the matrix spans a serial, a multiply-scale, and a packable program
-    assert {"add", "mul", "max"} <= ops
-
-
 def test_apc_bench_json_recorded_ap_runtime_rows():
     """The RECORDED benchmarks/apc_bench.json must carry the ap_runtime
     trajectory with the makespan <= sequential invariant intact."""
@@ -383,7 +331,7 @@ def test_regression_sentinel_smoke_catches_structural_baseline_drift(
     with open(sent.DEFAULT_JSON) as f:
         doc = json.load(f)
     doc["ap_pool"][0]["wall_write_cycles"] += 1
-    doc["ap_kernel"][0]["pack"] += 1
+    doc["ap_matmul"][0]["write_cycles"] += 1
     # fault-trajectory invariant: surviving-bank accounting must balance
     doc["ap_faults"][-1]["surviving_arrays"] += 1
     path = tmp_path / "tampered.json"

@@ -1,12 +1,14 @@
-"""Kernel-variant + VLIW-packing equivalence suite (ISSUE 5).
+"""Program-kernel equivalence suite: the one kernel body against the oracles.
 
-Contract: the one-hot, one-hot+packed and lanes program kernels are
-bit-identical to the gather kernel — digits AND APStats (sets/resets/cycles/
-mismatch histogram, including the saturating top bin) — on every program
-class, in both interpret (pallas) and compiled (interpret=False, jitted XLA
-on CPU) modes; the packing pass serializes every write-slot conflict;
-duplicate write/compare columns in one step fall back to the gather body
-under the one-hot variants, and the lanes body replays them itself.
+Contract: the program kernel (the ``lanes`` body) returns the replay
+oracle's digits AND per-block counters (sets/resets/mismatch histogram,
+including the saturating top bin) on every program class, in both
+interpret (pallas) and compiled (``interpret=False``, jitted XLA on CPU)
+modes.  The oracles are :func:`_oracle` below — a numpy step-by-step
+replay of a :class:`~repro.apc.lower.Step` schedule with per-block
+counters — the ``kernels/tap_pass/ref.py`` digit replay, and the
+``core.ap`` pass-by-pass drivers.  Steps with duplicate write or compare
+columns replay in order, one column after another.
 """
 import numpy as np
 import pytest
@@ -14,11 +16,13 @@ import pytest
 import jax.numpy as jnp
 
 from repro import apc
+from repro.apc.lower import CompiledProgram, Step
+from repro.apc.stats import HIST_BINS
 from repro.core import ap, build_lut_nonblocked
 from repro.core import truth_tables as tt
-from repro.apc.lower import PackedProgram, pack_steps, resolve_schedule
+from repro.kernels.tap_pass.ref import DONT_CARE
 
-VARIANTS = ("gather", "onehot", "onehot_packed", "lanes")
+MODES = (True, False)            # interpret: pallas interpreter, XLA harness
 
 
 def _stats_equal(a: ap.APStats, b: ap.APStats) -> None:
@@ -29,106 +33,71 @@ def _stats_equal(a: ap.APStats, b: ap.APStats) -> None:
     assert np.array_equal(a.mismatch_hist, b.mismatch_hist)
 
 
-def _run_all_variants(arr, compiled, rows, radix):
-    """(digits, APStats) per (variant, interpret) combination."""
-    out = {}
-    for kv in VARIANTS:
-        for interp in (True, False):
-            o, tr = apc.execute(arr, compiled, collect_stats=True,
-                                kernel_variant=kv, interpret=interp)
-            out[(kv, interp)] = (np.asarray(o),
-                                 apc.to_ap_stats(tr, compiled, rows, radix))
-    return out
+def _oracle(arr, compiled: CompiledProgram, n_valid: int | None = None,
+            block_rows: int | None = None):
+    """Step-by-step numpy replay: ``(digits, counts)`` with ``counts`` the
+    per-block (n_blocks, 2 + HIST_BINS) [sets, resets, hist...] counters.
+    Rows at or past ``n_valid`` are neither written nor counted."""
+    d = np.array(arr, np.int64)
+    rows = d.shape[0]
+    n_valid = rows if n_valid is None else n_valid
+    block_rows = block_rows or max(1, rows)
+    ok = np.arange(rows) < n_valid
+    blk = np.arange(rows) // block_rows
+    counts = np.zeros((max(1, -(-rows // block_rows)), 2 + HIST_BINS),
+                      np.int64)
+    for st in compiled.steps:
+        tag = ok.copy()
+        if st.keys:
+            tag[:] = False
+            for key in st.keys:
+                mm = np.zeros(rows, np.int64)
+                for c, k in zip(st.compare_cols, key):
+                    mm += (d[:, c] != k) & (d[:, c] != DONT_CARE)
+                tag |= mm == 0
+                if st.in_hist:
+                    np.add.at(counts, (blk[ok], 2 + np.minimum(
+                        mm[ok], HIST_BINS - 1)), 1)
+            tag &= ok
+        for c, v in zip(st.write_cols, st.write_vals):
+            old = d[:, c].copy()
+            changed = tag & (old != v)
+            np.add.at(counts, (blk[changed], 0), 1)
+            np.add.at(counts, (blk[changed & (old != DONT_CARE)], 1), 1)
+            d[changed, c] = v
+    return d.astype(np.int8), counts
 
 
-def _assert_all_match(results):
-    base = results[("gather", True)]
-    for key, (digits, stats) in results.items():
-        assert np.array_equal(digits, base[0]), f"{key} digits diverge"
-        _stats_equal(stats, base[1])
+def _oracle_stats(counts, compiled, rows, radix) -> ap.APStats:
+    return apc.to_ap_stats(apc.TracedStats(jnp.asarray(counts, jnp.int32)),
+                           compiled, rows, radix)
 
 
-# ---------------------------------------------------------------------------
-# Packing-pass structural invariants
-# ---------------------------------------------------------------------------
-
-def _cw(cc, key, wc, wv, hist=True):
-    from repro.apc.lower import Step
-    return Step(keys=(tuple(key),) if cc else (), compare_cols=tuple(cc),
-                write_cols=tuple(wc), write_vals=tuple(wv), in_hist=hist)
-
-
-def test_pack_steps_write_conflicts_do_not_pack():
-    """WAW: consecutive steps writing the same column must stay in strictly
-    ordered groups (the ISSUE's write-slot-conflict case)."""
-    steps = tuple(_cw((0,), (1,), (5,), (v % 3,)) for v in range(6))
-    groups = pack_steps(steps, max_pack=8)
-    assert [len(g) for g in groups] == [1] * 6          # fully serial
-    assert [g[0] for g in groups] == list(range(6))     # order preserved
+def _check_execute(arr, compiled, radix):
+    """Run ``apc.execute`` in both modes and hold each to the oracle's
+    digits and counters; returns the oracle's digits and APStats."""
+    rows = arr.shape[0]
+    want, counts = _oracle(arr, compiled)
+    want_stats = _oracle_stats(counts, compiled, rows, radix)
+    for interp in MODES:
+        out, tr = apc.execute(arr, compiled, collect_stats=True,
+                              interpret=interp)
+        assert np.array_equal(np.asarray(out), want), interp
+        _stats_equal(apc.to_ap_stats(tr, compiled, rows, radix), want_stats)
+    return want, want_stats
 
 
-def test_pack_steps_raw_and_war_serialize():
-    # RAW: step 1 compares what step 0 writes
-    g = pack_steps((_cw((0,), (1,), (2,), (1,)), _cw((2,), (1,), (3,), (1,))))
-    assert len(g) == 2
-    # WAR: step 1 writes what step 0 compares
-    g = pack_steps((_cw((0,), (1,), (2,), (1,)), _cw((3,), (1,), (0,), (1,))))
-    assert len(g) == 2
-    # independent columns: one group of 2
-    g = pack_steps((_cw((0,), (1,), (2,), (1,)), _cw((1,), (1,), (3,), (1,))))
-    assert len(g) == 1 and len(g[0]) == 2
-
-
-def test_pack_steps_capacity_cap():
-    steps = tuple(_cw((c,), (1,), (8 + c,), (1,)) for c in range(8))
-    assert [len(g) for g in pack_steps(steps, max_pack=3)] == [3, 3, 2]
-
-
-def test_packed_program_is_a_padded_permutation():
-    compiled = apc.compile_named("max", 3, 6)           # elementwise: packs
-    p = compiled.packed()
-    assert p.n_groups < compiled.n_steps
-    assert p.pack > 1
-    assert p.n_slots == p.n_groups * p.pack
-    # every original slot appears exactly once; pads are inert no-ops
-    occupied = p.key_valid.any(axis=1) | (p.wr_cols >= 0).any(axis=1)
-    assert occupied.sum() == compiled.n_steps
-    assert not p.hist_flag[~occupied].any()
-    assert (p.wr_cols[~occupied] == -1).all()
-    # original write-cycle accounting is untouched by packing
-    assert compiled.n_write_cycles == compiled.n_steps
-
-
-def test_elementwise_packs_substantially():
-    """Digitwise MVL ops have independent digit positions: the trip count
-    must shrink by ~the digit width (capped by max_pack)."""
-    compiled = apc.compile_named("max", 3, 8)
-    p = compiled.packed()
-    assert p.n_groups * 4 <= compiled.n_steps           # >= 4x fewer trips
-
-
-def test_mul_packing_is_critical_path_bound_and_gated():
-    """Carry-ripple programs barely pack (the serial chains are real); the
-    resolver must then skip the padded copy rather than inflate slot work."""
-    compiled = apc.compile_named("mul", 3, 4)
-    p = compiled.packed()
-    assert p.n_groups < compiled.n_steps                # repairs overlay
-    sched, variant, pack, name = resolve_schedule(compiled, "onehot_packed")
-    if p.n_slots > 1.25 * compiled.n_steps:             # inflation gate
-        assert pack == 1 and name == "onehot"
-        assert sched[0].shape[0] == compiled.n_steps
-
-
-def test_packed_program_rejects_duplicate_write_cols():
-    from repro.apc.lower import CompiledProgram
-    dup = CompiledProgram((_cw((0,), (1,), (2, 2), (1, 2)),))
-    assert not dup.writes_distinct
-    with pytest.raises(ValueError):
-        PackedProgram(dup)
+def _kernel_run(arr, tensors, n_valid, *, block_rows, interpret):
+    from repro.kernels.tap_pass.kernel import tap_run_program
+    out, counts = tap_run_program(
+        jnp.asarray(arr), *map(jnp.asarray, tensors), jnp.int32(n_valid),
+        block_rows=block_rows, collect_stats=True, hist_bins=HIST_BINS,
+        interpret=interpret)
+    return np.asarray(out), np.asarray(counts)
 
 
 # ---------------------------------------------------------------------------
-# Bit-exactness: named programs at radix 3/4/5, all variants x interpret
+# Bit-exactness: named programs at radix 3/4/5, both modes
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("radix", [3, 4, 5])
@@ -140,12 +109,18 @@ def test_variants_parity_addsub(radix, op):
     b = rng.integers(0, radix ** w, rows)
     arr = jnp.asarray(ap.encode_operands(a, b, radix, w))
     compiled = apc.compile_named(op, radix, w)
-    results = _run_all_variants(arr, compiled, rows, radix)
-    _assert_all_match(results)
-    got = ap.decode_digits(results[("gather", True)][0],
-                           list(range(w, 2 * w)), radix)
+    digits, stats = _check_execute(arr, compiled, radix)
+    got = ap.decode_digits(digits, list(range(w, 2 * w)), radix)
     want = (a + b if op == "add" else a - b) % radix ** w
     assert np.array_equal(got, want)
+    # the core.ap pass-by-pass replay writes and charges the same
+    lut = build_lut_nonblocked(
+        tt.full_adder(radix) if op == "add" else tt.full_subtractor(radix))
+    driver = ap.ripple_add if op == "add" else ap.ripple_sub
+    so = ap.APStats(radix=radix)
+    assert np.array_equal(np.asarray(driver(arr, lut, w, 2 * w, stats=so)),
+                          digits)
+    _stats_equal(so, stats)
 
 
 @pytest.mark.parametrize("radix", [3, 4, 5])
@@ -160,16 +135,14 @@ def test_variants_parity_mul(radix):
         arr[:, 2 * w + i] = (b // radix ** i) % radix
     arr = jnp.asarray(arr)
     compiled = apc.compile_named("mul", radix, w)
-    results = _run_all_variants(arr, compiled, rows, radix)
-    _assert_all_match(results)
-    got = ap.decode_digits(results[("gather", True)][0],
-                           list(range(3 * w, 5 * w)), radix)
+    digits, _ = _check_execute(arr, compiled, radix)
+    got = ap.decode_digits(digits, list(range(3 * w, 5 * w)), radix)
     assert np.array_equal(got, a * b)
 
 
 @pytest.mark.parametrize("fn", ["max", "min", "modsum", "negate"])
 def test_variants_parity_elementwise_and_negate(fn):
-    """The program classes where packing actually engages."""
+    """The digitwise programs and the radix complement."""
     r, w, rows = 3, 6, 129
     rng = np.random.default_rng(sum(map(ord, fn)))
     a = rng.integers(0, r ** w, rows)
@@ -177,7 +150,10 @@ def test_variants_parity_elementwise_and_negate(fn):
     extra = 1 if fn == "negate" else 0
     arr = jnp.asarray(ap.encode_operands(a, b, r, w, extra_cols=extra))
     compiled = apc.compile_named(fn, r, w)
-    _assert_all_match(_run_all_variants(arr, compiled, rows, r))
+    digits, _ = _check_execute(arr, compiled, r)
+    if fn == "negate":
+        got = ap.decode_digits(digits, list(range(w, 2 * w)), r)
+        assert np.array_equal(got, (-a) % r ** w)
 
 
 @pytest.mark.parametrize("radix", [3, 4, 5])
@@ -189,17 +165,15 @@ def test_variants_parity_mac(radix):
     wt = rng.integers(-1, 2, (rows, K))
     arr = jnp.asarray(apc.encode_mac_rows(x, wt, radix, width))
     compiled = apc.compile_mac(radix, K, width)
-    results = _run_all_variants(arr, compiled, rows, radix)
-    _assert_all_match(results)
-    got = apc.decode_mac_acc(results[("gather", True)][0], radix, K, width)
+    digits, _ = _check_execute(arr, compiled, radix)
+    got = apc.decode_mac_acc(digits, radix, K, width)
     assert np.array_equal(got, (x * wt).sum(axis=1))
 
 
-@pytest.mark.parametrize("kernel_variant",
-                         ["onehot", "onehot_packed", "lanes"])
-def test_variants_parity_tiled_mac_matmul(kernel_variant):
-    """The tiled-MAC serving path (pool + reduction chain) stays bit-exact
-    vs the jnp reference and counter-identical vs the gather run."""
+@pytest.mark.parametrize("radix", [3, 4, 5])
+def test_variants_parity_tiled_mac_matmul(radix):
+    """The tiled-MAC serving path (pool + reduction chain) is bit-exact vs
+    the jnp reference and counter-identical across the two modes."""
     from repro.kernels.ternary_matmul.ap import ternary_matmul_ap
     from repro.kernels.ternary_matmul.ops import quantize_and_pack
     from repro.kernels.ternary_matmul.ref import ternary_matmul_ref
@@ -210,39 +184,42 @@ def test_variants_parity_tiled_mac_matmul(kernel_variant):
     packed, scale = quantize_and_pack(w)
     x = jnp.asarray(rng.integers(-3, 4, (m, k)), jnp.float32)
     y_ref = ternary_matmul_ref(x, packed, scale)
-    stats = {}
-    for kv in ("gather", kernel_variant):
-        pool = apc.ArrayPool(n_arrays=2, rows=8, cols=64, kernel_variant=kv)
-        st = ap.APStats(radix=3)
-        y = ternary_matmul_ap(x, packed, scale, radix=3, pool=pool, stats=st)
+    stats = []
+    for interp in MODES:
+        pool = apc.ArrayPool(n_arrays=2, rows=8, cols=64, interpret=interp)
+        st = ap.APStats(radix=radix)
+        y = ternary_matmul_ap(x, packed, scale, radix=radix, pool=pool,
+                              stats=st)
         assert np.array_equal(np.asarray(y), np.asarray(y_ref))
-        stats[kv] = st
-    _stats_equal(stats["gather"], stats[kernel_variant])
+        stats.append(st)
+    assert stats[0].n_write_cycles > 0
+    _stats_equal(*stats)
 
 
 def test_variants_parity_runtime_graph():
-    """DevicePool/Runtime graph route honours the variant knob bit-exactly."""
+    """The DevicePool/Runtime graph route, in both modes, charges what the
+    single-array tiled MAC charges and decodes to the dot products."""
     rng = np.random.default_rng(9)
     K, width, rows = 12, 4, 21
     tiled = apc.compile_mac_tiled(3, K, width, 4, max_cols=64)
     x = jnp.asarray(rng.integers(-3, 4, (rows, K)), jnp.int32)
     wt = jnp.asarray(rng.integers(-1, 2, (rows, K)), jnp.int8)
     want = np.asarray((np.asarray(x) * np.asarray(wt)).sum(axis=1))
-    stats = {}
-    for kv in VARIANTS:
+    base = ap.APStats(radix=3)
+    got = apc.run_mac_tiled(x, wt, tiled, stats=base)
+    assert np.array_equal(np.asarray(got), want)
+    for interp in MODES:
         rt = apc.Runtime(apc.ArrayPool(n_arrays=2, rows=8, cols=64),
-                         kernel_variant=kv)
+                         interpret=interp)
         st = ap.APStats(radix=3)
         (digits,) = rt.run_mac_graph([(x, wt, tiled)], stats=st)
         got = np.asarray(apc.decode_signed_digits_jnp(digits, 3))
         assert np.array_equal(got, want)
-        stats[kv] = st
-    for kv in VARIANTS[1:]:
-        _stats_equal(stats["gather"], stats[kv])
+        _stats_equal(base, st)
 
 
 # ---------------------------------------------------------------------------
-# The lanes body at the served shapes: raw kernel output against gather
+# The kernel at the served shapes: raw output against the oracle
 # ---------------------------------------------------------------------------
 
 def _served_tiled(K: int):
@@ -269,24 +246,25 @@ def _wide_compare_program():
                          write_vals=(2,))))
 
 
-def _kernel_run(arr, tensors, n_valid, *, block_rows, variant, pack=1,
-                interpret):
-    from repro.apc.stats import HIST_BINS
-    from repro.kernels.tap_pass.kernel import tap_run_program
-    out, counts = tap_run_program(
-        jnp.asarray(arr), *map(jnp.asarray, tensors), jnp.int32(n_valid),
-        block_rows=block_rows, collect_stats=True, hist_bins=HIST_BINS,
-        interpret=interpret, variant=variant, pack=pack)
-    return np.asarray(out), np.asarray(counts)
+def _with_noop_slots(compiled: CompiledProgram) -> tuple[np.ndarray, ...]:
+    """``compiled``'s schedule tensors with a no-op slot (no valid key,
+    every write column -1) after every step."""
+    out = []
+    for t in compiled.schedule_tensors:
+        pad = np.full_like(t, -1) if t.dtype == np.int32 else \
+            np.zeros_like(t)
+        out.append(np.stack([t, pad], axis=1).reshape(-1, *t.shape[1:]))
+    return tuple(out)
 
 
 @pytest.mark.parametrize("case", [
     "add_r3w20", "mac_k1024_tile", "mac_k1024_reduce", "packed_noop_slots",
     "partial_blocks", "hist_top_bin"])
 def test_lanes_kernel_parity(case):
-    """The lanes body, interpreted and through the jitted-XLA harness,
-    returns the gather body's digits and per-block counters bit for bit
-    (every counter, the saturating top histogram bin included)."""
+    """The kernel, interpreted and through the jitted-XLA harness, returns
+    the oracle's digits and per-block counters bit for bit (every counter,
+    the saturating top histogram bin included); no-op slots interleaved
+    into the schedule change nothing."""
     rows, block_rows, n_valid = 256, 128, 256
     if case in ("add_r3w20", "partial_blocks"):
         compiled = apc.compile_named("add", 3, 20)
@@ -306,50 +284,107 @@ def test_lanes_kernel_parity(case):
         assert compiled.min_cols == 250 and compiled.n_steps > 9000
     rng = np.random.default_rng(sum(map(ord, case)))
     arr = rng.integers(-1, 3, (rows, compiled.min_cols)).astype(np.int8)
-    want = _kernel_run(arr, compiled.schedule_tensors, n_valid,
-                       block_rows=block_rows, variant="gather",
-                       interpret=True)
-    tensors, pack = compiled.schedule_tensors, 1
+    want = _oracle(arr, compiled, n_valid, block_rows)
+    tensors = compiled.schedule_tensors
     if case == "packed_noop_slots":
-        p = compiled.packed()
-        assert p.pack > 1 and p.n_slots > compiled.n_steps   # no-op slots
-        tensors, pack = p.schedule_tensors, p.pack
+        tensors = _with_noop_slots(compiled)
+        assert len(tensors[0]) == 2 * compiled.n_steps
     if case == "hist_top_bin":
-        from repro.apc.stats import HIST_BINS
         assert want[1][:, 2 + HIST_BINS - 1].sum() > 0
-    for interp in (True, False):
+    for interp in MODES:
         got = _kernel_run(arr, tensors, n_valid, block_rows=block_rows,
-                          variant="lanes", pack=pack, interpret=interp)
+                          interpret=interp)
         assert np.array_equal(got[0], want[0]), (case, interp)
         assert np.array_equal(got[1], want[1]), (case, interp)
 
 
+def _random_program(rng, n_steps: int, n_cols: int, radix: int = 3
+                    ) -> CompiledProgram:
+    """``n_steps`` random steps over ``n_cols`` columns: up to 3 compare
+    and 2 write columns (repeats allowed), up to 2 keys, some
+    unconditional writes and some steps out of the histogram."""
+    steps = []
+    for _ in range(n_steps):
+        cc = tuple(int(c) for c in rng.integers(0, n_cols,
+                                                 rng.integers(0, 4)))
+        wc = tuple(int(c) for c in rng.integers(0, n_cols,
+                                                 rng.integers(1, 3)))
+        keys = tuple(tuple(int(v) for v in rng.integers(0, radix, len(cc)))
+                     for _ in range(rng.integers(1, 3))) if cc else ()
+        wv = tuple(int(v) for v in rng.integers(0, radix, len(wc)))
+        steps.append(Step(keys=keys, compare_cols=cc, write_cols=wc,
+                          write_vals=wv, in_hist=bool(rng.integers(0, 4))))
+    return CompiledProgram(tuple(steps))
+
+
+@pytest.mark.parametrize("n_steps", [1023, 1024, 1025, 2049])
+def test_schedule_chunk_boundaries(n_steps):
+    """Programs one slot short of, at, one past and one past twice the
+    pallas kernel's schedule chunk: each chunk streams whole slots and the
+    rounded-up tail is no-op padding, so digits and counters match the
+    oracle."""
+    from repro.kernels.tap_pass.kernel import SCHED_CHUNK
+    assert SCHED_CHUNK == 1024
+    rng = np.random.default_rng(n_steps)
+    compiled = _random_program(rng, n_steps, n_cols=7)
+    assert compiled.n_steps == n_steps
+    rows, block_rows, n_valid = 96, 32, 90
+    arr = rng.integers(-1, 3, (rows, 7)).astype(np.int8)
+    want = _oracle(arr, compiled, n_valid, block_rows)
+    for interp in MODES:
+        got = _kernel_run(arr, compiled.schedule_tensors, n_valid,
+                          block_rows=block_rows, interpret=interp)
+        assert np.array_equal(got[0], want[0]), interp
+        assert np.array_equal(got[1], want[1]), interp
+
+
+@pytest.mark.parametrize("block_rows", [128, 1024, 4096])
+def test_interpret_modes_bit_identical(block_rows):
+    """The two CPU routes of the one body — the pallas interpreter and the
+    jitted-XLA harness — give identical digits and per-block counters on
+    two and a half blocks, the last one partial, and both match the
+    oracle."""
+    compiled = apc.compile_named("add", 3, 20)
+    rows = 3 * block_rows
+    n_valid = 2 * block_rows + block_rows // 2 + 3
+    rng = np.random.default_rng(block_rows)
+    arr = rng.integers(-1, 3, (rows, compiled.min_cols)).astype(np.int8)
+    pallas = _kernel_run(arr, compiled.schedule_tensors, n_valid,
+                         block_rows=block_rows, interpret=True)
+    xla = _kernel_run(arr, compiled.schedule_tensors, n_valid,
+                      block_rows=block_rows, interpret=False)
+    assert pallas[1].shape == (3, 2 + HIST_BINS)
+    assert np.array_equal(pallas[0], xla[0])
+    assert np.array_equal(pallas[1], xla[1])
+    want = _oracle(arr, compiled, n_valid, block_rows)
+    assert np.array_equal(pallas[0], want[0])
+    assert np.array_equal(pallas[1], want[1])
+
+
 def test_lanes_block_valid_launch_parity():
     """A row-concatenated pool launch (``block_valid``): per-segment
-    digits and counters equal the gather body's."""
+    digits and counters equal the oracle's for each segment alone."""
     compiled = apc.compile_named("add", 3, 20)
     rng = np.random.default_rng(14)
-    arr = jnp.asarray(rng.integers(0, 3, (3 * 128, compiled.min_cols)),
-                      jnp.int8)
-    got = {}
-    for kv in ("gather", "lanes"):
-        for interp in (True, False):
-            pool = apc.ArrayPool(n_arrays=2, rows=128, cols=64,
-                                 kernel_variant=kv, interpret=interp)
-            out, traced = pool.run(arr, compiled, collect_stats=True,
-                                   block_valid=(128, 70, 5))
-            got[kv, interp] = (np.asarray(out),
-                               np.asarray(traced.block_counts))
-    base = got["gather", True]
-    assert base[0].shape[0] == 128 + 70 + 5
-    for key, (digits, counts) in got.items():
-        assert np.array_equal(digits, base[0]), key
-        assert np.array_equal(counts, base[1]), key
+    arr = rng.integers(0, 3, (3 * 128, compiled.min_cols)).astype(np.int8)
+    valid = (128, 70, 5)
+    segs = [_oracle(arr[b * 128:(b + 1) * 128], compiled, v, 128)
+            for b, v in enumerate(valid)]
+    want_digits = np.concatenate([d[:v] for (d, _), v in zip(segs, valid)])
+    want_counts = np.concatenate([c for _, c in segs])
+    for interp in MODES:
+        pool = apc.ArrayPool(n_arrays=2, rows=128, cols=64,
+                             interpret=interp)
+        out, traced = pool.run(jnp.asarray(arr), compiled,
+                               collect_stats=True, block_valid=valid)
+        assert np.array_equal(np.asarray(out), want_digits), interp
+        assert np.array_equal(np.asarray(traced.block_counts),
+                              want_counts), interp
 
 
 def test_compiled_path_interpret_false_parity_sharded():
-    """interpret=False on CPU (the jitted-XLA harness) through the
-    shard_map scaffolding: digits + psummed counters match the oracle."""
+    """Both modes through the shard_map scaffolding: digits + psummed
+    counters match the oracle."""
     from repro.launch.mesh import make_smoke_mesh
     mesh = make_smoke_mesh()
     r, w, rows = 3, 6, 300
@@ -358,47 +393,37 @@ def test_compiled_path_interpret_false_parity_sharded():
     b = rng.integers(0, r ** w, rows)
     arr = jnp.asarray(ap.encode_operands(a, b, r, w))
     compiled = apc.compile_named("add", r, w)
-    out_l, tr_l = apc.execute(arr, compiled, collect_stats=True,
-                              kernel_variant="gather", interpret=True)
-    for kv in VARIANTS:
+    want, counts = _oracle(arr, compiled)
+    want_stats = _oracle_stats(counts, compiled, rows, r)
+    for interp in MODES:
         out_s, tr_s = apc.execute_sharded(arr, compiled, mesh,
                                           collect_stats=True, block_rows=128,
-                                          kernel_variant=kv, interpret=False)
-        assert np.array_equal(np.asarray(out_l), np.asarray(out_s))
-        _stats_equal(apc.to_ap_stats(tr_l, compiled, rows, r),
-                     apc.to_ap_stats(tr_s, compiled, rows, r))
+                                          interpret=interp)
+        assert np.array_equal(np.asarray(out_s), want)
+        _stats_equal(apc.to_ap_stats(tr_s, compiled, rows, r), want_stats)
 
 
-def test_dup_write_cols_fall_back_to_gather_bit_exact():
-    """Steps with duplicate write columns keep serial write semantics: the
-    resolver must route every one-hot request to the gather body (the
-    lanes body replays write columns one by one itself), and the result
-    must equal the legacy jnp schedule oracle."""
+def test_dup_cols_replay_in_order_bit_exact():
+    """Steps with duplicate write columns keep serial write semantics (the
+    last value wins, every change is charged) and duplicate compare
+    columns count one mismatch per position: the kernel replays both in
+    order and equals the jnp schedule oracle and the counter oracle."""
     from repro.kernels.tap_pass.ref import apply_schedule
     prog = (apc.CompareWrite(compare_cols=(0,), key=(1,),
                              write_cols=(2, 2), write_vals=(1, 2)),
             apc.CompareWrite(compare_cols=(1, 1), key=(0, 0),
                              write_cols=(3,), write_vals=(2,)),)
     compiled = apc.compile_program(prog)
-    assert not compiled.writes_distinct and not compiled.compares_distinct
-    for kv in VARIANTS:
-        sched, variant, pack, name = resolve_schedule(compiled, kv)
-        want = "lanes" if kv == "lanes" else "gather"
-        assert (variant, pack, name) == (want, 1, want)
     rng = np.random.default_rng(8)
     arr = jnp.asarray(rng.integers(0, 3, (64, 4)), jnp.int8)
     want = np.asarray(apply_schedule(arr, compiled.as_tap_steps()))
-    for kv in VARIANTS:
-        for interp in (True, False):
-            out, _ = apc.execute(arr, compiled, kernel_variant=kv,
-                                 interpret=interp)
-            assert np.array_equal(np.asarray(out), want)
+    assert np.array_equal(_check_execute(arr, compiled, 3)[0], want)
 
 
 def test_runtime_route_rejects_unhonored_knobs():
-    """The runtime= route executes with the Runtime's own knobs; explicit
-    per-call knobs that differ (including vs an unset None) must raise
-    instead of being silently dropped."""
+    """The runtime= route executes with the Runtime's own ``interpret``;
+    an explicit per-call value that differs (including vs an unset None)
+    must raise instead of being silently dropped."""
     from repro.kernels.ternary_matmul.ap import ternary_matmul_ap
     from repro.kernels.ternary_matmul.ops import quantize_and_pack
     import jax
@@ -406,71 +431,26 @@ def test_runtime_route_rejects_unhonored_knobs():
     packed, scale = quantize_and_pack(w)
     x = jnp.asarray(np.ones((2, 12)), jnp.float32)
     rt = apc.Runtime(apc.ArrayPool(n_arrays=1, rows=8, cols=64),
-                     kernel_variant="gather")
-    with pytest.raises(ValueError, match="kernel_variant"):
-        ternary_matmul_ap(x, packed, scale, runtime=rt,
-                          kernel_variant="onehot")
-    with pytest.raises(ValueError, match="unroll"):
-        ternary_matmul_ap(x, packed, scale, runtime=rt, unroll=8)
+                     interpret=True)
     with pytest.raises(ValueError, match="interpret"):
         ternary_matmul_ap(x, packed, scale, runtime=rt, interpret=False)
-    # matching knobs pass through
-    y = ternary_matmul_ap(x, packed, scale, runtime=rt,
-                          kernel_variant="gather")
+    # a matching value passes through
+    y = ternary_matmul_ap(x, packed, scale, runtime=rt, interpret=True)
     assert y.shape == (2, 2)
     # explicit values that restate the backend default of an unconfigured
-    # Runtime stay compatible (the pre-knob API's interpret=True callers)
+    # Runtime stay compatible
     rt_default = apc.Runtime(apc.ArrayPool(n_arrays=1, rows=8, cols=64))
     from repro.kernels.tap_pass.kernel import resolve_interpret
     y = ternary_matmul_ap(x, packed, scale, runtime=rt_default,
                           interpret=resolve_interpret(None))
     assert y.shape == (2, 2)
-
-
-def test_short_schedule_env_lever_does_not_reach_pallas_compiled(
-        monkeypatch):
-    """REPRO_AP_INTERPRET=0 must not crash the short-schedule (unrolled
-    pallas) path on a CPU host — it has no compiled pallas lowering, so the
-    lever applies only to the program kernel there."""
-    from repro.kernels.tap_pass.ops import tap_apply_lut
-    from repro.core.nonblocked import build_lut_nonblocked
-    lut = build_lut_nonblocked(tt.full_adder(3))
-    rng = np.random.default_rng(2)
-    arr = jnp.asarray(rng.integers(0, 3, (64, 3)), jnp.int8)
-    want = np.asarray(tap_apply_lut(arr, lut, (0, 1, 2), block_rows=64))
-    monkeypatch.setenv("REPRO_AP_INTERPRET", "0")
-    got = np.asarray(tap_apply_lut(arr, lut, (0, 1, 2), block_rows=64))
-    assert np.array_equal(got, want)
-    monkeypatch.delenv("REPRO_AP_INTERPRET")
-    # an EXPLICIT interpret=False is honored: the short schedule routes
-    # through the program kernel's compiled XLA harness, same digits
-    got = np.asarray(tap_apply_lut(arr, lut, (0, 1, 2), block_rows=64,
-                                   interpret=False))
-    assert np.array_equal(got, want)
-
-
-def test_unroll_knob_values_are_bit_exact():
-    r, w, rows = 3, 5, 77
-    rng = np.random.default_rng(4)
-    a = rng.integers(0, r ** w, rows)
-    b = rng.integers(0, r ** w, rows)
-    arr = jnp.asarray(ap.encode_operands(a, b, r, w))
-    compiled = apc.compile_named("add", r, w)
-    base, tr = apc.execute(arr, compiled, collect_stats=True, unroll=1)
-    s0 = apc.to_ap_stats(tr, compiled, rows, r)
-    for unroll in (2, 4, 8):
-        out, tr = apc.execute(arr, compiled, collect_stats=True,
-                              unroll=unroll)
-        assert np.array_equal(np.asarray(out), np.asarray(base))
-        _stats_equal(s0, apc.to_ap_stats(tr, compiled, rows, r))
-    with pytest.raises(ValueError):
-        apc.execute(arr, compiled, unroll=0)
-    with pytest.raises(ValueError):
-        apc.execute(arr, compiled, kernel_variant="vliw9000")
+    with pytest.raises(ValueError, match="interpret"):
+        ternary_matmul_ap(x, packed, scale, runtime=rt_default,
+                          interpret=not resolve_interpret(None))
 
 
 # ---------------------------------------------------------------------------
-# Cache bounds + stats exposure (ISSUE 5 satellite)
+# Cache bounds + stats exposure
 # ---------------------------------------------------------------------------
 
 def test_compile_caches_all_bounded_with_stats():
@@ -503,9 +483,11 @@ def test_ap_serve_context_exposes_cache_stats():
 # ---------------------------------------------------------------------------
 
 def test_random_schedules_packed_parity_hypothesis():
+    """Random schedules — repeated columns, read-after-write chains,
+    unconditional writes, stored don't-cares — replay as the oracle does
+    in both modes."""
     pytest.importorskip("hypothesis")
     from hypothesis import given, settings, strategies as st
-    from repro.apc.lower import Step, CompiledProgram
 
     N_COLS = 6
 
@@ -515,10 +497,10 @@ def test_random_schedules_packed_parity_hypothesis():
         n_steps = draw(st.integers(2, 12))
         steps = []
         for _ in range(n_steps):
-            cc = tuple(sorted(draw(st.sets(st.integers(0, N_COLS - 1),
-                                           max_size=3))))
-            wc = tuple(sorted(draw(st.sets(st.integers(0, N_COLS - 1),
-                                           min_size=1, max_size=2))))
+            cc = tuple(draw(st.lists(st.integers(0, N_COLS - 1),
+                                     max_size=3)))
+            wc = tuple(draw(st.lists(st.integers(0, N_COLS - 1),
+                                     min_size=1, max_size=2)))
             keys = tuple(
                 tuple(draw(st.integers(0, radix - 1)) for _ in cc)
                 for _ in range(draw(st.integers(1, 2)))) if cc else ()
@@ -537,14 +519,6 @@ def test_random_schedules_packed_parity_hypothesis():
         rows = int(rng.integers(1, 40))
         # include stored don't-cares: they match every key
         arr = jnp.asarray(rng.integers(-1, radix, (rows, N_COLS)), jnp.int8)
-        base, tr = apc.execute(arr, compiled, collect_stats=True,
-                               kernel_variant="gather", interpret=True)
-        s0 = apc.to_ap_stats(tr, compiled, rows, radix)
-        for kv in ("onehot", "onehot_packed", "lanes"):
-            for interp in (True, False):
-                out, tr = apc.execute(arr, compiled, collect_stats=True,
-                                      kernel_variant=kv, interpret=interp)
-                assert np.array_equal(np.asarray(out), np.asarray(base))
-                _stats_equal(s0, apc.to_ap_stats(tr, compiled, rows, radix))
+        _check_execute(arr, compiled, radix)
 
     prop()

@@ -161,6 +161,33 @@ def test_pool_validate_up_front_names_width():
     pool_ok.validate(compiled, n_cols=36)
 
 
+def test_pool_schedule_store_uploads_once_per_program():
+    """The pool uploads a program's schedule tensors on its first run and
+    reuses them on every later run (``pool.schedule_uploads`` /
+    ``pool.schedule_reuse``); a second program gets an entry of its own,
+    and the store stays bounded, re-uploading an evicted program."""
+    from repro.apc.metrics import get_registry
+    reg = get_registry()
+    ups, reuse = (reg.counter("pool.schedule_uploads"),
+                  reg.counter("pool.schedule_reuse"))
+    add, mx = apc.compile_named("add", 3, 2), apc.compile_named("max", 3, 2)
+    pool = apc.ArrayPool(n_arrays=2, rows=8, cols=16)
+    arr = jnp.zeros((20, 5), jnp.int8)
+    u0, r0 = ups.value, reuse.value
+    first, _ = pool.run(arr, add)
+    again, _ = pool.run(arr, add)
+    assert (ups.value - u0, reuse.value - r0) == (1, 1)
+    assert np.array_equal(np.asarray(first), np.asarray(again))
+    pool.run(arr[:, :4], mx)
+    assert (ups.value - u0, reuse.value - r0) == (2, 1)
+    assert set(pool._schedules) == {id(add), id(mx)}
+    pool._max_schedules = 2
+    pool.run(arr, apc.compile_named("sub", 3, 2))   # evicts add's entry
+    assert id(add) not in pool._schedules
+    pool.run(arr, add)
+    assert (ups.value - u0, reuse.value - r0) == (4, 1)
+
+
 def test_pool_reduce_plan_chains_under_budget():
     """Many tiles + tight budget: the reduction chains in groups, still
     bit-exact."""

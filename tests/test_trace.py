@@ -10,7 +10,7 @@ Acceptance contract (ISSUE 6):
 - to_chrome() round-trips through validate_chrome_trace: metadata first,
   "X" events with µs timestamps, model-time slices on pid 1;
 - with tracing OFF the instrumented paths leave digits + APStats
-  bit-identical across kernel variants (parity vs a traced run);
+  bit-identical (parity vs a traced and a profiled run);
 - per-program attribution sums bit-exactly back to the APStats the same
   run aggregated (total_ap_stats == stats);
 - compile front doors bump hit/miss counters in the metrics registry;
@@ -335,7 +335,7 @@ def test_validate_chrome_trace_rejects_malformed_counter_events():
 # instrumented paths: parity off, bit-exact attribution on
 # ---------------------------------------------------------------------------
 
-def _smoke_engine(kernel_variant=None):
+def _smoke_engine():
     """The 1-layer d_model=16 qwen3 smoke cut, its MLPs on a 4x64x64 bank."""
     import jax
     from repro.configs import get_smoke_config
@@ -348,8 +348,7 @@ def _smoke_engine(kernel_variant=None):
                      n_kv_heads=2, head_dim=8, vocab=32,
                      ternary=base.ternary.__class__(enabled=True))
     qparams = quantize_model_params(M.init_params(cfg, jax.random.PRNGKey(0)))
-    pool = apc.ArrayPool(n_arrays=4, rows=64, cols=64,
-                         kernel_variant=kernel_variant)
+    pool = apc.ArrayPool(n_arrays=4, rows=64, cols=64)
     ctx = apc.APServeContext(apc.Runtime(pool), x_levels=7)
     return Engine(cfg, qparams, make_smoke_mesh(), ServeCfg(max_len=8),
                   ap_ctx=ctx)
@@ -359,42 +358,35 @@ def _smoke_engine(kernel_variant=None):
 def test_tracing_off_is_bit_identical_across_variants(case, profiled):
     """REPRO_AP_TRACE=0 parity: digits, APStats and served tokens are the
     same with no tracer and no profiler, under a Tracer, and under the
-    profiler (which records the program's spans), for every kernel
-    variant of the MAC, whose digits decode to the integer dot
-    products."""
+    profiler (which records the program's spans); the MAC's digits
+    decode to the integer dot products."""
     x, w = _mac_inputs()
     radix, width, K = 3, 8, x.shape[1]
-    # serving, on the backend's default variant (the MAC case covers all)
-    variants = apc.KERNEL_VARIANTS if case == "mac" else (None,)
-    engines = ({kv: _smoke_engine(kv) for kv in variants}
-               if case == "serve" else {})
+    engine = _smoke_engine() if case == "serve" else None
     outs, stats = [], []
     for mode in ("off", "tracer", "profiler"):
-        for kv in variants:
-            if case == "mac":
-                def go():
-                    st = APStats(radix=radix)
-                    pool = apc.ArrayPool(n_arrays=2, rows=16, cols=96)
-                    tiled = apc.compile_mac_tiled(radix, K, width, 4,
-                                                  max_cols=pool.cols)
-                    out = apc.run_mac_tiled(x, w, tiled, pool=pool,
-                                            stats=st, kernel_variant=kv)
-                    return np.asarray(out), st
+        if case == "mac":
+            def go():
+                st = APStats(radix=radix)
+                pool = apc.ArrayPool(n_arrays=2, rows=16, cols=96)
+                tiled = apc.compile_mac_tiled(radix, K, width, 4,
+                                              max_cols=pool.cols)
+                out = apc.run_mac_tiled(x, w, tiled, pool=pool, stats=st)
+                return np.asarray(out), st
+        else:
+            def go():
+                toks = engine.generate(np.array([[3]], np.int32), 2)
+                return toks, engine.ap_ctx.stats
+        guard = (trace.tracing(trace.Tracer()) if mode == "tracer"
+                 else trace.disabled())
+        with guard:
+            if mode == "profiler":
+                (out, st), spans = profiled(go)
+                assert spans
             else:
-                def go():
-                    eng = engines[kv]
-                    toks = eng.generate(np.array([[3]], np.int32), 2)
-                    return toks, eng.ap_ctx.stats
-            guard = (trace.tracing(trace.Tracer()) if mode == "tracer"
-                     else trace.disabled())
-            with guard:
-                if mode == "profiler":
-                    (out, st), spans = profiled(go)
-                    assert spans
-                else:
-                    out, st = go()
-            outs.append(out)
-            stats.append(st)
+                out, st = go()
+        outs.append(out)
+        stats.append(st)
     if case == "mac":
         assert np.array_equal(outs[0], (x * w).sum(axis=1))
     for o in outs[1:]:
